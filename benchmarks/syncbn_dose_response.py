@@ -25,6 +25,10 @@ line. Two modes isolate different variables:
 Points are written to ``--out`` incrementally: a mid-sweep failure keeps
 every completed dose.
 
+Each dose is a child process started with ``--simulate R``, so this is a
+CPU-only tool; the parent never imports jax (a parent that touched JAX
+would hold the chip, and a child that needed it would fail or hang).
+
     python benchmarks/syncbn_dose_response.py --batches 1 2 4 8
     python benchmarks/syncbn_dose_response.py --mode const_global \
         --global-batch 16 --replicas 2 4 8
@@ -73,8 +77,7 @@ def parse_args():
 
 def _last_json_line(stdout: str):
     """First parseable JSON line scanning from the end — tolerates any
-    trailing library chatter on stdout (the tpu_validation.run_sub
-    pattern)."""
+    trailing library chatter on stdout."""
     for line in reversed(stdout.strip().splitlines()):
         try:
             return json.loads(line)

@@ -26,9 +26,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def parse_args():
     p = argparse.ArgumentParser()
-    p.add_argument("--simulate", type=int, default=8,
-                   help="virtual host devices (the seq-shard count); 0 = "
-                        "use the real backend topology")
+    p.add_argument("--simulate", type=int, default=0,
+                   help="run on this many virtual CPU host devices (the "
+                        "seq-shard count); 0 = the real backend topology")
     p.add_argument("--impl", choices=["ring", "ulysses"], default="ring")
     p.add_argument("--local-impl", choices=["oracle", "flash"],
                    default="oracle",
@@ -58,11 +58,9 @@ def main():
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.simulate}"
         ).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"
 
     import jax
-    if args.simulate:
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     import optax

@@ -241,3 +241,64 @@ def test_server_stop_with_live_connections_fast():
     t0 = time.time()
     server.stop()  # must not hang on the live connection
     assert time.time() - t0 < 2
+
+
+class TestBuildWhenStale:
+    """The binary is git-ignored: a checkout has none, and a working
+    tree may hold one older than the sources. ``load`` builds in both
+    cases and says which of built / loaded / unavailable happened."""
+
+    @pytest.fixture
+    def scratch_native(self, tmp_path, monkeypatch):
+        import os
+        import shutil
+
+        src = os.path.join(native._NATIVE_DIR, "csrc")
+        work = tmp_path / "native"
+        work.mkdir()
+        shutil.copy(os.path.join(native._NATIVE_DIR, "Makefile"), work)
+        shutil.copytree(src, work / "csrc")
+        monkeypatch.setattr(native, "_NATIVE_DIR", str(work))
+        monkeypatch.setattr(native, "_SRC_DIR", str(work / "csrc"))
+        monkeypatch.setattr(
+            native, "_LIB_PATH", str(work / "libtpu_syncbn_native.so")
+        )
+
+        def fresh_process():
+            monkeypatch.setattr(native, "_lib", None)
+            monkeypatch.setattr(native, "_status", "not loaded")
+
+        fresh_process()
+        return work, fresh_process
+
+    def test_absent_builds_then_loads_then_rebuilds_when_stale(
+        self, scratch_native
+    ):
+        import os
+
+        work, fresh_process = scratch_native
+        assert native.status() == "built"  # absent: built
+        fresh_process()
+        assert native.status() == "loaded"  # up to date: no build
+        lib = work / "libtpu_syncbn_native.so"
+        old = os.path.getmtime(work / "csrc" / "sampler.cc") - 100
+        os.utime(lib, (old, old))  # now older than its sources
+        fresh_process()
+        assert native.status() == "built"
+        assert os.path.getmtime(lib) > old
+
+    def test_no_toolchain_is_unavailable_and_said_so(
+        self, scratch_native, monkeypatch, caplog
+    ):
+        import subprocess
+
+        _, fresh_process = scratch_native
+
+        def no_make(*a, **k):
+            raise FileNotFoundError("make")
+
+        monkeypatch.setattr(subprocess, "run", no_make)
+        with caplog.at_level("WARNING", logger="tpu_syncbn"):
+            assert native.load() is None
+        assert native.status() == "unavailable"
+        assert "native library unavailable" in caplog.text

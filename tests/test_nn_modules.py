@@ -119,8 +119,9 @@ def test_converted_model_propagates_eval_mode(rng_x=None):
     class Mixed(nnx.Module):
         def __init__(self):
             self.tower = _Tower()  # attr + list + dict containers
-            self.pair = Pair(tnn.BatchNorm1d(C),
-                             nnx.Linear(C, C, rngs=nnx.Rngs(1)))
+            # flax 0.12 refuses arrays under an un-annotated tuple
+            self.pair = nnx.data(Pair(tnn.BatchNorm1d(C),
+                                      nnx.Linear(C, C, rngs=nnx.Rngs(1))))
 
     m = tnn.convert_sync_batchnorm(Mixed())
     bns = [m.tower.bn, *m.tower.blocks, m.tower.named["head"], m.pair.one]

@@ -137,7 +137,11 @@ def test_grad_norm_dispersion_zero_on_identical_replicas():
                            dp.batch_sharding)
     out = dp.train_step(batch)
     assert float(out.monitors["replica_grad_norm"]) > 0
-    assert float(out.monitors["replica_grad_norm_disp"]) < 1e-4
+    # std/mean from one fused psum of (Σx, Σx²) in f32: on identical
+    # replicas E[x²] − E[x]² cancels to rounding, and its square root
+    # sits near sqrt(eps_f32) ≈ 3e-4 whenever the all-reduce does not
+    # happen to sum 8 equal values exactly
+    assert float(out.monitors["replica_grad_norm_disp"]) < 1e-3
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from tpu_syncbn import compat
 from tpu_syncbn.compat import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -272,7 +271,7 @@ def test_trainer_with_pallas_kernels_matches_xla_path():
     # pallas-active on a TPU host or under TPU_SYNCBN_PALLAS=on)
     with xops.pallas_mode("off"):
         dp_xla = build()
-        assert dp_xla._check_vma == compat.HAS_VMA
+        assert dp_xla._check_vma
         out_x = dp_xla.train_step(batch)
 
     np.testing.assert_allclose(
@@ -312,8 +311,7 @@ def test_group_scoped_model_keeps_vma_checker_under_pallas_mode():
 
         dp = parallel.DataParallel(m, optax.sgd(0.1), loss_fn, donate=False)
         # pallas can't trace for this model, so the checker stays on
-        # wherever this jax HAS the VMA checker
-        assert dp._check_vma == compat.HAS_VMA
+        assert dp._check_vma
         rng = np.random.RandomState(0)
         batch = (
             jnp.asarray(rng.randn(16, 8, 8, 3).astype(np.float32)),
@@ -328,9 +326,9 @@ class TestVmemAwareBlock:
     TPU's 16 MiB scoped-VMEM ceiling in bn_backward_reduce at C=2048 f32
     (2 operands x 2 pipeline buffers x 512*2048*4 B = 16 MiB + scratch).
     _block_m must keep the fattest kernel's double-buffered working set
-    under budget while preserving the sweep-chosen cap (256 per the
-    fetch-synced sweep, tpu_pallas_sweep.json; the earlier 512 ranking
-    was a readiness-bug artifact) wherever it fits."""
+    under budget while preserving the sweep-chosen cap (256, per the
+    2026-07-31 sweep whose record was removed in PR 21) wherever it
+    fits."""
 
     def test_measured_oom_case_fires_clamp(self, monkeypatch):
         # the historical failure: cap 512, C=2048, f32 must CLAMP to 256
@@ -354,12 +352,15 @@ class TestVmemAwareBlock:
         assert pallas_bn._block_m(2048, 2) == cap  # bf16 halves the rows
 
     def test_budget_invariant(self):
-        for c in (8, 64, 256, 512, 1024, 2048, 4096, 8192, 16384):
+        for c in (8, 64, 256, 512, 1024, 2048, 4096, 8192, 16384, 32768):
             for itemsize in (2, 4):
                 m = pallas_bn._block_m(c, itemsize)
-                assert m >= 64
-                assert (4 * m * c * itemsize <= pallas_bn._VMEM_BUDGET_BYTES
-                        or m == 64)
+                assert m >= 32 // itemsize  # one sublane tile of rows
+                assert 4 * m * c * itemsize <= pallas_bn._VMEM_BUDGET_BYTES
+
+    def test_too_wide_for_one_tile_is_a_named_error(self):
+        with pytest.raises(ValueError, match="scoped-VMEM budget"):
+            pallas_bn._block_m(1 << 20, 4)
 
     def test_wide_channel_kernels_correct_at_clamped_block(self):
         """Functional check at a C wide enough to clamp the block below

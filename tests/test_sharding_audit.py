@@ -107,9 +107,12 @@ class TestPropagation:
             )
             return jax.lax.psum(acc, DATA_AXIS)
 
+        # check_vma=False: jax's own checker refuses an un-cast
+        # replicated carry that turns varying — the analyzer's fixpoint
+        # is what is under test, so the program skips jax's
         fn = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P(None, DATA_AXIS),),
-            out_specs=P(),
+            out_specs=P(), check_vma=False,
         ))
         flow = sharding_audit.analyze_program(
             fn, (_sds(4, 16),), mesh=mesh, in_specs=(P(None, DATA_AXIS),),
@@ -138,7 +141,7 @@ class TestPropagation:
 
         fn = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P(None, DATA_AXIS),),
-            out_specs=P(DATA_AXIS),
+            out_specs=P(DATA_AXIS), check_vma=False,  # as above
         ))
         flow = sharding_audit.analyze_program(
             fn, (_sds(4, 64),), mesh=mesh,
@@ -168,9 +171,11 @@ class TestPropagation:
                 big, DATA_AXIS, scatter_dimension=0, tiled=True
             )
 
+        # check_vma=False: jax 0.9's checker asserts on a vmap-axis
+        # psum of a mesh-varying value; the analyzer must not care
         fn = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P(DATA_AXIS),),
-            out_specs=P(DATA_AXIS),
+            out_specs=P(DATA_AXIS), check_vma=False,
         ))
         flow = sharding_audit.analyze_program(
             fn, (_sds(64, 8),), mesh=mesh, in_specs=(P(DATA_AXIS),),

@@ -66,15 +66,6 @@ def test_syncbn_equals_big_batch_bn_forward_and_stats():
 
 
 def test_syncbn_equals_big_batch_bn_gradients():
-    from tpu_syncbn import compat
-
-    if not compat.HAS_VMA:
-        pytest.skip(
-            "legacy shard_map cannot transpose replicated (P()) args "
-            "through jax.grad — _SpecError with either check_rep setting; "
-            "the module/trainer-level golden tests cover the gradient "
-            "contract on this toolchain"
-        )
     """Backward: the psum's autodiff must reproduce the reference's
     all_reduce([sum_dy, sum_dy_xmu]) semantics — per-input grads under
     N-replica SyncBN equal big-batch BN grads."""
@@ -162,14 +153,6 @@ def test_eval_mode_emits_zero_collectives():
 
 
 def test_train_mode_emits_exactly_one_fused_allreduce():
-    from tpu_syncbn import compat
-
-    if not compat.HAS_VMA:
-        pytest.skip(
-            "old XLA emits the (sum, sumsq, count) reduction as three "
-            "all-reduces instead of one tuple-fused collective; this pin "
-            "is a property of the current compiler"
-        )
     """SyncBN forward should lower to a single fused AllReduce for the
     (sum, sumsq, count) triple — 2C+1 floats, the reference's per-layer
     traffic (SURVEY §3.3) in one collective."""

@@ -1,0 +1,137 @@
+"""Compile — not merely lower — the Pallas kernels for a TPU v5e that is
+described, not attached.
+
+``test_tpu_lowering.py`` stops at the Mosaic lowering, which enforces
+the block-shape rules. What it cannot see is what the TPU *compiler*
+refuses: a kernel that wants more scoped VMEM than a core has, a slice
+that does not sit on the tiling, a program that does not fit the chip.
+libtpu is installed here and compiles for a topology given by name
+(``/opt/skills/guides/on-chip-measurement/SKILL.md`` §2.3), so these
+cases ask it at the widths the ResNet-50 main path and the flash
+attention callers really use. A pass is not a chip run: nothing
+executes, no number comes out.
+
+All of it stays in this one process: libtpu takes a lock file, and a
+second process describing a topology at the same time aborts.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpu_syncbn.ops import pallas_attention as pa
+from tpu_syncbn.ops import pallas_bn
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip, with the persistent compile cache off
+    around the module: an entry written for a described chip cannot be
+    read back without one, and the next run would warn about it."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu / topology unknown to this build
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Route pallas_calls to the TPU compiler, not the interpreter
+    (``interpret()`` asks ``jax.default_backend()``, which is the CPU
+    here whatever the program is compiled for)."""
+    monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_bn, "_interpret", lambda: False)
+
+
+def _compile_fwd_and_grad(loss, *args):
+    """One program that holds the forward kernels and the backward
+    ones: ``value_and_grad`` keeps the forward's result."""
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _bn_loss(x, w, b):
+    y, mean, var, count = pallas_bn.fused_batch_norm(
+        x, w, b, eps=1e-5, axis_name=None
+    )
+    # stats feed the no-grad running-buffer update only; the VJP
+    # rejects differentiation through them by design
+    return y.astype(jnp.float32).sum() + sum(
+        jax.lax.stop_gradient(s).sum() for s in (mean, var, count)
+    )
+
+
+def _bn_args(shape, dtype, chip):
+    c = shape[-1]
+    return (
+        jax.ShapeDtypeStruct(shape, dtype, sharding=chip),
+        jax.ShapeDtypeStruct((c,), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct((c,), jnp.float32, sharding=chip),
+    )
+
+
+# ResNet-50 at per-chip batch 64, 224²: the stem BN, a stage-1 block's
+# widest BN, the last stage's; and rows that are no multiple of a block
+BN_SHAPES = [
+    (64, 112, 112, 64),
+    (64, 56, 56, 256),
+    (64, 7, 7, 2048),
+    (2, 100, 100, 256),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape", BN_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_fused_batch_norm_compiles_for_v5e(v5e, mosaic, shape, dtype):
+    _compile_fwd_and_grad(_bn_loss, *_bn_args(shape, dtype, v5e))
+
+
+@pytest.mark.parametrize("c", [16384, 32768])
+def test_fused_batch_norm_very_wide_channels(v5e, mosaic, c):
+    """Wider than any BN the models have. At C = 16384 f32 a 64-row
+    block's two double-buffered streams are 16 MiB, the whole scoped
+    VMEM, and the compiler still takes it; at C = 32768 it refuses 64
+    rows (16.25 MiB against the 16 MiB limit), so ``_block_m`` has to
+    follow its budget below 64 — and the compiler, not a chip run, is
+    who says the result fits."""
+    _compile_fwd_and_grad(_bn_loss, *_bn_args((512, c), jnp.float32, v5e))
+
+
+# (seq, heads, head_dim): the chip_smoke shape, a long sequence, and the
+# narrow head the 128-lane layout has to pad
+FLASH_SHAPES = [(2048, 16, 128), (8192, 16, 128), (4096, 16, 64)]
+
+
+@pytest.mark.parametrize("backward", ["xla", "pallas"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=lambda s: "S{}h{}d{}".format(*s))
+def test_flash_attention_compiles_for_v5e(v5e, mosaic, shape, causal, backward):
+    s, h, d = shape
+    q = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=causal, backward=backward
+        ).astype(jnp.float32).sum()
+
+    _compile_fwd_and_grad(loss, q, q, q)

@@ -352,10 +352,6 @@ def test_vma_unvarying_grad_transpose_pinned():
     keeps the grad local. The trainer relies on exactly this pair of
     facts (see _microbatch_grads); if a jax upgrade changes either, this
     fails loudly before any silent numeric drift."""
-    from tpu_syncbn import compat
-
-    if not compat.HAS_VMA:
-        pytest.skip("this jax predates the VMA type system")
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 

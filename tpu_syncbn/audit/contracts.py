@@ -54,12 +54,30 @@ SHARDING_SCHEMA = 1
 #: build, but the figure is a cross-check, not a number we control.
 XLA_PEAK_RTOL = 0.10
 
-#: Named-axis collective primitives (jax 0.4 names plus newer aliases —
-#: an unknown collective should fail the contract, not slip past it).
+#: Named-axis collective primitives, under the name a contract counts
+#: them by (an unknown collective should fail the contract, not slip
+#: past it).
 COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "ppermute", "pgather",
     "all_gather", "all_to_all", "reduce_scatter", "psum_scatter",
 })
+
+#: Under ``check_vma`` jax 0.9 types a collective by what it does to the
+#: value's varying set and names the primitive after it: a psum of a
+#: varying value is ``psum_invariant``. The wire operation is the same,
+#: so it is counted under the classic name and the goldens do not move
+#: with the checker.
+_PRIM_ALIASES = {
+    "psum_invariant": "psum",
+    "all_gather_invariant": "all_gather",
+}
+
+
+def prim_name(eqn) -> str:
+    """The equation's primitive name, VMA-typed collective variants
+    folded onto the name :data:`COLLECTIVE_PRIMS` knows."""
+    name = eqn.primitive.name
+    return _PRIM_ALIASES.get(name, name)
 
 #: Host-callback primitives: any of these in a hot program means a
 #: device→host→device round trip per execution.
@@ -274,7 +292,7 @@ def summarize_jaxpr(closed_jaxpr) -> dict:
     callbacks: dict[str, int] = {}
     upcasts: dict[str, int] = {}
     for eqn in iter_eqns(closed_jaxpr):
-        prim = eqn.primitive.name
+        prim = prim_name(eqn)
         if prim in COLLECTIVE_PRIMS:
             collectives[prim] = collectives.get(prim, 0) + 1
             nbytes = sum(_aval_bytes(v.aval) for v in eqn.invars
@@ -369,7 +387,7 @@ def weighted_cost_summary(closed_jaxpr) -> dict:
                 cbytes[k] = cbytes.get(k, 0) + v
 
         for eqn in jaxpr.eqns:
-            prim = eqn.primitive.name
+            prim = prim_name(eqn)
             if prim in COLLECTIVE_PRIMS:
                 nbytes = sum(_aval_bytes(v.aval) for v in eqn.invars
                              if hasattr(v, "aval"))

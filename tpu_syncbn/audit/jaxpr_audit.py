@@ -728,7 +728,6 @@ def _sequence_ring_attention() -> ProgramSpec:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tpu_syncbn import compat
     from tpu_syncbn.compat import shard_map
     from tpu_syncbn.mesh_axes import SEQ_AXIS
     from tpu_syncbn.parallel import sequence
@@ -740,7 +739,6 @@ def _sequence_ring_attention() -> ProgramSpec:
     fn = jax.jit(shard_map(
         sequence.ring_attention, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=compat.HAS_VMA,
     ))
     sds = jax.ShapeDtypeStruct
     qkv = sds((b, l, h, dh), jnp.float32)

@@ -69,6 +69,8 @@ import dataclasses
 import math
 from typing import Any, Callable, Sequence
 
+from tpu_syncbn.audit.contracts import prim_name
+
 #: Fully-replicated intermediates at or above this per-device footprint
 #: are reported as accidental replication (``sharding.replication``).
 #: 1 MiB: big enough that every pinned tiny-model program is quiet, small
@@ -291,7 +293,7 @@ _COLLECTIVE_EFFECT = {
 }
 
 _SUBJAXPR_CALLS = {
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "jit", "pjit", "closed_call", "core_call", "remat", "checkpoint",
     "remat2", "custom_jvp_call", "custom_vjp_call",
     "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
 }
@@ -306,6 +308,16 @@ def _eqn_axes(eqn) -> tuple[str, ...]:
     if isinstance(axes, (str, int)):
         axes = (axes,)
     return tuple(a for a in axes if isinstance(a, str))
+
+
+def _spec_names(spec) -> dict[int, tuple[str, ...]]:
+    """A shard_map eqn's ``PartitionSpec`` as ``{dim: mesh axes}`` over
+    the dims it splits."""
+    names = {}
+    for dim, entry in enumerate(spec):
+        if entry is not None:
+            names[dim] = entry if isinstance(entry, tuple) else (entry,)
+    return names
 
 
 def _call_jaxpr(eqn):
@@ -363,21 +375,33 @@ class _Interp:
         for var in jaxpr.constvars:
             env[var] = self._default(var.aval, local=local)
 
-        # liveness: last use index per var (program-text order)
+        # liveness: last use index per var (program-text order). A
+        # ``pvary`` retypes its operand for the VMA checker and moves
+        # nothing: its result is the operand's buffer (``alias``), so it
+        # weighs nothing itself and keeps the operand alive instead.
         last_use: dict = {}
+        alias: dict = {}
         from jax._src import core as jcore
+
+        def use(v, idx):
+            if not isinstance(v, jcore.Literal):
+                last_use[v] = idx
+                if alias.get(v) is not None:
+                    last_use[alias[v]] = idx
 
         for idx, eqn in enumerate(jaxpr.eqns):
             for v in eqn.invars:
-                if not isinstance(v, jcore.Literal):
-                    last_use[v] = idx
+                use(v, idx)
+            if eqn.primitive.name == "pvary":
+                for out, src in zip(eqn.outvars, eqn.invars):
+                    alias[out] = None if isinstance(src, jcore.Literal) \
+                        else alias.get(src, src)
         for v in jaxpr.outvars:
-            if not isinstance(v, jcore.Literal):
-                last_use[v] = len(jaxpr.eqns)
+            use(v, len(jaxpr.eqns))
 
         def vbytes(var) -> int:
             lo = env.get(var)
-            if lo is None:
+            if lo is None or var in alias:
                 return 0
             return _value_bytes(var.aval, lo, self.col.mesh_axes)
 
@@ -394,7 +418,8 @@ class _Interp:
                 if type(var).__name__ == "DropVar":
                     continue
                 env[var] = lo
-                if record and _fully_replicated(var.aval, lo,
+                if record and var not in alias \
+                        and _fully_replicated(var.aval, lo,
                                                 self.col.mesh_axes) \
                         and len(self.col.mesh_axes) \
                         and math.prod(self.col.mesh_axes.values()) > 1:
@@ -408,7 +433,7 @@ class _Interp:
             )
             peak = max(peak, live_bytes + extra)
             # free values whose last use was this eqn
-            for v in set(v for v in eqn.invars
+            for v in set(alias.get(v, v) for v in eqn.invars
                          if not isinstance(v, jcore.Literal)):
                 if last_use.get(v) == idx and v in env:
                     live_bytes -= vbytes(v)
@@ -444,7 +469,7 @@ class _Interp:
         return self._global_eqn(eqn, in_los, record=record), 0
 
     def _local_eqn(self, eqn, in_los: list, *, record: bool) -> list:
-        prim = eqn.primitive.name
+        prim = prim_name(eqn)
         effect = _COLLECTIVE_EFFECT.get(prim)
         # only MESH axes move data between devices: a vmap-minted named
         # axis ('batch') on the same primitive is intra-device and must
@@ -610,8 +635,8 @@ class _Interp:
     def _shard_map(self, eqn, in_los: list, *, record: bool):
         mesh = eqn.params["mesh"]
         mesh_axes = {str(k): int(v) for k, v in dict(mesh.shape).items()}
-        in_names = eqn.params["in_names"]
-        out_names = eqn.params["out_names"]
+        in_names = [_spec_names(s) for s in eqn.params["in_specs"]]
+        out_names = [_spec_names(s) for s in eqn.params["out_specs"]]
         body = getattr(eqn.params["jaxpr"], "jaxpr", eqn.params["jaxpr"])
 
         # boundary check: operand global layout vs the declared in_names

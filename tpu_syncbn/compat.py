@@ -1,21 +1,13 @@
-"""Version-compat shims: gate the few current-jax/flax APIs this codebase
-uses so the package *imports and degrades* instead of crashing on an older
-baked toolchain (observed container: jax 0.4.x / flax 0.10, where
-``jax.shard_map`` lives in ``jax.experimental.shard_map`` with a
-``check_rep`` flag instead of the VMA type system's ``check_vma``, and the
-``nnx.to_pure_dict`` module functions are still ``State`` methods).
+"""Version-compat shims: the few jax/flax APIs this codebase reaches
+through one audited door (srclint ``raw_api_bypass``), so a toolchain
+move is repaired here and not at every call site. The installation there
+is: jax 0.9.0, flax 0.12.3. Arms for versions that are not installed are
+removed when the toolchain moves past them (PR 21 removed the pre-VMA
+``jax.experimental.shard_map``/``check_rep`` arm and ``HAS_VMA``).
 
 Robustness contract (docs/RESILIENCE.md): a missing optional API selects a
-documented fallback path once, at import; it never raises mid-step. The
-fallbacks are semantic no-ops for correctness-relevant behavior:
+documented fallback path once, at import; it never raises mid-step:
 
-* ``shard_map(check_vma=...)`` → legacy shard_map with ``check_rep=False``.
-  The VMA checker is an extra *validator*; legacy shard_map without
-  ``lax.pvary`` has no implicit varying-cast/psum insertion, so gradients
-  stay replica-local and the trainer's explicit ``pmean`` remains the one
-  aggregation (the round-1 "8x off" hazard does not exist on this path).
-* ``HAS_VMA=False`` additionally makes ``pcast_varying`` the identity —
-  there is no VMA type to cast.
 * ``nnx_merge(..., copy=True)`` falls back to plain ``nnx.merge`` (flax
   versions without the kwarg construct fresh Variables already).
 """
@@ -26,34 +18,13 @@ from typing import Any
 
 import jax
 
-#: True when this jax has the VMA (varying-manual-axes) type system —
-#: ``lax.pvary``/``lax.pcast`` and shard_map's ``check_vma``.
-HAS_VMA: bool = hasattr(jax.lax, "pvary")
-
-_NATIVE_SHARD_MAP: bool = hasattr(jax, "shard_map")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the ``check_vma`` kwarg, on any supported
-    jax. On pre-VMA jax the legacy ``jax.experimental.shard_map`` runs
-    with ``check_rep=False``: ``check_rep`` is a different (replication)
-    checker that several of our step programs legitimately fail — e.g.
-    per-replica buffer storage — and the VMA-cast machinery that keeps
-    the modern checker satisfied is an identity here (``HAS_VMA``)."""
-    if _NATIVE_SHARD_MAP:
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map(
+    """``jax.shard_map`` with the VMA checker on unless a caller turns it
+    off by name."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        # check_rep=False unconditionally: the legacy checker neither
-        # fixes the legacy transpose limitation for replicated args
-        # (tested) nor accepts all our step programs; the modern
-        # checker's guarantees simply don't exist on this toolchain
-        check_rep=False,
+        check_vma=check_vma,
     )
 
 
@@ -76,10 +47,7 @@ def axis_size(axis_name):
 
 
 def vma_of(x) -> frozenset:
-    """The VMA (varying axes) set of a traced value; empty on pre-VMA
-    jax, where every value is effectively unvarying."""
-    if not hasattr(jax, "typeof"):
-        return frozenset()
+    """The VMA (varying axes) set of a traced value."""
     return getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
 
 

@@ -47,11 +47,13 @@ _AUTO_PALLAS_CACHE: list = []
 
 def set_pallas_mode(mode: str) -> None:
     """Select the BN kernel backend: 'auto' (on TPU, Pallas if — and only
-    if — the committed hardware measurement
-    ``benchmarks/artifacts/tpu_syncbn_overhead.json`` shows
-    ``pallas_speedup_vs_xla >= 1``; the XLA-fusion path otherwise and on
-    every non-TPU backend), 'on' (always Pallas; interpret mode off-TPU),
-    'off' (always the XLA-fusion path).
+    if — a hardware measurement of this kernel version, written by
+    ``benchmarks/syncbn_overhead.py`` to
+    ``benchmarks/artifacts/tpu_syncbn_overhead.json``, shows
+    ``pallas_speedup_vs_xla >= 1``; the XLA-fusion path otherwise — no
+    such record is in the tree today — and on every non-TPU backend),
+    'on' (always Pallas; interpret mode off-TPU), 'off' (always the
+    XLA-fusion path).
 
     Read at *trace* time for direct functional calls; the trainers
     (``DataParallel``/``GANTrainer``) additionally snapshot the
@@ -122,12 +124,13 @@ def kernel_code_version() -> str:
 
 
 def _measured_pallas_speedup(path: str | None = None) -> float | None:
-    """The committed hardware evidence for the Pallas-vs-XLA decision:
+    """The hardware evidence for the Pallas-vs-XLA decision:
     ``benchmarks/artifacts/tpu_syncbn_overhead.json``'s
     ``pallas_speedup_vs_xla`` (model-level step-time ratio measured on a
-    real chip by ``benchmarks/tpu_validation.py``). None when the
-    artifact hasn't landed, wasn't TPU-tagged, or measured a different
-    kernel version than the one about to trace."""
+    real chip by ``benchmarks/syncbn_overhead.py``). None when the
+    artifact is absent (as it is today: the 2026-07-31 record was removed
+    in PR 21), wasn't TPU-tagged, or measured a different kernel version
+    than the one about to trace."""
     import json
 
     if path is None:

@@ -201,11 +201,6 @@ def pcast_varying(tree: Pytree, axis_name: str = DATA_AXIS) -> Pytree:
     trainers and the sequence-parallel scan carries — one place to adapt
     if jax's vma/pcast API shifts again."""
 
-    from tpu_syncbn import compat
-
-    if not compat.HAS_VMA:
-        return tree  # pre-VMA jax: no varying type to cast to
-
     axes = tuple(axis_name) if isinstance(axis_name, (tuple, list)) else (axis_name,)
 
     def leaf(x):

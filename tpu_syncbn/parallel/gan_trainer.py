@@ -141,7 +141,7 @@ class GANTrainer:
         # same contract as DataParallel: checker on unless pallas traces
         # for either network under the interpret lowering (snapshotted at
         # construction)
-        self._check_vma = compat.HAS_VMA and not _pallas_forces_vma_off(
+        self._check_vma = not _pallas_forces_vma_off(
             generator, discriminator
         )
 

@@ -303,7 +303,6 @@ class PipelineTrainer:
         divergence_guard: str | None = None,
         donate: bool = True,
     ):
-        from tpu_syncbn import compat
         from tpu_syncbn.parallel import scan_driver
         from tpu_syncbn.parallel.zero import check_elementwise
 
@@ -376,7 +375,6 @@ class PipelineTrainer:
                 f"stacked_params has {self.n_stages} stages"
             )
         self.data_world = int(self.mesh.shape[data_axis])
-        self._check_vma = compat.HAS_VMA
 
         # per-stage params: each device owns ONE stage's slice (P(pipe)
         # on the leading axis); optimizer state mirrors the layout.
@@ -457,7 +455,6 @@ class PipelineTrainer:
         n, m = self.n_stages, self.num_microbatches
         sched = self.schedule
         guard = self.divergence_guard is not None
-        check_vma = self._check_vma
         opt_staged = self._opt_staged
         right = [(i, (i + 1) % n) for i in range(n)]
         left = [(i, (i - 1) % n) for i in range(n)]
@@ -474,8 +471,6 @@ class PipelineTrainer:
         from tpu_syncbn.parallel import collectives
 
         def varying(tree):
-            if not check_vma:
-                return tree
             return pcast_varying(pcast_varying(tree, axis_d), axis_p)
 
         def row_at(row, s):
@@ -652,7 +647,7 @@ class PipelineTrainer:
             out_specs=(P(), P()),
             n_steps=n_steps,
             stacked=stacked,
-            check_vma=self._check_vma,
+            check_vma=True,
             donate=self._donate,
         )
 

@@ -500,7 +500,6 @@ def _tensor_spec(stack: LayerStack, batch_shape: tuple):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tpu_syncbn import compat
     from tpu_syncbn.audit.jaxpr_audit import ProgramSpec
     from tpu_syncbn.compat import shard_map
     from tpu_syncbn.parallel import tensor
@@ -519,7 +518,6 @@ def _tensor_spec(stack: LayerStack, batch_shape: tuple):
                 P(None, MODEL_AXIS, None), P())
     sharded = shard_map(
         fwd, mesh=mesh, in_specs=in_specs, out_specs=P(),
-        check_vma=compat.HAS_VMA,
     )
 
     def train(x, w1, b1, w2, b2):
@@ -553,7 +551,6 @@ def _dp_tensor_spec(stack: LayerStack, batch_shape: tuple, *,
     import jax
     import jax.numpy as jnp
 
-    from tpu_syncbn import compat
     from tpu_syncbn.audit.jaxpr_audit import ProgramSpec
     from tpu_syncbn.compat import shard_map
     from tpu_syncbn.parallel import tensor
@@ -572,7 +569,7 @@ def _dp_tensor_spec(stack: LayerStack, batch_shape: tuple, *,
                 P(None, MODEL_AXIS), P(None, MODEL_AXIS, None), P())
     sharded = shard_map(
         fwd, mesh=lay.mesh, in_specs=in_specs,
-        out_specs=lay.batch_spec, check_vma=compat.HAS_VMA,
+        out_specs=lay.batch_spec,
     )
 
     def train(x, w1, b1, w2, b2):

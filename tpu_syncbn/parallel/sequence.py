@@ -481,11 +481,11 @@ def sharded_self_attention(
     # bodies on the CPU mesh); on TPU the checker stays on
     from tpu_syncbn import compat
 
-    check_vma = compat.HAS_VMA
+    check_vma = True
     if local_impl == "flash":
         from tpu_syncbn.ops._pallas_common import interpret
 
-        check_vma = check_vma and not interpret()
+        check_vma = not interpret()
     seq_sharded = P(None, axis_name, None, None)
     shard_fn = compat.shard_map(
         fn,

@@ -96,8 +96,9 @@ def main(argv: list[str] | None = None) -> None:
             f"{args.simulate_chips}"
         ).strip()
         os.environ["JAX_PLATFORMS"] = "cpu"
-        # jax may already be imported (e.g. launcher under pytest): the
-        # env alone is too late then — mirror it into the live config.
+        # jax reads JAX_PLATFORMS when it is imported, and `python -m
+        # tpu_syncbn.launch` imports the package (and jax) before main()
+        # runs: the env alone is too late, so mirror it into the config
         if "jax" in sys.modules:
             import jax
 
@@ -110,14 +111,6 @@ def main(argv: list[str] | None = None) -> None:
         os.environ["TPU_SYNCBN_NUM_PROCESSES"] = str(args.num_processes)
     if args.process_id is not None:
         os.environ["TPU_SYNCBN_PROCESS_ID"] = str(args.process_id)
-
-    # Environments that pre-register an accelerator plugin at interpreter
-    # start (sitecustomize) override JAX_PLATFORMS through jax.config; a
-    # user-provided env value must win, so mirror it into the live config.
-    if os.environ.get("JAX_PLATFORMS") and "jax" in sys.modules:
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 
     from tpu_syncbn import runtime
 

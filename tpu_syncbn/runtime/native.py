@@ -12,47 +12,93 @@ non-kernel components (SURVEY §2 "Native?" rows):
 * TCP key/value store + counters (torch's C++ TCPStore behind
   ``init_method='env://'``, reference ``README.md:32``).
 
-The library is built lazily with ``make`` on first use; every consumer has
-a pure-Python fallback, so the framework works without a toolchain.
+The library is built with ``make`` on first use, and again whenever a
+source under ``native/csrc/`` is newer than it (the binary is
+git-ignored, so what is on disk may predate the sources). Every consumer
+has a pure-Python fallback, so the framework works without a toolchain —
+but it says so: :func:`status` tells which of built / loaded /
+unavailable happened, and an unavailable library is logged once.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
+_SRC_DIR = os.path.join(_NATIVE_DIR, "csrc")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libtpu_syncbn_native.so")
 
 _lib = None
 _lib_lock = threading.Lock()
-_load_failed = False
+_status = "not loaded"
+
+
+def _stale() -> bool:
+    """Is the binary absent, or older than any source it is built from?"""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(
+        os.path.getmtime(os.path.join(_SRC_DIR, name)) > built
+        for name in os.listdir(_SRC_DIR)
+    )
+
+
+def _build() -> None:
+    """``make`` into a name of this process's own, then rename: several
+    processes (loader workers) may find the binary stale at once, and
+    none of them may load a half-written file."""
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["make", "-B", "-C", _NATIVE_DIR, f"OUT={os.path.basename(tmp)}"],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, _LIB_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def load() -> ctypes.CDLL | None:
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    """Load (building if absent or stale) the native library; None if
+    unavailable."""
+    global _lib, _status
+    if _status != "not loaded":
         return _lib
     with _lib_lock:
-        if _lib is not None or _load_failed:
+        if _status != "not loaded":
             return _lib
         try:
-            if not os.path.exists(_LIB_PATH):
-                subprocess.run(
-                    ["make", "-C", _NATIVE_DIR],
-                    check=True, capture_output=True, timeout=120,
-                )
+            build = _stale()
+            if build:
+                _build()
             lib = ctypes.CDLL(_LIB_PATH)
-        except Exception:
-            _load_failed = True
+        except (OSError, subprocess.SubprocessError) as e:
+            _status = "unavailable"
+            logging.getLogger("tpu_syncbn").warning(
+                "native library unavailable (%s: %s); using the "
+                "pure-Python paths", type(e).__name__, e,
+            )
             return None
         _configure(lib)
         _lib = lib
+        _status = "built" if build else "loaded"
         return _lib
+
+
+def status() -> str:
+    """``"built"`` (compiled by this process), ``"loaded"`` (an
+    up-to-date binary was on disk) or ``"unavailable"`` (no toolchain or
+    the build failed: the pure-Python paths are in use)."""
+    load()
+    return _status
 
 
 def available() -> bool:

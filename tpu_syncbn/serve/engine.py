@@ -124,7 +124,6 @@ class InferenceEngine:
         from flax import nnx
         from jax.sharding import PartitionSpec as P
 
-        from tpu_syncbn import compat
         from tpu_syncbn.parallel.layout import SpecLayout
         from tpu_syncbn.parallel.trainer import _pallas_forces_vma_off
         from tpu_syncbn.runtime import distributed as dist
@@ -208,7 +207,7 @@ class InferenceEngine:
         # the Pallas train kernels, but track_running_stats=False models
         # eval on the batch-stats path, which can trace them — so the
         # VMA checker follows the trainer's gate
-        self._check_vma = compat.HAS_VMA and not _pallas_forces_vma_off(model)
+        self._check_vma = not _pallas_forces_vma_off(model)
 
         from tpu_syncbn.parallel import scan_driver
 
