@@ -136,6 +136,39 @@ class TestRuleEdges:
         )
         assert lint_source(src, "x.py") == []
 
+    def test_donate_rebind_inside_a_with_block_is_clean(self):
+        # the trainer's own shape: the dispatch under a tracing span.
+        # The ``with`` does not donate on behalf of the assignment
+        # nested in it, which rebinds what it donates
+        src = (
+            "class T:\n"
+            "    def step(self, b):\n"
+            "        with span('train_step'):\n"
+            "            (self._p, loss) = self._train_step(self._p, b)\n"
+            "        return dict(self._p), loss\n"
+        )
+        assert lint_source(src, "x.py") == []
+
+    @pytest.mark.parametrize("block", [
+        "with span('snapshot'):", "if b is not None:", "for _ in range(2):",
+        "try:",
+    ])
+    def test_donate_then_read_in_a_nested_block_is_caught(self, block):
+        tail = "        finally:\n            pass\n" \
+            if block == "try:" else ""
+        src = (
+            "class T:\n"
+            "    def step(self, b):\n"
+            "        with span('train_step'):\n"
+            "            out = self._train_step(self._p, b)\n"
+            f"        {block}\n"
+            "            snap = dict(self._p)\n"
+            f"{tail}"
+            "        return out, snap\n"
+        )
+        vs = lint_source(src, "x.py")
+        assert [(v.rule, v.line) for v in vs] == [("donate_after_use", 6)]
+
     def test_donating_factory_result_is_tracked(self):
         src = (
             "class T:\n"

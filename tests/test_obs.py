@@ -36,6 +36,36 @@ def _clean_obs_state():
     tracing.uninstall()
 
 
+@pytest.fixture
+def capture(tmp_path, monkeypatch):
+    """``start()`` / ``stop()`` of a ``jax.profiler`` capture with its
+    own tracers off (quick; the switch only needs the session), stopped
+    whatever the test does. The module forgets earlier captures first
+    (other test files of this process may have made some)."""
+    import jax
+
+    monkeypatch.setattr(tracing, "_capture", None)
+    monkeypatch.setattr(tracing, "_capture_session", None)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 0
+    running = []
+
+    class Capture:
+        def start(self):
+            jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+            running.append(True)
+
+        def stop(self):
+            if running:
+                running.pop()
+                jax.profiler.stop_trace()
+
+    c = Capture()
+    yield c
+    c.stop()
+
+
 # ------------------------------------------------------------- instruments
 
 
@@ -277,6 +307,276 @@ class TestTracing:
                 raise RuntimeError("x")
         assert t.events[0]["name"] == "boom"
         assert t.latest_open_span_id() is None
+
+
+class TestSpanClocks:
+    """What a span records besides its name: wall time on
+    ``time.perf_counter`` and its thread's CPU time."""
+
+    def test_a_sleeping_span_has_little_cpu_time_a_busy_one_nearly_all(self):
+        t = tracing.Tracer()
+        with t.span("asleep"):
+            time.sleep(0.05)
+        with t.span("busy"):
+            end = time.perf_counter() + 0.05
+            while time.perf_counter() < end:
+                pass
+        by_name = {e["name"]: e for e in t.events}
+        asleep, busy = by_name["asleep"], by_name["busy"]
+        assert asleep["dur"] >= 50_000
+        assert asleep["args"]["cpu_us"] < 0.2 * asleep["dur"]
+        # a loaded test machine may take the core away for a while
+        assert 0.5 * busy["dur"] < busy["args"]["cpu_us"] <= 1.05 * busy["dur"]
+
+    def test_spans_are_absolute_perf_counter_times_around_the_block(self):
+        t = tracing.Tracer()
+        assert t.t0 <= time.perf_counter()
+        before = time.perf_counter()
+        with t.span("a", step=7):
+            inside = time.perf_counter()
+        after = time.perf_counter()
+        t.instant("not_a_span")
+        with t.span("b"):
+            pass
+        (name, t0, t1, cpu_s, tid, args), = t.spans("a")
+        assert name == "a" and tid == threading.get_ident()
+        # events keep nanoseconds: allow for the rounding
+        assert before - 1e-6 <= t0 <= inside <= t1 <= after + 1e-6
+        assert 0 <= cpu_s <= t1 - t0 + 1e-6
+        assert args["step"] == 7 and args["cpu_us"] == pytest.approx(
+            cpu_s * 1e6, abs=1e-3)
+        assert [s[0] for s in t.spans()] == ["a", "b"]
+
+    def test_begin_end_is_the_span_with_args_known_at_the_end(self):
+        t = tracing.Tracer()
+        with t.span("outer") as outer_id:
+            token = t.begin("fetch", depth_before=2)
+            assert t.current_span_id() == token[0]
+            t.end(token, depth=0)
+            assert t.current_span_id() == outer_id
+        fetch = t.events[0]
+        assert fetch["name"] == "fetch"
+        assert fetch["args"]["parent_id"] == outer_id
+        assert fetch["args"]["depth_before"] == 2
+        assert fetch["args"]["depth"] == 0
+        assert t.latest_open_span_id() is None
+
+
+class TestCaptureSwitch:
+    """With no tracer installed, spans record for as long as a
+    ``jax.profiler`` capture runs in the process."""
+
+    def test_capture_active_follows_start_and_stop_from_any_thread(
+            self, capture):
+        import jax._src.profiler as jax_profiler
+
+        # the one private attribute the switch reads: if jax moves it,
+        # this is the line that says so
+        assert hasattr(jax_profiler._profile_state, "profile_session")
+        assert tracing.capture_active() is False
+        capture.start()
+        assert tracing.capture_active() is True
+        seen = []
+        th = threading.Thread(
+            target=lambda: seen.append(tracing.capture_active()))
+        th.start()
+        th.join(timeout=10)
+        assert seen == [True]
+        capture.stop()
+        assert tracing.capture_active() is False
+
+    def test_spans_record_only_while_a_capture_runs(self, capture):
+        with tracing.span("before"):
+            pass
+        assert tracing.get() is None and tracing.last_capture() is None
+        capture.start()
+        with tracing.span("during", step=1) as sid:
+            assert sid is not None
+            assert tracing.current_span_id() == sid
+        with stepstats.timed_span("timed", "step.time_s"):
+            pass
+        assert stepstats.timed_fetch(iter([5]), "data_wait", None) == 5
+        ring = tracing.last_capture()
+        assert tracing.get() is ring
+        assert isinstance(ring, tracing.RingTracer)
+        assert ring.capacity == tracing.CAPTURE_CAPACITY == 16_384
+        capture.stop()
+        with tracing.span("after"):
+            pass
+        assert tracing.get() is None
+        # kept after the capture so that it can be read
+        assert tracing.last_capture() is ring
+        assert [s[0] for s in ring.spans()] == ["during", "timed",
+                                                "data_wait"]
+
+    def test_a_second_capture_gets_a_fresh_ring(self, capture):
+        capture.start()
+        with tracing.span("first"):
+            pass
+        first = tracing.last_capture()
+        capture.stop()
+        # no span site runs between the two captures
+        capture.start()
+        with tracing.span("second"):
+            pass
+        capture.stop()
+        second = tracing.last_capture()
+        assert second is not first
+        assert [s[0] for s in first.spans()] == ["first"]
+        assert [s[0] for s in second.spans()] == ["second"]
+
+    def test_the_ring_is_there_before_the_session_is_known(
+            self, capture, monkeypatch):
+        # threads on the lock-free path test the session and then take
+        # the ring: whoever sees the new session must find ITS ring
+        seen = []
+
+        class Watched(tracing.RingTracer):
+            def __init__(self, capacity):
+                seen.append(tracing._capture_session
+                            is tracing._profile_session())
+                super().__init__(capacity)
+
+        monkeypatch.setattr(tracing, "RingTracer", Watched)
+        for _ in range(2):
+            capture.start()
+            assert tracing.get() is tracing.last_capture()
+            assert tracing._capture_session is not None
+            capture.stop()
+        assert tracing.get() is None
+        # each made while its capture's session was not the known one yet
+        assert seen == [False, False]
+
+    def test_an_installed_tracer_takes_the_spans_of_a_capture(self, capture):
+        t = tracing.install()
+        capture.start()
+        with tracing.span("mine"):
+            pass
+        capture.stop()
+        assert [e["name"] for e in t.events] == ["mine"]
+        assert tracing.last_capture() is None
+
+    def test_off_path_is_the_shared_null_context(self, capture):
+        assert tracing.span("x") is tracing.span("y", step=1)
+
+
+class TestLoaderSpans:
+    """The loader names its own work (docs/OBSERVABILITY.md, span
+    table)."""
+
+    class DS:
+        def __len__(self):
+            return 24
+
+        def __getitem__(self, i):
+            return np.full((4,), i, np.float32)
+
+    def batches(self, **kw):
+        from tpu_syncbn.data import DataLoader, device_prefetch
+
+        loader = DataLoader(self.DS(), batch_size=4, **kw)
+        return [np.asarray(b) for b in device_prefetch(iter(loader))]
+
+    @pytest.mark.parametrize("num_workers", [0, 3])
+    def test_a_capture_sees_one_build_per_batch_and_changes_no_batch(
+            self, capture, num_workers):
+        plain = self.batches(num_workers=num_workers)
+        assert tracing.last_capture() is None
+        capture.start()
+        traced = self.batches(num_workers=num_workers)
+        capture.stop()
+        assert len(traced) == len(plain) == 6
+        for a, b in zip(plain, traced):
+            np.testing.assert_array_equal(a, b)
+
+        ring = tracing.last_capture()
+        builds = ring.spans("loader.build")
+        assert sorted(b[5]["seq"] for b in builds) == list(range(6))
+        workers = max(num_workers, 1)
+        assert all(b[5]["worker"] == b[5]["seq"] % workers for b in builds)
+        collates = {c[5]["parent_id"]: c
+                    for c in ring.spans("loader.collate")}
+        assert len(collates) == 6  # one a build, and no other child
+        assert {e["name"] for e in ring.recent_events()} <= {
+            "loader.build", "loader.collate", "loader.fetch", "data_wait",
+            "h2d"}
+        for b in builds:
+            collate = collates[b[5]["span_id"]]
+            assert collate[4] == b[4]  # on the building thread
+            # the samples come first: what of the build is not collation
+            assert b[1] <= collate[1] <= collate[2] <= b[2]
+        # every fetch that found the stream over closed its span too
+        assert len(ring.spans("data_wait")) > 6
+        h2d = ring.spans("h2d")
+        assert [s[5]["bytes"] for s in h2d] == [4 * 4 * 4] * 6
+        assert all("parent_id" not in s[5] for s in h2d)
+        if num_workers:
+            waits = {s[5]["span_id"] for s in ring.spans("data_wait")}
+            fetches = [f for f in ring.spans("loader.fetch")
+                       if "seq" in f[5]]
+            assert [f[5]["seq"] for f in fetches] == list(range(6))
+            for f in fetches:
+                assert f[5]["worker"] == f[5]["seq"] % num_workers
+                assert f[5]["parent_id"] in waits
+                assert f[5]["depth_before"] >= 0 and f[5]["depth"] >= 0
+                assert f[4] == threading.get_ident()
+            assert all("parent_id" not in b[5] for b in builds)
+
+    def test_the_fetch_span_and_the_gauge_share_one_depth_sample(
+            self, monkeypatch):
+        from tpu_syncbn.data import DataLoader, loader as loader_mod
+
+        reads = []
+        real = loader_mod._queue_depth
+
+        def counted(queues):
+            reads.append(1)
+            return real(queues)
+
+        monkeypatch.setattr(loader_mod, "_queue_depth", counted)
+        assert len(list(DataLoader(self.DS(), 4, num_workers=2))) == 6
+        assert reads == []  # everything off: the depth is never read
+        telemetry.set_enabled(True)
+        t = tracing.install()
+        assert len(list(DataLoader(self.DS(), 4, num_workers=2))) == 6
+        # per batch: one read when the wait begins (the span's alone) and
+        # one when it ends (the span's and the gauge's); the fetch that
+        # finds the epoch over reads once
+        assert len(reads) == 2 * 6 + 1
+        fetches = [s for s in t.spans("loader.fetch") if "seq" in s[5]]
+        assert telemetry.snapshot()["gauges"]["loader.queue_depth"] == \
+            fetches[-1][5]["depth"]
+
+
+class TestTrainerSpansAndScopes:
+    def _dp(self):
+        return parallel.DataParallel(
+            tnn.convert_sync_batchnorm(_Net(nnx.Rngs(0))),
+            optax.sgd(0.1), _loss,
+        )
+
+    def test_train_step_span_counts_the_calls(self):
+        dp = self._dp()
+        batch = jnp.ones((16, 8), jnp.float32)
+        dp.train_step(batch)  # tracing off: nothing recorded, still counted
+        t = tracing.install()
+        dp.train_step(batch)
+        dp.train_steps(batch, 2)
+        steps = t.spans("train_step")
+        assert [s[5]["step"] for s in steps] == [2, 3]
+        assert "n_steps" not in steps[0][5] and steps[1][5]["n_steps"] == 2
+        assert all("parent_id" not in s[5] for s in steps)
+
+    def test_the_lowered_step_carries_the_scope_names(self):
+        dp = self._dp()
+        text = dp.lowered_train_step(
+            jnp.ones((16, 8), jnp.float32)).as_text(debug_info=True)
+        for scope in ("forward_backward/", "grad_allreduce/", "optimizer/",
+                      "monitors/", "jvp(syncbn)/stats/", "jvp(syncbn)/psum/",
+                      "jvp(syncbn)/normalize/",
+                      # the backward pass keeps the forward's names
+                      "forward_backward/transpose(jvp(syncbn))/normalize/"):
+            assert scope in text, scope
 
 
 # ---------------------------------------------------------- export/merge

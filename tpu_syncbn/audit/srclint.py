@@ -409,9 +409,14 @@ def check_donate_after_use(
         donated: dict[str, int] = {}  # dotted expr -> donating lineno
         statements = list(_statements_in_order(fdef))
         for stmt in statements:
-            calls = [n for n in ast.walk(stmt) if isinstance(n, ast.Call)]
+            # a compound statement's own expressions only: its nested
+            # statements come up on their own, in order (a dispatch
+            # inside a ``with`` is rebound by the assignment that
+            # makes it, not donated by the ``with``)
+            nodes = list(_walk_own(stmt))
+            calls = [n for n in nodes if isinstance(n, ast.Call)]
             # 1. reads of already-donated buffers in this statement
-            for node in ast.walk(stmt):
+            for node in nodes:
                 dotted = None
                 if isinstance(node, ast.Attribute) \
                         and isinstance(node.ctx, ast.Load):
@@ -493,6 +498,17 @@ def _statements_in_order(fdef: ast.AST) -> Iterable[ast.stmt]:
             for handler in getattr(stmt, "handlers", []) or []:
                 yield from rec(handler.body)
     yield from rec(fdef.body)
+
+
+def _walk_own(stmt: ast.stmt) -> Iterable[ast.AST]:
+    """``ast.walk`` over ``stmt`` without the statements nested in it
+    (the bodies ``_statements_in_order`` yields separately)."""
+    todo: list[ast.AST] = [stmt]
+    while todo:
+        node = todo.pop()
+        yield node
+        todo.extend(child for child in ast.iter_child_nodes(node)
+                    if not isinstance(child, (ast.stmt, ast.ExceptHandler)))
 
 
 def _assigned_exprs(stmt: ast.stmt) -> list[str]:

@@ -30,7 +30,7 @@ import numpy as np
 from tpu_syncbn.data.dataset import Dataset
 from tpu_syncbn.data.sampler import Sampler, SequentialSampler
 from tpu_syncbn.obs import stepstats as obs_stepstats
-from tpu_syncbn.obs import telemetry
+from tpu_syncbn.obs import telemetry, tracing
 
 
 class WorkerError(RuntimeError):
@@ -70,6 +70,19 @@ def get_worker_info() -> WorkerInfo | None:
 # epoch so batches from an abandoned iteration are dropped, not yielded.
 
 
+def _build_batch(dataset, collate_fn, idxs, *, seq: int, worker: int):
+    """One batch, where every worker model builds it: the samples
+    (dataset + transforms), then the collation. Under tracing
+    (``obs.tracing``) a ``loader.build`` span with ``loader.collate``
+    as its child, on the building thread (what of the build is not
+    collation is the samples); a process worker records in its own
+    process."""
+    with tracing.span("loader.build", seq=seq, worker=worker):
+        samples = [dataset[i] for i in idxs]
+        with tracing.span("loader.collate"):
+            return collate_fn(samples)
+
+
 def _persistent_process_worker(
     wid, num_workers, dataset, collate_fn, worker_init_fn, index_q, out_q
 ):
@@ -97,7 +110,8 @@ def _persistent_process_worker(
         _, epoch, seq, idxs = item
         try:
             out_q.put(("ok", epoch, seq,
-                       collate_fn([dataset[i] for i in idxs])))
+                       _build_batch(dataset, collate_fn, idxs,
+                                    seq=seq, worker=wid)))
         except Exception:
             out_q.put(("err", epoch, seq, traceback.format_exc()))
 
@@ -133,50 +147,81 @@ def _consume_ordered(out_queues, dispatch_error, *, epoch=0, idle_check=None):
     consumer spent inside this generator waiting on workers — queue
     starvation shows up here), a ``loader.queue_depth`` gauge sampled at
     each yield (0 with a step-bound consumer means the loader is the
-    bottleneck), and a ``loader.batches`` counter."""
+    bottleneck), and a ``loader.batches`` counter.
+
+    Tracing (when on): one ``loader.fetch`` span per batch on the
+    consumer's thread, from resuming to having the batch in hand, with
+    its ``seq`` and ``worker`` and the batches buffered across all
+    queues when the wait began (``depth_before``) and when it ended
+    (``depth``, the gauge's sample): a wait that ends with ``depth`` > 0
+    waited for ITS worker while others had batches ready."""
     n = len(out_queues)
     done = [False] * n
     seq = 0
-    t_resume = time.perf_counter()
-    while not all(done):
-        wid = seq % n
-        if done[wid]:
-            seq += 1
-            continue
-        try:
-            item = out_queues[wid].get(timeout=0.05)
-        except queue.Empty:
-            if dispatch_error:
-                raise dispatch_error[0]
-            item = idle_check(wid) if idle_check is not None else None
-            if item is None:
+
+    def take():
+        """The next batch in dispatch order as (seq, worker, payload);
+        None once every worker has ended its epoch."""
+        nonlocal seq
+        while not all(done):
+            wid = seq % n
+            if done[wid]:
+                seq += 1
                 continue
-        tag = item[0]
-        if tag == "init_err":
-            raise WorkerError(f"worker {wid} init failed:\n{item[1]}")
-        if item[1] != epoch:
-            continue  # stale output from an abandoned iteration: drop
-        if tag == "epoch_end":
-            done[wid] = True
+            try:
+                item = out_queues[wid].get(timeout=0.05)
+            except queue.Empty:
+                if dispatch_error:
+                    raise dispatch_error[0]
+                item = idle_check(wid) if idle_check is not None else None
+                if item is None:
+                    continue
+            tag = item[0]
+            if tag == "init_err":
+                raise WorkerError(f"worker {wid} init failed:\n{item[1]}")
+            if item[1] != epoch:
+                continue  # stale output from an abandoned iteration: drop
+            if tag == "epoch_end":
+                done[wid] = True
+                seq += 1
+                continue
+            _, _, got_seq, payload = item
+            assert got_seq == seq, f"order violation: {got_seq} != {seq}"
+            if tag == "err":
+                if isinstance(payload, BaseException):
+                    raise payload  # thread worker: original exception object
+                raise WorkerError(f"error in worker {wid}:\n{payload}")
             seq += 1
-            continue
-        _, _, got_seq, payload = item
-        assert got_seq == seq, f"order violation: {got_seq} != {seq}"
-        if tag == "err":
-            if isinstance(payload, BaseException):
-                raise payload  # thread worker: original exception object
-            raise WorkerError(f"error in worker {wid}:\n{payload}")
-        if telemetry.enabled():
+            return got_seq, wid, payload
+        return None
+
+    while True:
+        t_resume = time.perf_counter()
+        record = telemetry.enabled()
+        tracer = tracing.get()
+        # begin/end, not a with block: the span has to close before the
+        # yield, and what it found is known only at its end
+        token = None if tracer is None else tracer.begin(
+            "loader.fetch", depth_before=_queue_depth(out_queues))
+        found: dict = {}
+        try:
+            got = take()
+            if got is not None and (record or token is not None):
+                # one sample for the span and the gauge
+                found = {"seq": got[0], "worker": got[1],
+                         "depth": _queue_depth(out_queues)}
+        finally:
+            if token is not None:
+                tracer.end(token, **found)
+        if got is None:
+            return
+        if record:
             telemetry.observe(
                 "loader.fetch_wait_s", time.perf_counter() - t_resume
             )
-            telemetry.set_gauge(
-                "loader.queue_depth", _queue_depth(out_queues)
-            )
+            telemetry.set_gauge("loader.queue_depth", found["depth"])
             telemetry.count("loader.batches")
-        yield payload
-        t_resume = time.perf_counter()
-        seq += 1
+        yield got[2]
 
 
 def _close_pool(pool) -> None:
@@ -299,8 +344,9 @@ class DataLoader:
 
     def __iter__(self):
         if self.num_workers == 0:
-            for idxs in self._batches_of_indices():
-                yield self.collate_fn([self.dataset[i] for i in idxs])
+            for seq, idxs in enumerate(self._batches_of_indices()):
+                yield _build_batch(self.dataset, self.collate_fn, idxs,
+                                   seq=seq, worker=0)
             return
         if self.worker_type == "process":
             yield from self._iter_processes()
@@ -463,7 +509,8 @@ class DataLoader:
                 try:
                     out = (
                         "ok", 0, seq,
-                        self.collate_fn([self.dataset[i] for i in idxs]),
+                        _build_batch(self.dataset, self.collate_fn, idxs,
+                                     seq=seq, worker=wid),
                     )
                 except Exception as e:  # same-process: keep the object
                     out = ("err", 0, seq, e)
@@ -616,6 +663,15 @@ def staged_iter(iterator, *, slots: int = 3, slot_mb: int = 64):
         ring.close()
 
 
+def _traced_bytes(batch) -> dict:
+    """The ``h2d`` span's ``bytes`` (the summed size of the batch's
+    leaves); nothing is computed while tracing is off."""
+    if tracing.get() is None:
+        return {}
+    return {"bytes": sum(int(getattr(leaf, "nbytes", 0))
+                         for leaf in jax.tree_util.tree_leaves(batch))}
+
+
 def device_prefetch(
     iterator,
     *,
@@ -695,16 +751,17 @@ def device_prefetch(
     def staged(it):
         """Fetch + stage the next batch (or K-chunk), instrumented
         (obs.stepstats): ``data_wait`` is the blocking wait on the host
-        iterator, ``h2d`` the stack + device_put *dispatch* (the DMA
-        itself is async — overlap is the point, so the span measures
-        dispatch, not transfer completion). The terminal StopIteration
-        fetch is NOT a wait sample (stepstats.timed_fetch) — recording
-        it would add one end-of-epoch outlier per epoch."""
+        iterator, ``h2d`` the device_put *dispatch* of ``bytes`` bytes
+        (the DMA itself is async — overlap is the point, so the span
+        measures dispatch, not transfer completion). The terminal
+        StopIteration fetch is NOT a wait sample (stepstats.timed_fetch)
+        — recording it would add one end-of-epoch outlier per epoch."""
         if scan_steps == 1:
             batch = obs_stepstats.timed_fetch(
                 it, "data_wait", "loader.data_wait_s"
             )
-            with obs_stepstats.timed_span("h2d", "loader.h2d_s"):
+            with obs_stepstats.timed_span("h2d", "loader.h2d_s",
+                                          **_traced_bytes(batch)):
                 return put(batch)
         # K-slot staging buffer, filled incrementally: each batch is
         # copied into its slot AT FETCH TIME, so the chunk owns its
@@ -744,13 +801,14 @@ def device_prefetch(
                     )
                 s[count] = l
             count += 1
-        with obs_stepstats.timed_span("h2d", "loader.h2d_s"):
+        stacked = jax.tree_util.tree_unflatten(
+            treedef,
+            [s if count == scan_steps else s[:count] for s in slots],
+        )
+        with obs_stepstats.timed_span("h2d", "loader.h2d_s",
+                                      **_traced_bytes(stacked)):
             if telemetry.enabled():
                 telemetry.set_gauge("loader.stage_depth", count)
-            stacked = jax.tree_util.tree_unflatten(
-                treedef,
-                [s if count == scan_steps else s[:count] for s in slots],
-            )
             return put(stacked)
 
     buf: list = []

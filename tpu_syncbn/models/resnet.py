@@ -144,22 +144,28 @@ class ResNet(nnx.Module):
 
     def features(self, x: jax.Array) -> list[jax.Array]:
         """Per-stage feature maps (C2..C5) — consumed by FPN (RetinaNet)."""
-        if self.dtype is not None:
-            x = x.astype(self.dtype)
-        x = nnx.relu(self.stem_bn(self.stem_conv(x)))
-        if not self.small_input:
-            x = nnx.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        # named scopes: what the device trace's operations are called
+        # (stem, layer1..4 / block<i>, fc), forward and backward alike
+        with jax.named_scope("stem"):
+            if self.dtype is not None:
+                x = x.astype(self.dtype)
+            x = nnx.relu(self.stem_bn(self.stem_conv(x)))
+            if not self.small_input:
+                x = nnx.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         feats = []
-        for stage in self.stages:
-            for blk in stage:
-                x = blk(x)
+        for i, stage in enumerate(self.stages):
+            with jax.named_scope(f"layer{i + 1}"):
+                for b, blk in enumerate(stage):
+                    with jax.named_scope(f"block{b}"):
+                        x = blk(x)
             feats.append(x)
         return feats
 
     def __call__(self, x: jax.Array) -> jax.Array:
         x = self.features(x)[-1]
-        x = x.mean(axis=(1, 2))  # global average pool
-        return self.fc(x)
+        with jax.named_scope("fc"):
+            x = x.mean(axis=(1, 2))  # global average pool
+            return self.fc(x)
 
 
 def resnet18(**kw) -> ResNet:

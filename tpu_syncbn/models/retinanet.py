@@ -98,14 +98,16 @@ class RetinaHead(nnx.Module):
     def __call__(self, feats):
         cls_all, box_all = [], []
         for f in feats:
-            c = f
-            for conv in self.cls_tower:
-                c = nnx.relu(conv(c))
-            cls = self.cls_out(c)
-            b = f
-            for conv in self.box_tower:
-                b = nnx.relu(conv(b))
-            box = self.box_out(b)
+            with jax.named_scope("head_cls"):
+                c = f
+                for conv in self.cls_tower:
+                    c = nnx.relu(conv(c))
+                cls = self.cls_out(c)
+            with jax.named_scope("head_box"):
+                b = f
+                for conv in self.box_tower:
+                    b = nnx.relu(conv(b))
+                box = self.box_out(b)
             n = f.shape[0]
             cls_all.append(cls.reshape(n, -1, self.num_classes))
             box_all.append(box.reshape(n, -1, 4))
@@ -150,7 +152,8 @@ class RetinaNet(nnx.Module):
 
     def __call__(self, images: jax.Array):
         feats = self.backbone.features(images)  # C2..C5
-        p = self.fpn(feats[1], feats[2], feats[3])
+        with jax.named_scope("fpn"):
+            p = self.fpn(feats[1], feats[2], feats[3])
         return self.head(p)
 
     def loss(self, images, gt_boxes, gt_labels, gt_valid):
@@ -160,18 +163,23 @@ class RetinaNet(nnx.Module):
         anchors = self.anchors[...]
 
         def one_image(logits, deltas, boxes, labels, valid):
-            matched, _ = det.match_anchors(anchors, boxes, valid)
-            fg = matched >= 0
-            ignore = matched == -2
-            # classification targets: one-hot of matched GT class, zeros for bg
-            safe = jnp.clip(matched, 0)
-            cls_t = jax.nn.one_hot(labels[safe], self.num_classes) * fg[:, None]
-            cls_loss = det.sigmoid_focal_loss(logits, cls_t)
-            cls_loss = jnp.where(ignore[:, None], 0.0, cls_loss).sum()
-            # box targets for fg anchors
-            box_t = det.box_encode(boxes[safe], anchors)
-            box_loss = det.smooth_l1(deltas, box_t).sum(-1)
-            box_loss = jnp.where(fg, box_loss, 0.0).sum()
+            with jax.named_scope("anchors_match"):
+                matched, _ = det.match_anchors(anchors, boxes, valid)
+                fg = matched >= 0
+                ignore = matched == -2
+                safe = jnp.clip(matched, 0)
+            with jax.named_scope("focal"):
+                # classification targets: one-hot of matched GT class,
+                # zeros for bg
+                cls_t = (jax.nn.one_hot(labels[safe], self.num_classes)
+                         * fg[:, None])
+                cls_loss = det.sigmoid_focal_loss(logits, cls_t)
+                cls_loss = jnp.where(ignore[:, None], 0.0, cls_loss).sum()
+            with jax.named_scope("smooth_l1"):
+                # box targets for fg anchors
+                box_t = det.box_encode(boxes[safe], anchors)
+                box_loss = det.smooth_l1(deltas, box_t).sum(-1)
+                box_loss = jnp.where(fg, box_loss, 0.0).sum()
             n_fg = jnp.maximum(fg.sum(), 1)
             return cls_loss / n_fg, box_loss / n_fg
 

@@ -160,23 +160,25 @@ class BatchNorm(nnx.Module):
         rm = self.running_mean[...] if self.track_running_stats else None
         rv = self.running_var[...] if self.track_running_stats else None
         nbt = self.num_batches_tracked[...] if self.track_running_stats else None
-        y, (new_rm, new_rv, new_nbt) = bn_ops.batch_norm_train(
-            x,
-            rm,
-            rv,
-            nbt,
-            w,
-            b,
-            momentum=self.momentum,
-            eps=self.eps,
-            channel_axis=self.channel_axis,
-            axis_name=self._sync_axis(),
-            group_size=self.group_size if self._sync_axis() else None,
-            stats_compress=(
-                self.stats_compress if self._sync_axis() else "none"
-            ),
-            mask=mask,
-        )
+        sync_axis = self._sync_axis()
+        # the names the device trace's operations carry: `syncbn` with
+        # `stats`, `psum` and `normalize` inside it (ops.batch_norm)
+        with jax.named_scope("syncbn" if sync_axis else "bn"):
+            y, (new_rm, new_rv, new_nbt) = bn_ops.batch_norm_train(
+                x,
+                rm,
+                rv,
+                nbt,
+                w,
+                b,
+                momentum=self.momentum,
+                eps=self.eps,
+                channel_axis=self.channel_axis,
+                axis_name=sync_axis,
+                group_size=self.group_size if sync_axis else None,
+                stats_compress=self.stats_compress if sync_axis else "none",
+                mask=mask,
+            )
         if self.track_running_stats:
             # .value assignment (not var[...] = x): portable across
             # flax versions whose Variable.__setitem__ writes through to

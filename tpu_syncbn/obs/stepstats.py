@@ -49,7 +49,8 @@ from tpu_syncbn.obs import telemetry, tracing
 @contextlib.contextmanager
 def timed_span(span_name: str, hist_name: str | None = None, **args):
     """One context manager for the span + histogram pair: a tracing span
-    named ``span_name`` (when a tracer is installed) and a telemetry
+    named ``span_name`` (when tracing is on: a tracer installed, or a
+    profiler capture running — ``tracing.get``) and a telemetry
     histogram observation into ``hist_name`` seconds (when telemetry is
     enabled). With both off this is a bare yield — hot-loop safe."""
     tracer = tracing.get()

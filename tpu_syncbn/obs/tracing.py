@@ -14,14 +14,27 @@ into watchdog stall dumps and divergence-restore log lines
 (:func:`latest_open_span_id`), so a RESILIENCE event log and a Perfetto
 timeline can be joined on it.
 
-Like telemetry, the disabled path is near-free: with no tracer installed
-(:func:`install` not called), the module-level :func:`span` returns a
-shared ``nullcontext`` — no clock reads, no allocation.
+Two ways on. :func:`install` makes a tracer the process tracer until
+:func:`uninstall` (``bench.py --trace``, the flight recorder). With none
+installed, spans record for as long as a ``jax.profiler`` capture runs
+in this process (:func:`capture_active`): the first span site that sees
+the capture makes a :class:`RingTracer`, the *capture tracer*, which
+stays readable through :func:`last_capture` after the capture ends and
+is replaced when the next capture starts. A device profile of this
+program — ``chipbench``'s traced run, ``POST /profilez``,
+``utils.profiler_trace`` — so comes with the host's own account of the
+same seconds, on the clock (``time.perf_counter``) that the profile's
+reduction aligns with the device trace.
 
-The optional ``jax_bridge`` wraps every span in
-``jax.profiler.TraceAnnotation`` as well, so host spans line up with
-device activity inside a ``jax.profiler`` trace
-(``utils.profiler_trace``) when both are active.
+Like telemetry, the disabled path is near-free: with no tracer installed
+and no capture running, the module-level :func:`span` returns a shared
+``nullcontext`` after two attribute tests — no clock read, no lock,
+nothing recorded or kept (the call's own keyword dict is all it makes).
+
+A span records its wall time and its thread's CPU time (``args.cpu_us``,
+from ``time.thread_time``): a span whose wall time is far above its CPU
+time was blocked (the GIL, a queue, the runtime); one whose two times
+agree was computing.
 """
 
 from __future__ import annotations
@@ -29,6 +42,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -41,21 +55,22 @@ class Tracer:
     """Collects Chrome trace events in memory; thread-safe (each thread
     keeps its own span stack, event append is locked)."""
 
-    def __init__(self, *, jax_bridge: bool = False):
-        self._t0 = time.perf_counter()
+    def __init__(self):
+        #: ``time.perf_counter()`` at construction: every event's ``ts``
+        #: is microseconds since then (:meth:`spans` adds it back)
+        self.t0 = time.perf_counter()
         self._lock = threading.Lock()
         self._tls = threading.local()
         # insertion-ordered map of currently-open span ids → name; the
         # newest entry is what a watchdog thread should correlate with
         self._open: dict[int, str] = {}
         self._next_id = 1
-        self.jax_bridge = bool(jax_bridge)
         self.events: list[dict] = []
 
     # -- internals --------------------------------------------------------
 
     def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+        return (time.perf_counter() - self.t0) * 1e6
 
     def _stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -69,11 +84,11 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Record a complete event around the block; yields the span id.
-        Nest freely (including across threads — each thread nests its own
-        stack). ``args`` must be JSON-serializable."""
+    def begin(self, name: str, **args) -> tuple:
+        """Open a span on this thread and return its token for
+        :meth:`end`. For a span that cannot be a ``with`` block (a
+        generator that must close it before its ``yield``); everything
+        else uses :meth:`span`. ``token[0]`` is the span id."""
         st = self._stack()
         parent = st[-1] if st else None
         with self._lock:
@@ -81,41 +96,54 @@ class Tracer:
             self._next_id += 1
             self._open[sid] = name
         st.append(sid)
-        bridge = None
-        if self.jax_bridge:
-            try:
-                import jax
+        # the CPU clock is read inside the wall clock's interval, so a
+        # span's CPU time never exceeds its wall time
+        t0 = time.perf_counter()
+        return sid, name, parent, args, time.thread_time(), t0
 
-                bridge = jax.profiler.TraceAnnotation(name)
-                bridge.__enter__()
-            except Exception:
-                bridge = None
-        t0 = self._now_us()
-        try:
-            yield sid
-        finally:
-            dur = self._now_us() - t0
-            if bridge is not None:
-                with contextlib.suppress(Exception):
-                    bridge.__exit__(None, None, None)
+    def end(self, token: tuple, **more) -> None:
+        """Close the span ``token`` opened on this thread and record its
+        complete event; ``more`` joins the ``args`` given at
+        :meth:`begin` (what is known only at the end)."""
+        cpu1 = time.thread_time()
+        t1 = time.perf_counter()
+        sid, name, parent, args, cpu0, t0 = token
+        st = self._stack()
+        if st and st[-1] == sid:
             st.pop()
-            ev_args: dict = {"span_id": sid}
-            if parent is not None:
-                ev_args["parent_id"] = parent
-            ev_args.update(args)
-            event = {
-                "name": name,
-                "ph": "X",
-                "ts": round(t0, 3),
-                "dur": round(dur, 3),
-                "pid": os.getpid(),
-                "tid": threading.get_ident(),
-                "cat": "tpu_syncbn",
-                "args": ev_args,
-            }
-            with self._lock:
-                self._open.pop(sid, None)
-                self.events.append(event)
+        ev_args: dict = {"span_id": sid}
+        if parent is not None:
+            ev_args["parent_id"] = parent
+        ev_args["cpu_us"] = round((cpu1 - cpu0) * 1e6, 3)
+        ev_args.update(args)
+        ev_args.update(more)
+        event = {
+            "name": name,
+            "ph": "X",
+            "ts": round((t0 - self.t0) * 1e6, 3),
+            "dur": round((t1 - t0) * 1e6, 3),
+            "pid": os.getpid(),
+            "tid": threading.get_ident(),
+            "cat": "tpu_syncbn",
+            "args": ev_args,
+        }
+        with self._lock:
+            self._open.pop(sid, None)
+            self.events.append(event)
+
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Record a complete event around the block; yields the span id.
+        Nest freely (including across threads — each thread nests its own
+        stack). ``args`` must be JSON-serializable. Besides them the
+        event carries ``span_id``, ``parent_id`` (the span open on this
+        thread when this one began) and ``cpu_us``, this thread's CPU
+        time inside the block."""
+        token = self.begin(name, **args)
+        try:
+            yield token[0]
+        finally:
+            self.end(token)
 
     def instant(self, name: str, **args) -> None:
         """Record an instant event (``ph: "i"``) — a point-in-time marker
@@ -171,6 +199,24 @@ class Tracer:
         if limit is not None and len(events) > limit:
             events = events[-limit:]
         return events
+
+    def spans(self, name: str | None = None) -> list[tuple]:
+        """The recorded complete events as ``(name, t0_s, t1_s, cpu_s,
+        tid, args)``, oldest first, ``t0_s``/``t1_s`` in absolute
+        ``time.perf_counter()`` seconds — the clock and the form of a
+        host span that ``chipbench/trace_reduce.align`` lays onto a
+        device trace. ``name`` keeps only the spans of that name."""
+        with self._lock:
+            events = list(self.events)
+        out = []
+        for ev in events:
+            if ev["ph"] != "X" or (name is not None and ev["name"] != name):
+                continue
+            t0 = self.t0 + ev["ts"] / 1e6
+            out.append((ev["name"], t0, t0 + ev["dur"] / 1e6,
+                        ev["args"].get("cpu_us", 0.0) / 1e6, ev["tid"],
+                        ev["args"]))
+        return out
 
     # -- queries ----------------------------------------------------------
 
@@ -241,11 +287,18 @@ class RingTracer(Tracer):
 
 
 # ---------------------------------------------------------------------------
-# module-level installed tracer
+# the process tracer: installed, or made for a running profiler capture
 
 
 _installed: Tracer | None = None
 _install_lock = threading.Lock()
+
+#: events the capture tracer keeps: some ten spans a step, so minutes
+CAPTURE_CAPACITY = 16_384
+_capture: RingTracer | None = None  # the newest capture's tracer
+_capture_session = None  # the profiler session it was made for, until
+#                          that session has been seen to have ended
+_profile_state = None  # jax's, once its profiler module is imported
 
 
 def install(tracer: Tracer | None = None) -> Tracer:
@@ -267,46 +320,97 @@ def uninstall() -> Tracer | None:
         return t
 
 
+def _profile_session():
+    """The ``jax.profiler`` capture running in this process, or None.
+    jax 0.9.0 has no public query: this is the one place that reads
+    ``jax._src.profiler._profile_state`` (tests/test_obs.py goes red
+    when it moves). Never imports jax: a process that has not imported
+    its profiler has no capture."""
+    global _profile_state
+    state = _profile_state
+    if state is None:
+        mod = sys.modules.get("jax._src.profiler")
+        state = _profile_state = getattr(mod, "_profile_state", None)
+    return getattr(state, "profile_session", None)
+
+
+def capture_active() -> bool:
+    """Whether a ``jax.profiler`` capture is running in this process
+    (``start_trace``/``stop_trace``, ``utils.profiler_trace``,
+    ``POST /profilez``) — seen from any thread."""
+    return _profile_session() is not None
+
+
+def last_capture() -> RingTracer | None:
+    """The tracer of the newest profiler capture during which a span
+    site ran with no tracer installed: still recording while that
+    capture runs, readable after it (``.spans()``, ``.save(path)``),
+    replaced when a span site sees the next capture. None before the
+    first."""
+    return _capture
+
+
 def get() -> Tracer | None:
-    return _installed
+    """The tracer spans record into now: the installed one, else the
+    capture tracer while a profiler capture runs, else None."""
+    global _capture, _capture_session
+    t = _installed
+    if t is not None:
+        return t
+    session = _profile_session()
+    if session is _capture_session:
+        return _capture if session is not None else None
+    # a capture started or ended since the last span site: once each
+    with _install_lock:
+        session = _profile_session()
+        if session is not _capture_session:
+            # the ring first, the session last: a thread on the
+            # lock-free path above that sees the new session must find
+            # its ring already there. Holding the running session keeps
+            # its identity from being handed to the next one; it is let
+            # go of when it has ended
+            if session is not None:
+                _capture = RingTracer(CAPTURE_CAPACITY)
+            _capture_session = session
+    return _capture if session is not None else None
 
 
 def span(name: str, **args):
-    """Context manager: a span on the installed tracer, or a shared
-    no-op context when tracing is off."""
-    t = _installed
+    """Context manager: a span on the process tracer (:func:`get`), or a
+    shared no-op context when tracing is off."""
+    t = get()
     if t is None:
         return _NULL
     return t.span(name, **args)
 
 
 def instant(name: str, **args) -> None:
-    t = _installed
+    t = get()
     if t is not None:
         t.instant(name, **args)
 
 
 def flow_start(name: str, flow_id: int, **args) -> None:
-    """Flow-arrow start on the installed tracer (no-op when off)."""
-    t = _installed
+    """Flow-arrow start on the process tracer (no-op when off)."""
+    t = get()
     if t is not None:
         t.flow_start(name, flow_id, **args)
 
 
 def flow_end(name: str, flow_id: int, **args) -> None:
-    """Flow-arrow end on the installed tracer (no-op when off)."""
-    t = _installed
+    """Flow-arrow end on the process tracer (no-op when off)."""
+    t = get()
     if t is not None:
         t.flow_end(name, flow_id, **args)
 
 
 def current_span_id() -> int | None:
-    t = _installed
+    t = get()
     return t.current_span_id() if t is not None else None
 
 
 def latest_open_span_id() -> int | None:
-    t = _installed
+    t = get()
     return t.latest_open_span_id() if t is not None else None
 
 

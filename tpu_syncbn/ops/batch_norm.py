@@ -224,21 +224,24 @@ def sync_moments(
     elements, supporting the uneven/empty-shard contract
     (``_functions.py:50-57``).
     """
-    if mask is None:
-        s, sq, count = batch_norm_stats(x, channel_axis=channel_axis)
-    else:
-        axes = _reduction_axes(x.ndim, channel_axis)
-        xf = x.astype(jnp.float32)
-        mf = jnp.broadcast_to(mask, x.shape).astype(jnp.float32)
-        s = jnp.sum(xf * mf, axis=axes)
-        sq = jnp.sum(xf * xf * mf, axis=axes)
-        count = jnp.sum(mf, axis=axes)  # per-channel (all equal when the
-        # mask has channel-axis size 1); reduce_moments handles either form
+    with jax.named_scope("stats"):
+        if mask is None:
+            s, sq, count = batch_norm_stats(x, channel_axis=channel_axis)
+        else:
+            axes = _reduction_axes(x.ndim, channel_axis)
+            xf = x.astype(jnp.float32)
+            mf = jnp.broadcast_to(mask, x.shape).astype(jnp.float32)
+            s = jnp.sum(xf * mf, axis=axes)
+            sq = jnp.sum(xf * xf * mf, axis=axes)
+            count = jnp.sum(mf, axis=axes)  # per-channel (all equal when
+            # the mask has channel-axis size 1); reduce_moments handles
+            # either form
     if axis_name is not None:
-        return reduce_moments(
-            s, sq, count, axis_name, group_size=group_size,
-            mode=stats_compress,
-        )
+        with jax.named_scope("psum"):
+            return reduce_moments(
+                s, sq, count, axis_name, group_size=group_size,
+                mode=stats_compress,
+            )
     mean, var = moments_from_stats(s, sq, count)
     return mean, var, count
 
@@ -367,9 +370,10 @@ def batch_norm_train(
             group_size=group_size, stats_compress=stats_compress,
             mask=mask,
         )
-        y = batch_norm_elemt(
-            x, mean, var, weight, bias, eps, channel_axis=channel_axis
-        )
+        with jax.named_scope("normalize"):
+            y = batch_norm_elemt(
+                x, mean, var, weight, bias, eps, channel_axis=channel_axis
+            )
     if running_mean is None:
         return y, (None, None, None)
     # Buffers do not participate in autodiff (torch updates them in-place,

@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_syncbn import compat
 from tpu_syncbn.compat import shard_map
 from tpu_syncbn.obs import numerics as obs_numerics, stepstats as obs_stepstats
+from tpu_syncbn.obs import tracing as obs_tracing
 from tpu_syncbn.parallel import collectives
 from tpu_syncbn.parallel.collectives import pcast_varying as _pcast_varying
 from tpu_syncbn.runtime import distributed as dist
@@ -605,6 +606,9 @@ class DataParallel:
         # recompile-storm detector must see (a hot weight swap that
         # rebuilds the trainer re-pays it)
         self._first_dispatch_noted = False
+        # host-side count of step dispatches: the ``step`` of the
+        # ``train_step`` span (obs.tracing)
+        self._calls = 0
         from tpu_syncbn.parallel import scan_driver
 
         # n_steps -> scanned jit (FIFO-bounded, hit/miss/eviction counted)
@@ -654,9 +658,10 @@ class DataParallel:
         # (With the checker off — pallas mode — grads are local anyway.)
         if self._check_vma:
             params = _pcast_varying(params, self.axis_name)
-        (loss, (metrics, new_rest, numx)), grads = jax.value_and_grad(
-            lossed, has_aux=True
-        )(params, rest, batch)
+        with jax.named_scope("forward_backward"):
+            (loss, (metrics, new_rest, numx)), grads = jax.value_and_grad(
+                lossed, has_aux=True
+            )(params, rest, batch)
         return loss, metrics, new_rest, grads, numx
 
     def _gather_params(self, store):
@@ -864,7 +869,7 @@ class DataParallel:
                         g = collectives.psum(g, cross)
                     return g / self.world
 
-                with ccol_ctx as ccol:
+                with jax.named_scope("grad_allreduce"), ccol_ctx as ccol:
                     # the compressed reduce-scatters record their int8
                     # clip fraction / overflow headroom into the active
                     # collector (parallel.collectives)
@@ -880,18 +885,20 @@ class DataParallel:
                     # shards only: one scalar device-side psum (over the
                     # shard axis — the cross axes already hold the
                     # reduced value replicated) globalizes
-                    monitors.update(obs_stepstats.grad_monitors(
-                        gshard, shard_axis, sharded=True
-                    ))
-                updates, opt_state = self.optimizer.update(
-                    gshard, opt_state, pstore
-                )
-                if (self.divergence_guard == "halve_lr"
-                        and guard_in is not None):
-                    updates = jax.tree_util.tree_map(
-                        lambda u: u * guard_in["lr_scale"], updates
+                    with jax.named_scope("monitors"):
+                        monitors.update(obs_stepstats.grad_monitors(
+                            gshard, shard_axis, sharded=True
+                        ))
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = self.optimizer.update(
+                        gshard, opt_state, pstore
                     )
-                pstore = optax.apply_updates(pstore, updates)
+                    if (self.divergence_guard == "halve_lr"
+                            and guard_in is not None):
+                        updates = jax.tree_util.tree_map(
+                            lambda u: u * guard_in["lr_scale"], updates
+                        )
+                    pstore = optax.apply_updates(pstore, updates)
             else:
                 if self.monitors:
                     # per-replica grad norm BEFORE the all-reduce: the
@@ -902,7 +909,7 @@ class DataParallel:
                 # DDP gradient averaging: one compiler-scheduled
                 # all-reduce; the compressed paths record their int8
                 # clip fraction / overflow headroom into the collector
-                with obs_numerics.collect(
+                with jax.named_scope("grad_allreduce"), obs_numerics.collect(
                     enabled=bool(self.monitors)
                 ) as ccol:
                     if self._ef:
@@ -937,16 +944,18 @@ class DataParallel:
                         )
                     # post-pmean grads are replicated: pure arithmetic,
                     # no collective needed
-                    monitors.update(obs_stepstats.grad_monitors(grads))
-                updates, opt_state = self.optimizer.update(
-                    grads, opt_state, params
-                )
-                if (self.divergence_guard == "halve_lr"
-                        and guard_in is not None):
-                    updates = jax.tree_util.tree_map(
-                        lambda u: u * guard_in["lr_scale"], updates
+                    with jax.named_scope("monitors"):
+                        monitors.update(obs_stepstats.grad_monitors(grads))
+                with jax.named_scope("optimizer"):
+                    updates, opt_state = self.optimizer.update(
+                        grads, opt_state, params
                     )
-                pstore = optax.apply_updates(params, updates)
+                    if (self.divergence_guard == "halve_lr"
+                            and guard_in is not None):
+                        updates = jax.tree_util.tree_map(
+                            lambda u: u * guard_in["lr_scale"], updates
+                        )
+                    pstore = optax.apply_updates(params, updates)
 
             if self.monitors and numx:
                 # numerics drift/compression monitors (obs.numerics): the
@@ -955,10 +964,11 @@ class DataParallel:
                 # into ONE scalar psum. That single collective is the
                 # monitors' whole wire cost, pinned by the golden program
                 # contracts and tests/test_numerics.py's one-psum gate.
-                monitors.update(obs_numerics.cross_replica_monitors(
-                    numx, axis, disp_keys=("replica_grad_norm",),
-                    varying_cast=self._check_vma,
-                ))
+                with jax.named_scope("monitors"):
+                    monitors.update(obs_numerics.cross_replica_monitors(
+                        numx, axis, disp_keys=("replica_grad_norm",),
+                        varying_cast=self._check_vma,
+                    ))
 
             if guard_in is not None:
                 # exact skip of a non-finite step: params, optimizer
@@ -1005,17 +1015,19 @@ class DataParallel:
                 if self.monitors:
                     # post-broadcast (or by-construction-replicated)
                     # buffers: pure arithmetic yields replicated monitors
-                    monitors.update(obs_stepstats.state_health(
-                        rest, per_layer=self.monitors == "full"
-                    ))
+                    with jax.named_scope("monitors"):
+                        monitors.update(obs_stepstats.state_health(
+                            rest, per_layer=self.monitors == "full"
+                        ))
             else:
                 if self.monitors:
                     # per-replica buffers: reduce to the worst replica so
                     # the monitors stay legal replicated outputs
-                    monitors.update(obs_stepstats.state_health(
-                        rest, axis, reduce=True,
-                        per_layer=self.monitors == "full",
-                    ))
+                    with jax.named_scope("monitors"):
+                        monitors.update(obs_stepstats.state_health(
+                            rest, axis, reduce=True,
+                            per_layer=self.monitors == "full",
+                        ))
                 # re-stack for honest per-replica storage (P(axis) output:
                 # declare varying even when SyncBN stats are replicated)
                 if self._check_vma:
@@ -1072,14 +1084,17 @@ class DataParallel:
             n_steps if not stacked else key,
             lambda: self._build_train_steps(n_steps, stacked=stacked),
         )
-        (
-            self._param_store,
-            self.rest,
-            self.opt_state,
-            losses,
-            metrics,
-            monitors,
-        ) = fn(self._param_store, self.rest, self.opt_state, batch)
+        self._calls += 1
+        with obs_tracing.span("train_step", step=self._calls,
+                              n_steps=n_steps):
+            (
+                self._param_store,
+                self.rest,
+                self.opt_state,
+                losses,
+                metrics,
+                monitors,
+            ) = fn(self._param_store, self.rest, self.opt_state, batch)
         return StepOutput(loss=losses, metrics=metrics, monitors=monitors)
 
     def train_steps(self, batch, n_steps: int) -> StepOutput:
@@ -1266,14 +1281,19 @@ class DataParallel:
         """One optimizer step on a *global* batch (sharded or shardable
         along axis 0 across the mesh)."""
         t0 = time.perf_counter() if not self._first_dispatch_noted else None
-        (
-            self._param_store,
-            self.rest,
-            self.opt_state,
-            loss,
-            metrics,
-            monitors,
-        ) = self._train_step(self._param_store, self.rest, self.opt_state, batch)
+        self._calls += 1
+        # the enqueue: wall time against cpu_us says whether the host
+        # thread worked or waited (obs.tracing; off unless tracing is on)
+        with obs_tracing.span("train_step", step=self._calls):
+            (
+                self._param_store,
+                self.rest,
+                self.opt_state,
+                loss,
+                metrics,
+                monitors,
+            ) = self._train_step(
+                self._param_store, self.rest, self.opt_state, batch)
         if t0 is not None:
             # first dispatch = XLA compile (+ one execution, async on
             # real hardware): one compile.train event, time tagged
