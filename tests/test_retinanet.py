@@ -191,6 +191,243 @@ def test_matcher_tie_highest_gt_wins():
     assert int(matched[0]) == 1
 
 
+# -- assign_targets: gather-free target assignment -------------------------
+
+PUBLISHED_IMAGE, PUBLISHED_M, PUBLISHED_K = (800, 1344), 100, 80
+
+
+@pytest.fixture(scope="module")
+def published_anchors():
+    anchors = det.retinanet_anchors(PUBLISHED_IMAGE)
+    assert anchors.shape == (201_600, 4)
+    return anchors
+
+
+def _padded_gt(rng, n_valid):
+    """Boxes inside the image, at least 16 pixels a side, padded to
+    ``PUBLISHED_M`` with zero boxes (the benchmark's recipe for the
+    detection cell)."""
+    (h, w), m = PUBLISHED_IMAGE, PUBLISHED_M
+    x1 = rng.uniform(0, w - 16, m)
+    y1 = rng.uniform(0, h - 16, m)
+    x2 = x1 + rng.uniform(16, np.maximum(w - x1, 16))
+    y2 = y1 + rng.uniform(16, np.maximum(h - y1, 16))
+    valid = np.arange(m) < n_valid
+    boxes = np.stack([x1, y1, x2, y2], -1).astype(np.float32) * valid[:, None]
+    labels = rng.integers(0, PUBLISHED_K, m, dtype=np.int32)
+    return jnp.asarray(boxes), jnp.asarray(labels), jnp.asarray(valid)
+
+
+def _plain_box_iou(a, b):
+    lt = jnp.maximum(a[:, None, :2], b[None, :, :2])
+    rb = jnp.minimum(a[:, None, 2:], b[None, :, 2:])
+    wh = jnp.clip(rb - lt, 0.0)
+    inter = wh[..., 0] * wh[..., 1]
+    area_a = jnp.clip(a[:, 2] - a[:, 0], 0) * jnp.clip(a[:, 3] - a[:, 1], 0)
+    area_b = jnp.clip(b[:, 2] - b[:, 0], 0) * jnp.clip(b[:, 3] - b[:, 1], 0)
+    union = area_a[:, None] + area_b[None, :] - inter
+    return jnp.where(union > 0, inter / union, 0.0)
+
+
+def _plain_match(anchors, gt_boxes, gt_valid, high=0.5, low=0.4):
+    iou = jnp.where(gt_valid[None, :], _plain_box_iou(anchors, gt_boxes), -1.0)
+    best_gt = jnp.argmax(iou, axis=1)
+    best_iou = jnp.max(iou, axis=1)
+    matched = jnp.where(
+        best_iou >= high, best_gt, jnp.where(best_iou < low, -1, -2))
+    gt_best_iou = jnp.max(iou, axis=0)
+    is_best = ((iou >= gt_best_iou[None, :])
+               & (gt_valid & (gt_best_iou > 0))[None, :])
+    promote_to = gt_boxes.shape[0] - 1 - jnp.argmax(is_best[:, ::-1], axis=1)
+    return jnp.where(jnp.any(is_best, axis=1), promote_to, matched)
+
+
+def _plain_box_encode(boxes, anchors):
+    aw = anchors[..., 2] - anchors[..., 0]
+    ah = anchors[..., 3] - anchors[..., 1]
+    ax = anchors[..., 0] + 0.5 * aw
+    ay = anchors[..., 1] + 0.5 * ah
+    bw = jnp.maximum(boxes[..., 2] - boxes[..., 0], 1e-6)
+    bh = jnp.maximum(boxes[..., 3] - boxes[..., 1], 1e-6)
+    bx = boxes[..., 0] + 0.5 * bw
+    by = boxes[..., 1] + 0.5 * bh
+    return jnp.stack(
+        [(bx - ax) / aw, (by - ay) / ah, jnp.log(bw / aw), jnp.log(bh / ah)],
+        axis=-1)
+
+
+def _gather_losses(anchors, logits, deltas, boxes, labels, valid, k):
+    """The plain oracle: the formulation ``RetinaNet.loss`` had before
+    ``assign_targets``, two gathers with one index per anchor."""
+    matched = _plain_match(anchors, boxes, valid)
+    fg = matched >= 0
+    ignore = matched == -2
+    safe = jnp.clip(matched, 0)
+    cls_t = jax.nn.one_hot(labels[safe], k) * fg[:, None]
+    box_t = _plain_box_encode(boxes[safe], anchors)
+    cls_loss = det.sigmoid_focal_loss(logits, cls_t)
+    cls_loss = jnp.where(ignore[:, None], 0.0, cls_loss).sum()
+    box_loss = det.smooth_l1(deltas, box_t).sum(-1)
+    box_loss = jnp.where(fg, box_loss, 0.0).sum()
+    n_fg = jnp.maximum(fg.sum(), 1)
+    return cls_loss / n_fg, box_loss / n_fg, (cls_t, box_t, fg, ignore)
+
+
+def _assigned_losses(anchors, logits, deltas, boxes, labels, valid, k):
+    """What ``RetinaNet.loss`` does for one image."""
+    cls_t, box_t, fg, ignore = det.assign_targets(
+        anchors, boxes, labels, valid, k)
+    cls_loss = det.sigmoid_focal_loss(logits, cls_t)
+    cls_loss = jnp.where(ignore[:, None], 0.0, cls_loss).sum()
+    box_loss = jnp.where(fg, det.smooth_l1(deltas.T, box_t), 0.0).sum()
+    n_fg = jnp.maximum(fg.sum(), 1)
+    return cls_loss / n_fg, box_loss / n_fg, (cls_t, box_t.T, fg, ignore)
+
+
+def _losses_and_grads(losses):
+    def both(anchors, logits, deltas, boxes, labels, valid):
+        def total(logits, deltas):
+            c, b, targets = losses(anchors, logits, deltas, boxes, labels,
+                                   valid, PUBLISHED_K)
+            return c + b, (c, b, targets)
+
+        (_, aux), grads = jax.value_and_grad(
+            total, argnums=(0, 1), has_aux=True)(logits, deltas)
+        return aux, grads
+
+    return jax.jit(both)
+
+
+@pytest.mark.parametrize("n_valid,seed", [(0, 0), (1, 1), (7, 39), (20, 36)])
+def test_assign_targets_equals_gather_formulation(published_anchors, n_valid,
+                                                  seed):
+    """At the published shape (201,600 anchors, 100 padded boxes, 80
+    classes) the dense select gives the targets, losses and gradients of
+    the gather formulation. Seeds 39 and 36 hold many-way ties for a box's
+    best IoU (see the next test)."""
+    rng = np.random.default_rng(seed)
+    boxes, labels, valid = _padded_gt(rng, n_valid)
+    a = published_anchors.shape[0]
+    logits = jnp.asarray(rng.normal(-4, 1, (a, PUBLISHED_K)), jnp.float32)
+    deltas = jnp.asarray(rng.normal(0, 0.5, (a, 4)), jnp.float32)
+    args = (published_anchors, logits, deltas, boxes, labels, valid)
+    (c0, b0, (cls0, box0, fg0, ig0)), (gl0, gd0) = _losses_and_grads(
+        _gather_losses)(*args)
+    (c1, b1, (cls1, box1, fg1, ig1)), (gl1, gd1) = _losses_and_grads(
+        _assigned_losses)(*args)
+
+    fg = np.asarray(fg0)
+    assert int(fg.sum()) >= n_valid  # every valid box holds an anchor
+    np.testing.assert_array_equal(np.asarray(fg1), fg)
+    np.testing.assert_array_equal(np.asarray(ig1), np.asarray(ig0))
+    np.testing.assert_array_equal(np.asarray(cls1), np.asarray(cls0))
+    np.testing.assert_array_equal(np.asarray(box1)[fg], np.asarray(box0)[fg])
+    assert np.isfinite(np.asarray(box1)).all()  # a zero box off the foreground
+    # same arithmetic an element; the box loss sums its four coordinates
+    # in another order
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c0))
+    np.testing.assert_allclose(np.asarray(b1), np.asarray(b0), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(gl1), np.asarray(gl0),
+                               rtol=1e-6, atol=0)
+    np.testing.assert_allclose(np.asarray(gd1), np.asarray(gd0),
+                               rtol=1e-6, atol=0)
+    if n_valid == 0:
+        assert not fg.any() and float(b1) == 0.0 and np.isfinite(float(c1))
+
+
+def test_matcher_keeps_exact_ties_at_published_shape(published_anchors):
+    """A pool in which dozens of anchors tie for one box's best IoU (one
+    pool in three does): every tied anchor is promoted, as by the plain
+    matcher. An IoU matrix laid out (M, A) rounds 51 of them apart on the
+    CPU."""
+    boxes, _, valid = _padded_gt(np.random.default_rng(39), 7)
+    plain = jax.jit(_plain_match)(published_anchors, boxes, valid)
+    ours, _ = jax.jit(det.match_anchors)(published_anchors, boxes, valid)
+    np.testing.assert_array_equal(np.asarray(ours), np.asarray(plain))
+
+
+def test_assign_targets_lowers_without_gather_or_scatter(published_anchors):
+    boxes, labels, valid = _padded_gt(np.random.default_rng(3), 7)
+    a = published_anchors.shape[0]
+    logits = jnp.zeros((a, PUBLISHED_K))
+    deltas = jnp.zeros((a, 4))
+    programs = {
+        "assign_targets": jax.jit(
+            det.assign_targets, static_argnums=4).lower(
+                published_anchors, boxes, labels, valid, PUBLISHED_K),
+        "grad of the losses": _losses_and_grads(_assigned_losses).lower(
+            published_anchors, logits, deltas, boxes, labels, valid),
+    }
+    for name, lowered in programs.items():
+        text = lowered.as_text()
+        assert "stablehlo." in text
+        for op in ("gather", "scatter"):
+            assert op not in text, (name, op)
+    # the oracle is what the assertion would catch
+    assert "gather" in _losses_and_grads(_gather_losses).lower(
+        published_anchors, logits, deltas, boxes, labels, valid).as_text()
+
+
+@pytest.mark.parametrize("case", ["padded_invalid_gt", "tie_highest_gt_wins"])
+def test_assign_targets_matcher_regressions(case):
+    """``test_matcher_promotion_with_padded_invalid_gt`` and
+    ``test_matcher_tie_highest_gt_wins`` through ``assign_targets``: the
+    promoted anchor carries its GT's class and box."""
+    if case == "padded_invalid_gt":
+        anchors = jnp.asarray([[0, 0, 10, 10], [50, 50, 60, 60]], jnp.float32)
+        gt = jnp.asarray([[0, 0, 10, 22], [0, 0, 0, 0], [0, 0, 0, 0]],
+                         jnp.float32)
+        valid = jnp.asarray([True, False, False])
+        labels = jnp.asarray([3, 1, 2], jnp.int32)
+        want_gt, want_fg = 0, [True, False]
+    else:
+        anchors = jnp.asarray([[0, 0, 10, 10]], jnp.float32)
+        gt = jnp.asarray([[0, 0, 10, 30], [0, 0, 30, 10]], jnp.float32)
+        valid = jnp.asarray([True, True])
+        labels = jnp.asarray([3, 1], jnp.int32)
+        want_gt, want_fg = 1, [True]
+    cls_t, box_t, fg, ignore = det.assign_targets(anchors, gt, labels, valid, 5)
+    assert np.asarray(fg).tolist() == want_fg
+    assert not np.asarray(ignore).any()
+    want_cls = np.zeros((len(want_fg), 5), np.float32)
+    want_cls[0, int(labels[want_gt])] = 1.0
+    np.testing.assert_array_equal(np.asarray(cls_t), want_cls)
+    np.testing.assert_array_equal(
+        np.asarray(box_t)[:, 0],
+        np.asarray(det.box_encode(gt[want_gt], anchors[0])))
+
+
+def test_retinanet_loss_background_only_image():
+    """No valid box in either image: every anchor is background, the
+    losses are finite and the box loss is zero."""
+    model = _small_retinanet()
+    images = jnp.asarray(
+        np.random.RandomState(0).randn(2, 64, 64, 3), jnp.float32)
+    total, aux = model.loss(
+        images, jnp.zeros((2, 4, 4), jnp.float32),
+        jnp.zeros((2, 4), jnp.int32), jnp.zeros((2, 4), bool))
+    assert np.isfinite(float(total)) and float(aux["cls_loss"]) > 0
+    assert float(aux["box_loss"]) == 0.0
+
+
+def test_retinanet_loss_and_its_gradient_lower_without_gather():
+    """Through ``RetinaNet.loss`` itself: nothing in the model's forward
+    and backward pass indexes per anchor."""
+    model = _small_retinanet()
+    graphdef, params, rest = nnx.split(model, nnx.Param, ...)
+
+    def loss_fn(p, images, boxes, labels, valid):
+        m = compat.nnx_merge(graphdef, p, rest, copy=True)
+        return m.loss(images, boxes, labels, valid)[0]
+
+    text = jax.jit(jax.value_and_grad(loss_fn)).lower(
+        params, jnp.zeros((2, 64, 64, 3)), jnp.zeros((2, 4, 4)),
+        jnp.zeros((2, 4), jnp.int32), jnp.zeros((2, 4), bool)).as_text()
+    assert "stablehlo.convolution" in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
+
+
 def test_detection_dataset_pipeline_end_to_end():
     """Capability config 4 with the REAL data pipeline: detection dataset →
     sampler → loader → device_prefetch → SyncBN DP RetinaNet step."""

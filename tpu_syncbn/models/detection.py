@@ -7,6 +7,13 @@ capability config in BASELINE.json). All ops are static-shape and
 jit-friendly: ground truth arrives padded to a fixed ``max_boxes`` with a
 validity mask, matching is a dense IoU argmax, and losses mask invalid
 entries — no data-dependent shapes anywhere (XLA requirement).
+
+Target assignment (:func:`assign_targets`) is gather-free and works on
+coordinate rows: a TPU gather with one scalar index per anchor runs index
+by index, and an array whose minor dimension is 2 or 4 fills that many of
+a vector register's 128 lanes, so the matched label and box are picked by
+a one-hot select over the (few) ground-truth boxes, coordinate by
+coordinate.
 """
 
 from __future__ import annotations
@@ -69,19 +76,26 @@ def retinanet_anchors(
 # -- box coding -----------------------------------------------------------
 
 
+def _encode_rows(b, a):
+    """:func:`box_encode` on coordinate rows: ``b`` and ``a`` are
+    (x1, y1, x2, y2) tuples of arrays that broadcast; returns the four
+    rows (dx, dy, dw, dh)."""
+    aw = a[2] - a[0]
+    ah = a[3] - a[1]
+    ax = a[0] + 0.5 * aw
+    ay = a[1] + 0.5 * ah
+    bw = jnp.maximum(b[2] - b[0], 1e-6)
+    bh = jnp.maximum(b[3] - b[1], 1e-6)
+    bx = b[0] + 0.5 * bw
+    by = b[1] + 0.5 * bh
+    return (bx - ax) / aw, (by - ay) / ah, jnp.log(bw / aw), jnp.log(bh / ah)
+
+
 def box_encode(boxes: jnp.ndarray, anchors: jnp.ndarray) -> jnp.ndarray:
     """(x1y1x2y2 boxes, anchors) → (dx, dy, dw, dh) regression targets
     (Faster-R-CNN coding, weights 1)."""
-    aw = anchors[..., 2] - anchors[..., 0]
-    ah = anchors[..., 3] - anchors[..., 1]
-    ax = anchors[..., 0] + 0.5 * aw
-    ay = anchors[..., 1] + 0.5 * ah
-    bw = jnp.maximum(boxes[..., 2] - boxes[..., 0], 1e-6)
-    bh = jnp.maximum(boxes[..., 3] - boxes[..., 1], 1e-6)
-    bx = boxes[..., 0] + 0.5 * bw
-    by = boxes[..., 1] + 0.5 * bh
     return jnp.stack(
-        [(bx - ax) / aw, (by - ay) / ah, jnp.log(bw / aw), jnp.log(bh / ah)],
+        _encode_rows(jnp.moveaxis(boxes, -1, 0), jnp.moveaxis(anchors, -1, 0)),
         axis=-1,
     )
 
@@ -110,14 +124,16 @@ def box_decode(deltas: jnp.ndarray, anchors: jnp.ndarray) -> jnp.ndarray:
 
 
 def box_iou(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Pairwise IoU: (N, 4) × (M, 4) → (N, M)."""
-    lt = jnp.maximum(a[:, None, :2], b[None, :, :2])
-    rb = jnp.minimum(a[:, None, 2:], b[None, :, 2:])
-    wh = jnp.clip(rb - lt, 0.0)
-    inter = wh[..., 0] * wh[..., 1]
-    area_a = jnp.clip(a[:, 2] - a[:, 0], 0) * jnp.clip(a[:, 3] - a[:, 1], 0)
-    area_b = jnp.clip(b[:, 2] - b[:, 0], 0) * jnp.clip(b[:, 3] - b[:, 1], 0)
-    union = area_a[:, None] + area_b[None, :] - inter
+    """Pairwise IoU: (N, 4) × (M, 4) → (N, M), coordinate by coordinate
+    (no intermediate with a minor dimension of 2)."""
+    ax1, ay1, ax2, ay2 = a.T[:, :, None]
+    bx1, by1, bx2, by2 = b.T[:, None, :]
+    w = jnp.clip(jnp.minimum(ax2, bx2) - jnp.maximum(ax1, bx1), 0.0)
+    h = jnp.clip(jnp.minimum(ay2, by2) - jnp.maximum(ay1, by1), 0.0)
+    inter = w * h
+    area_a = jnp.clip(ax2 - ax1, 0) * jnp.clip(ay2 - ay1, 0)
+    area_b = jnp.clip(bx2 - bx1, 0) * jnp.clip(by2 - by1, 0)
+    union = area_a + area_b - inter
     return jnp.where(union > 0, inter / union, 0.0)
 
 
@@ -138,6 +154,11 @@ def match_anchors(
     """
     iou = box_iou(anchors, gt_boxes)  # (N, M)
     iou = jnp.where(gt_valid[None, :], iou, -1.0)
+    # one IoU matrix for every reduction below: the promotion compares it
+    # with its own column maxima for equality, and a compiler that fused
+    # the arithmetic into each reduction could round the two sides apart
+    # (materialized it is also the faster program on the TPU)
+    iou = jax.lax.optimization_barrier(iou)
     best_gt = jnp.argmax(iou, axis=1)
     best_iou = jnp.max(iou, axis=1)
     matched = jnp.where(
@@ -152,12 +173,49 @@ def match_anchors(
     gt_best_iou = jnp.max(iou, axis=0)  # (M,)
     ok = gt_valid & (gt_best_iou > 0)
     is_best = (iou >= gt_best_iou[None, :]) & ok[None, :]  # (N, M)
-    m = gt_boxes.shape[0]
-    rev = is_best[:, ::-1]
-    promote_to = (m - 1 - jnp.argmax(rev, axis=1)).astype(jnp.int32)
-    has_promo = jnp.any(is_best, axis=1)
-    matched = jnp.where(has_promo, promote_to, matched)
+    gt_index = jnp.arange(gt_boxes.shape[0], dtype=jnp.int32)[None, :]
+    promote_to = jnp.max(jnp.where(is_best, gt_index, -1), axis=1)
+    matched = jnp.where(promote_to >= 0, promote_to, matched)
     return matched, best_iou
+
+
+def assign_targets(
+    anchors: jnp.ndarray,
+    boxes: jnp.ndarray,
+    labels: jnp.ndarray,
+    valid: jnp.ndarray,
+    num_classes: int,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One image's training targets for the (A, 4) ``anchors`` from its
+    padded ground truth (``boxes`` (M, 4), ``labels`` (M,), ``valid`` (M,)).
+
+    Returns ``(cls_t, box_t, fg, ignore)``: the one-hot class targets
+    (A, ``num_classes``), all zero off the foreground; the
+    :func:`box_encode` targets as four rows (4, A), meaningful on the
+    foreground only; and the (A,) foreground and ignore masks of
+    :func:`match_anchors`.
+
+    The matched label and box are taken by a select against the (A, M)
+    one-hot of the matched index (see the module docstring), whose rows
+    are all zero for background and ignored anchors: those read label -1
+    and a zero box, which encodes to finite values.
+    """
+    with jax.named_scope("anchors_match"):
+        matched, _ = match_anchors(anchors, boxes, valid)
+        fg = matched >= 0
+        ignore = matched == -2
+        gt_index = jnp.arange(boxes.shape[0], dtype=matched.dtype)[None, :]
+        picked = matched[:, None] == gt_index  # (A, M)
+
+    def pick(per_gt):  # (M,) → (A,), 0 where the anchor matched no GT
+        return jnp.sum(jnp.where(picked, per_gt[None, :], 0), axis=1)
+
+    with jax.named_scope("focal"):
+        cls_t = jax.nn.one_hot(jnp.where(fg, pick(labels), -1), num_classes)
+    with jax.named_scope("smooth_l1"):
+        box_t = jnp.stack(_encode_rows(tuple(pick(r) for r in boxes.T),
+                                       tuple(anchors.T)))
+    return cls_t, box_t, fg, ignore
 
 
 # -- losses ---------------------------------------------------------------

@@ -163,22 +163,14 @@ class RetinaNet(nnx.Module):
         anchors = self.anchors[...]
 
         def one_image(logits, deltas, boxes, labels, valid):
-            with jax.named_scope("anchors_match"):
-                matched, _ = det.match_anchors(anchors, boxes, valid)
-                fg = matched >= 0
-                ignore = matched == -2
-                safe = jnp.clip(matched, 0)
+            cls_t, box_t, fg, ignore = det.assign_targets(
+                anchors, boxes, labels, valid, self.num_classes)
             with jax.named_scope("focal"):
-                # classification targets: one-hot of matched GT class,
-                # zeros for bg
-                cls_t = (jax.nn.one_hot(labels[safe], self.num_classes)
-                         * fg[:, None])
                 cls_loss = det.sigmoid_focal_loss(logits, cls_t)
                 cls_loss = jnp.where(ignore[:, None], 0.0, cls_loss).sum()
             with jax.named_scope("smooth_l1"):
-                # box targets for fg anchors
-                box_t = det.box_encode(boxes[safe], anchors)
-                box_loss = det.smooth_l1(deltas, box_t).sum(-1)
+                # anchors on the minor axis, as the (4, A) targets are
+                box_loss = det.smooth_l1(deltas.T, box_t)
                 box_loss = jnp.where(fg, box_loss, 0.0).sum()
             n_fg = jnp.maximum(fg.sum(), 1)
             return cls_loss / n_fg, box_loss / n_fg
