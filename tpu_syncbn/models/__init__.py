@@ -1,8 +1,10 @@
 """Model zoo: the architectures named by the reference's capability configs
 (ResNet-18/50, RetinaNet-R50-FPN, DCGAN/SNGAN — BASELINE.json), plus the
-transformer LM that exercises the long-context path."""
+transformer LM that exercises the long-context path and the looped
+decoder LM that trains through ``DataParallel``."""
 
-from tpu_syncbn.models import detection, gan, transformer
+from tpu_syncbn.models import detection, gan, looped_lm, transformer
+from tpu_syncbn.models.looped_lm import LoopedDecoderLM
 from tpu_syncbn.models.transformer import init_transformer_lm, transformer_lm
 from tpu_syncbn.models.gan import (
     DCGANGenerator,
@@ -46,4 +48,6 @@ __all__ = [
     "transformer",
     "init_transformer_lm",
     "transformer_lm",
+    "looped_lm",
+    "LoopedDecoderLM",
 ]
