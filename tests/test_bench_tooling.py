@@ -1079,3 +1079,65 @@ class TestRecoveryBlock:
         assert rec["ckpt_bytes"] > 0
         assert rec["ckpt_roundtrip_s"] > 0
         assert rec["resume_after_kill_s"] < 10
+
+
+def _load_flash_sweep():
+    bench_dir = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench_dir)  # its ``from _common import ...``
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "flash_tile_sweep_under_test",
+            os.path.join(bench_dir, "flash_tile_sweep.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+    finally:
+        sys.path.remove(bench_dir)
+    return mod
+
+
+class TestFlashTileSweep:
+    """benchmarks/flash_tile_sweep.py's pure parts: the counts beside
+    each time, the fit, and which operations of a capture belong to
+    which program."""
+
+    def test_walk_counts_the_cells_call(self):
+        from tpu_syncbn.ops import pallas_attention as pa
+
+        mod = _load_flash_sweep()
+        assert mod.walk(pa, 2048, 128, 128) == 136  # x 32 = 4,352 steps
+        assert mod.walk(pa, 2048, 512, 512) == 10   # x 32 = 320
+
+    def test_fit_recovers_step_and_score_costs(self):
+        mod = _load_flash_sweep()
+        rows = [{"steps": s, "scores": n,
+                 "kernel_ms": 4e-4 * s + 3e-9 * n}
+                for s, n in [(4352, 71e6), (320, 84e6), (96, 100e6),
+                             (1152, 75e6)]]
+        got = mod.fit(rows + [{"steps": 1, "scores": 1, "error": "x"}])
+        assert got["points"] == 4
+        assert got["a_us_per_step"] == pytest.approx(0.4, rel=1e-6)
+        assert got["b_ps_per_score"] == pytest.approx(3.0, rel=1e-6)
+        assert mod.fit(rows[:2]) == {}
+
+    def test_operations_go_to_the_execution_that_holds_them(self):
+        mod = _load_flash_sweep()
+        plane = {
+            "metadata": {1: ("jit_flash_q128_k128(123)", None),
+                         2: ("jit_other(9)", None),
+                         3: ("%k = custom-call(...)", "a/pallas_call"),
+                         4: ("%copy.1 = copy(...)", None)},
+            "lines": [
+                {"name": "XLA Modules",
+                 "events": [(1, 0.0, 100.0), (2, 100.0, 50.0),
+                            (1, 200.0, 100.0)]},
+                {"name": "XLA Ops",
+                 "events": [(3, 10.0, 60.0), (4, 70.0, 20.0),
+                            (4, 110.0, 30.0), (3, 210.0, 80.0)]},
+            ],
+        }
+        runs = mod.by_program([plane])
+        assert [len(r) for r in runs["jit_flash_q128_k128"]] == [2, 1]
+        kernel, whole = mod.kernel_ms(runs["jit_flash_q128_k128"])
+        assert kernel == pytest.approx(70e-6)  # median of 60 and 80 ns
+        assert whole == pytest.approx(80e-6)
+        assert mod.kernel_ms(runs["jit_other"]) == (0.0, pytest.approx(30e-6))

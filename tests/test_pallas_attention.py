@@ -2,6 +2,8 @@
 and gradients, interpret mode on CPU (the same kernel code path the TPU
 compiles; the on-chip battery revalidates compiled)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,10 +15,10 @@ from tpu_syncbn.parallel import sequence
 B, H, D = 2, 3, 16
 
 
-def make(l, seed=0, dtype=jnp.float32):
+def make(l, seed=0, dtype=jnp.float32, b=B, h=H):
     rng = np.random.default_rng(seed)
     mk = lambda: jnp.asarray(
-        rng.standard_normal((B, l, H, D)).astype(np.float32), dtype
+        rng.standard_normal((b, l, h, D)).astype(np.float32), dtype
     )
     return mk(), mk(), mk()
 
@@ -146,6 +148,90 @@ class TestCausalTileWalk:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=2e-5
         )
+
+
+class TestForwardBlocks:
+    """A call that names no blocks gets the forward's from its shape
+    (``forward_blocks``); the backward keeps 128; a call that names them
+    gets them."""
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("l", [1, 32, 100, 128, 300, 512, 640, 2048,
+                                   8192])
+    def test_choice_fits_the_shape(self, l, d, itemsize):
+        padded = -(-l // 128) * 128
+        for block in pa.forward_blocks(l, d, itemsize):
+            assert block % 128 == 0 and 0 < block <= padded
+            assert padded % block == 0  # no padding beyond 128's
+        assert pa.forward_vmem_bytes(
+            *pa.forward_blocks(l, d, itemsize), d, itemsize
+        ) <= pa._FWD_VMEM_BUDGET < pa._VMEM_SCOPED_BYTES
+
+    def test_choice_at_the_timed_shape(self):
+        # the benchmark cell's call: 320 grid steps where 128 x 128
+        # tiles took 4,352 (PERF.md section 6, PR 31)
+        assert pa.forward_blocks(2048, 128, 2) == (512, 512)
+        walk = len(pa._causal_tiles(4, 4, 512, 512)[0])
+        assert 32 * walk == 320
+
+    # (length, causal) -> the chosen tiles, and how many of the walk's
+    # tile pairs take the masked and the unmasked branch of the kernel:
+    # the diagonal's and the padding's (1100, causal), the padding's
+    # alone (1100, full), the diagonal's alone (1280, causal), none
+    # (1280, full)
+    CASES = {
+        (1100, True): ((384, 384), 3, 3),
+        (1100, False): ((384, 384), 3, 6),
+        (1280, True): ((256, 256), 5, 10),
+        (1280, False): ((256, 256), 0, 25),
+    }
+
+    @pytest.mark.parametrize("l,causal", list(CASES))
+    def test_default_blocks_match_oracle(self, l, causal):
+        (bq, bk), masked, unmasked = self.CASES[(l, causal)]
+        assert pa.forward_blocks(l, D, 4) == (bq, bk)
+        n_q, n_k = -(-l // bq), -(-l // bk)
+        pairs = (zip(*pa._causal_tiles(n_q, n_k, bq, bk)) if causal else
+                 ((qi, ki) for qi in range(n_q) for ki in range(n_k)))
+        took = [bool(pa._holds_masked_scores(
+            int(qi), int(ki), causal=causal, block_q=bq, block_k=bk,
+            n_k=n_k, pad_k=n_k * bk - l)) for qi, ki in pairs]
+        assert (sum(took), len(took) - sum(took)) == (masked, unmasked)
+
+        q, k, v = make(l, seed=12, b=1, h=1)
+        w = make(l, seed=13, b=1, h=1)[0]
+        oracle = lambda q, k, v: sequence._single_device_attention(
+            q, k, v, causal=causal, scale=None)
+        flash = lambda q, k, v: pa.flash_attention(q, k, v, causal=causal)
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v)), np.asarray(oracle(q, k, v)),
+            atol=2e-5)
+        grads = lambda f: jax.grad(
+            lambda q, k, v: jnp.sum(w * f(q, k, v)), argnums=(0, 1, 2)
+        )(q, k, v)
+        for a, b, name in zip(grads(flash), grads(oracle), "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), atol=5e-5, err_msg=f"d{name}")
+
+    @staticmethod
+    def _program(l, **blocks):
+        q = jnp.zeros((1, l, 1, D))
+        return str(jax.make_jaxpr(jax.grad(lambda q: pa.flash_attention(
+            q, q, q, causal=True, **blocks).sum()))(q))
+
+    def test_named_blocks_give_todays_walk(self):
+        program = self._program(200, block_q=64, block_k=128)
+        walk = len(pa._causal_tiles(4, 2, 64, 128)[0])
+        assert "name=flash_fwd_q64_k128" in program
+        assert f"grid=(1, {walk})" in program
+        # the backward scans the key blocks the caller named
+        assert re.search(r"length=2\b", program)
+
+    def test_backward_keeps_128_when_none_is_named(self):
+        program = self._program(1280)
+        assert "name=flash_fwd_q256_k256" in program
+        assert "length=10" in program  # 1280 / 128 key blocks
 
 
 class TestPallasBackward:

@@ -137,6 +137,30 @@ def test_flash_attention_compiles_for_v5e(v5e, mosaic, shape, causal, backward):
     _compile_fwd_and_grad(loss, q, q, q)
 
 
+# (batch, seq, heads, head_dim): the benchmark cell's call, and a length
+# that is no multiple of a block; both take the tiles the kernel chooses
+# from the shape, the largest it can return, so that Mosaic's VMEM limit
+# and tiling rules judge them
+FLASH_CALLS = [(2, 2048, 16, 128), (1, 2000, 16, 128)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("call", FLASH_CALLS,
+                         ids=lambda c: "B{}S{}h{}d{}".format(*c))
+def test_flash_attention_chosen_blocks_compile_for_v5e(v5e, mosaic, call,
+                                                       dtype):
+    b, s, h, d = call
+    assert pa.forward_blocks(s, d, jnp.dtype(dtype).itemsize) == (512, 512)
+    q = jax.ShapeDtypeStruct((b, s, h, d), dtype, sharding=v5e)
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    _compile_fwd_and_grad(loss, q, q, q)
+
+
 def test_looped_decoder_compiles_for_v5e(v5e, mosaic):
     """The looped decoder at Ouro-2.6B's published widths (hidden 2048,
     16 heads of 128, SwiGLU 5632), 2 sequences of 2,048 tokens, through

@@ -45,10 +45,12 @@ PR 30). The passes as a scan or unrolled: the same step (847 and 850 ms)
 at 14.4 against 16.4 GB, so a scan. Saving every matmul's output
 (``dots_with_no_batch_dims_saveable``) wants 22.6 GiB and without
 recomputation the step wants 71 GiB of the chip's 15.75. ``attn_impl``:
-``"xla"`` 847 ms a step, ``"flash"`` 774 (the kernel's forward with its
-backward as an XLA scan over key blocks; with the kernel's own two
-backward kernels, ``flash_attention(backward="pallas")``, 848, so the
-model offers no such value); the default stays ``"xla"`` because it
+``"xla"`` 847 ms a step, ``"flash"`` 643 (the kernel's forward with its
+backward as an XLA scan over key blocks; 774 in PR 30, before the
+forward chose its tiles from the shape, PERF.md section 6, PR 31; with
+the kernel's own two backward kernels,
+``flash_attention(backward="pallas")``, 848 then, so the model offers
+no such value); the default stays ``"xla"`` because it
 runs everywhere (the kernel's interpret mode does not pass
 ``shard_map``'s ``check_vma`` on the CPU), and a configuration for the
 chip names ``"flash"``. The two differ in one rounding: ``"xla"``

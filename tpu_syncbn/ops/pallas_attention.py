@@ -17,8 +17,12 @@ only the at-or-below-diagonal (qi, ki) tile pairs are enumerated (a 1-D
 tile walk mapped through scalar-prefetched index arrays), so tiles
 strictly above the diagonal cost neither MXU work NOR VMEM streaming —
 the BlockSpec pipeline never touches their DMA (~2x bandwidth cut at
-long L vs the rectangular grid); the diagonal tile masks with a 2-D
-iota.
+long L vs the rectangular grid); only the tiles the diagonal crosses
+(and the last key tile of a padded length) build a mask, with a 2-D
+iota. The forward's tiles come from the call's shape
+(:func:`forward_blocks`: 512 x 512 from 512 tokens up, where the length
+allows) unless the caller names them: a grid step costs 0.3-0.4 us
+whatever it holds, so the walk is made of few, large steps.
 
 Backward is a ``jax.custom_vjp`` with two implementations, both
 recomputing P from the saved logsumexp (O(L·block) live memory, never
@@ -32,15 +36,21 @@ Like the BN kernels, everything runs under ``interpret=True`` off-TPU
 (the CPU suite exercises the real kernel code path), and the kernel is
 an *opt-in* backend (``attn_impl="flash"`` of ``models.transformer`` and
 ``models.looped_lm``) — the same evidence-gating stance as
-``ops.batch_norm``'s ``auto``. The hardware measurement (TPU v5e, PR 30,
-PERF.md section 6): inside the looped decoder at 16 heads of 128, 2 x
-2,048 tokens, causal, forward + recomputed forward + backward of 32
-layer applications a step, XLA's attention takes 332 ms, this kernel
-with the ``"xla"`` backward 224 ms (the forward 2.67 ms a call, 7.6%
-of its roofline; its dots take float32 tiles, which the TPU multiplies
-in bf16 passes: on float32 inputs the output is 1.8e-3 off a float32
-reference) and with the ``"pallas"`` backward 303 ms. So where a model runs on the chip at such shapes its
-configuration names ``"flash"``; the ``"pallas"`` backward stays opt-in.
+``ops.batch_norm``'s ``auto``. The hardware measurement (TPU v5e,
+PERF.md section 6, PR 30 and PR 31): inside the looped decoder at 16
+heads of 128, 2 x 2,048 tokens, causal, forward + recomputed forward +
+backward of 32 layer applications a step, XLA's attention takes 332 ms
+and this kernel with the ``"xla"`` backward 92.5 (224 while the forward
+walked 128 x 128 tiles: 4,352 grid steps and 2.67 / 2.14 ms a call, now
+320 steps and 0.38 / 0.34 ms, forward pass / recomputed, 49% of the
+kernel's roofline; the backward scan is 68 of the 92.5), with the
+``"pallas"`` backward 303 at 128 x 128. The products take their
+operands in the inputs' type and accumulate in float32; with bf16
+inputs that is bit for bit what the MXU made of the float32 tiles the
+kernel handed it before (on float32 inputs the TPU multiplies in bf16
+passes too: 1.8e-3 off a float32 reference). So where a model runs on
+the chip at such shapes its configuration names ``"flash"``; the
+``"pallas"`` backward stays opt-in.
 """
 
 from __future__ import annotations
@@ -56,75 +66,182 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpu_syncbn.ops._pallas_common import NEG_BIG as _NEG_BIG
 from tpu_syncbn.ops._pallas_common import interpret as _interpret
+from tpu_syncbn.ops._pallas_common import sds as _sds
 
+# the two Pallas backward kernels' tiles, and the XLA backward scan's
+# key block: what each was measured with (PERF.md section 6, PR 30); the
+# forward's tiles come from the call's shape (``forward_blocks``)
 _BLOCK_Q = 128
 _BLOCK_K = 128
+_BWD_SCAN_BLOCK_K = 128
+
+_LANES = 128
+# the forward's tiles: the widest the sweep on the chip found worth
+# having (benchmarks/flash_tile_sweep.py, PERF.md section 6, PR 31: at
+# 2,048 tokens of 128-wide heads 512 x 512 takes 0.35 ms a call, 512 x
+# 1024 and 1024 x 1024 0.41, 128 x 128 2.3), and the share of the 16 MiB
+# of VMEM a kernel may scope that one grid step's buffers may take (the
+# rest is the compiler's, for what it spills)
+_FWD_MAX_BLOCK_Q = 512
+_FWD_MAX_BLOCK_K = 512
+_VMEM_SCOPED_BYTES = 16 * 2**20
+_FWD_VMEM_BUDGET = _VMEM_SCOPED_BYTES // 2
 
 
-from tpu_syncbn.ops._pallas_common import sds as _sds
+def forward_vmem_bytes(block_q: int, block_k: int, d: int,
+                       itemsize: int) -> int:
+    """VMEM one grid step of the forward kernel needs: the q, k, v and
+    output tiles (double-buffered by the pipeline) and the log-sum-exp
+    column (lane-padded), the scaled q, the float32 scores and
+    probabilities and the probabilities in the compute type, the
+    accumulator and the two lane-dense statistics."""
+    lanes_d = -(-d // _LANES) * _LANES
+    streamed = 2 * ((2 * block_q + 2 * block_k) * lanes_d * itemsize
+                    + block_q * _LANES * 4)
+    scores = block_q * block_k * (4 + 4 + itemsize)
+    carried = block_q * (lanes_d * (4 + itemsize) + 2 * _LANES * 4)
+    return streamed + scores + carried
+
+
+def forward_blocks(length: int, d: int, itemsize: int) -> tuple[int, int]:
+    """(block_q, block_k) of the forward kernel for a call that names
+    none, from the call's shape alone. The length is padded to a
+    multiple of 128 and no further; each block is the largest multiple
+    of 128 that divides the padded length, stays under the widest tile
+    the sweep found worth having, and with the other keeps
+    ``forward_vmem_bytes`` under the budget (the key block gives way
+    first: the query block is what amortises a key tile's DMA)."""
+    padded = -(-length // _LANES) * _LANES
+
+    def fitting(cap: int) -> list[int]:
+        return [b for b in range(min(cap, padded), 0, -_LANES)
+                if padded % b == 0]
+
+    for block_q in fitting(_FWD_MAX_BLOCK_Q):
+        for block_k in fitting(_FWD_MAX_BLOCK_K):
+            if forward_vmem_bytes(block_q, block_k, d,
+                                  itemsize) <= _FWD_VMEM_BUDGET:
+                return block_q, block_k
+    return _LANES, _LANES
 
 
 # -- forward kernel -------------------------------------------------------
 
 
+def _across(x, n: int):
+    """A (rows, 128) statistic, its value replicated across the lanes,
+    as (rows, n): whole vector registers repeated where n is a multiple
+    of 128, no lane broadcast."""
+    if n % _LANES == 0:
+        return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _holds_masked_scores(qi, ki, *, causal, block_q, block_k, n_k, pad_k):
+    """Whether tile (qi, ki) can hold a score that must not count: only
+    a tile the diagonal crosses, or the last key tile of a padded
+    length. Every other live tile skips the iotas, the compares and the
+    select. ``qi`` / ``ki`` traced or plain; None where the shape alone
+    says that no tile can (full attention, no padding)."""
+    edge = None
+    if causal:
+        edge = ki * block_k + block_k - 1 > qi * block_q
+    if pad_k:
+        last = ki == n_k - 1
+        edge = last if edge is None else edge | last
+    return edge
+
+
+def _init_carry(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref):
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / _across(l, acc_ref.shape[1])).astype(
+        o_ref.dtype)
+    # lse rides a (BH, T, 1) array: a 2-D (BH, T) output would put
+    # the BH axis in the block's last-two-dims window, where the TPU
+    # lowering rejects a block size of 1 (must divide 8 / equal the
+    # array dim — observed live in tpu_vma_probe.json round 5)
+    lse_ref[0] = (m_ref[...] + jnp.log(l))[:, :1]
+
+
 def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                 acc_ref, m_ref, l_ref, qi, ki, last_ki, *,
-                 scale, causal, block_q, block_k, l_real):
+                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, last_ki, *,
+                 scale, causal, block_q, block_k, n_k, l_real):
     """One (qi, ki) online-softmax step; ``qi``/``ki`` may be traced
     scalars (compressed causal grid) or program ids (rectangular grid).
     The ki sweep for a fixed (bh, qi) is contiguous in the grid walk, so
-    the VMEM scratch carries the running (max, denom, acc) across it."""
+    the VMEM scratch carries the running (max, denom, acc) across it,
+    the two statistics lane-dense: (block_q, 128), the value of a row in
+    every lane. Each product's operands reach the MXU in the inputs'
+    type, rounded once: k and v as stored, ``q * scale`` once a query
+    tile into ``qs_ref``, the float32 probabilities where they enter the
+    second product (the sum that normalises them takes them unrounded).
+    For bf16 inputs this is what the MXU did to float32 operands
+    already (PERF.md section 6, PR 30 and PR 31); float32 inputs stay
+    float32."""
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_carry(acc_ref, m_ref, l_ref)
+        qs_ref[...] = (q_ref[0].astype(jnp.float32) * scale).astype(
+            qs_ref.dtype)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    s = lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # (block_q, block_k)
-    cols = k_start + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    mask = cols < l_real  # right-pad KV rows are dead
-    if causal:
-        rows = q_start + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
+    pad_k = n_k * block_k - l_real  # a fact of the shape, not traced
+
+    def step(masked: bool):
+        s = lax.dot_general(
+            qs_ref[...], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (block_q, block_k)
+        if masked:
+            cols = ki * block_k + lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            mask = cols < l_real if pad_k else None  # right-pad KV rows
+            if causal:
+                rows = qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0
+                )
+                visible = rows >= cols
+                mask = visible if mask is None else mask & visible
+            s = jnp.where(mask, s, _NEG_BIG)
+
+        m_prev = m_ref[...]  # (block_q, 128)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _across(m_new, block_k))
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = (
+            acc_ref[...] * _across(corr, acc_ref.shape[1])
+            + lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
         )
-        mask = mask & (rows >= cols)
-    s = jnp.where(mask, s, _NEG_BIG)
+        m_ref[...] = m_new
 
-    m_prev = m_ref[...]  # (block_q, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    edge = _holds_masked_scores(qi, ki, causal=causal, block_q=block_q,
+                                block_k=block_k, n_k=n_k, pad_k=pad_k)
+    if edge is None:
+        step(masked=False)
+    else:
+        pl.when(edge)(lambda: step(masked=True))
+        pl.when(jnp.logical_not(edge))(lambda: step(masked=False))
 
     @pl.when(ki == last_ki)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        # lse rides a (BH, T, 1) array: a 2-D (BH, T) output would put
-        # the BH axis in the block's last-two-dims window, where the TPU
-        # lowering rejects a block size of 1 (must divide 8 / equal the
-        # array dim — observed live in tpu_vma_probe.json round 5)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref)
 
 
 def _attn_kernel_rect(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc_ref, m_ref, l_ref, *,
+                      acc_ref, m_ref, l_ref, qs_ref, *,
                       scale, causal, block_q, block_k, n_k, l_real):
     """Full rectangular grid (BH, n_q, n_k), ki innermost. Non-causal
     always; also the causal fallback when the compressed walk's index
@@ -132,42 +249,34 @@ def _attn_kernel_rect(q_ref, k_ref, v_ref, o_ref, lse_ref,
     tiles still stream through VMEM but skip their matmuls."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    attend = functools.partial(
+        _attend_tile, q_ref, k_ref, v_ref, o_ref, lse_ref,
+        acc_ref, m_ref, l_ref, qs_ref, qi, ki, n_k - 1,
+        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        n_k=n_k, l_real=l_real)
     if not causal:
-        _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                     acc_ref, m_ref, l_ref, qi, ki, n_k - 1,
-                     scale=scale, causal=False,
-                     block_q=block_q, block_k=block_k, l_real=l_real)
+        attend()
         return
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_BIG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_carry(acc_ref, m_ref, l_ref)
 
     # a KV tile strictly right of this query tile's last row touches
     # nothing — skip its matmuls (its DMA still streams in this path)
     live = ki * block_k <= qi * block_q + block_q - 1
-
-    @pl.when(live)
-    def _attend():
-        _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                     acc_ref, m_ref, l_ref, qi, ki, n_k - 1,
-                     scale=scale, causal=True,
-                     block_q=block_q, block_k=block_k, l_real=l_real)
+    pl.when(live)(attend)
 
     @pl.when(ki == n_k - 1)
     def _finalize():
         # _attend_tile's own finalize only fires when the last tile is
         # live, which for a causal row it always is (diagonal end) — but
         # keep the rect path self-sufficient if block ratios change
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref)
 
 
 def _attn_kernel_causal(qids_ref, kids_ref, q_ref, k_ref, v_ref,
-                        o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
+                        o_ref, lse_ref, acc_ref, m_ref, l_ref, qs_ref, *,
                         scale, block_q, block_k, n_k, l_real):
     """Causal: compressed 1-D tile walk (BH, T) over ONLY the live
     (qi, ki) pairs, decoded from the scalar-prefetched index arrays —
@@ -181,9 +290,9 @@ def _attn_kernel_causal(qids_ref, kids_ref, q_ref, k_ref, v_ref,
         n_k - 1, (qi * block_q + block_q - 1) // block_k
     )
     _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                 acc_ref, m_ref, l_ref, qi, ki, last_ki,
-                 scale=scale, causal=True,
-                 block_q=block_q, block_k=block_k, l_real=l_real)
+                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, last_ki,
+                 scale=scale, causal=True, block_q=block_q,
+                 block_k=block_k, n_k=n_k, l_real=l_real)
 
 
 # compressed-walk ceiling: the (qids, kids) int32 pairs live in scalar
@@ -228,8 +337,13 @@ def _causal_tiles_kv(n_q: int, n_k: int, block_q: int, block_k: int):
 
 
 def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
-    """(BH, L, D) in → ((BH, L, D) out, (BH, L) logsumexp)."""
+    """(BH, L, D) in → ((BH, L, D) out, (BH, L) logsumexp). A block
+    the caller did not name (None) comes from the shape."""
     bh, l_real, d = q.shape
+    if block_q is None or block_k is None:
+        chosen = forward_blocks(l_real, d, q.dtype.itemsize)
+        block_q = chosen[0] if block_q is None else block_q
+        block_k = chosen[1] if block_k is None else block_k
     n_q = pl.cdiv(l_real, block_q)
     n_k = pl.cdiv(l_real, block_k)
     pad_q = n_q * block_q - l_real
@@ -246,10 +360,13 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
         _sds((bh, n_q * block_q, 1), jnp.float32, qp),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block_q, d), jnp.float32),   # acc
-        pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-        pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
+        pltpu.VMEM((block_q, d), jnp.float32),        # acc
+        pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
+        pltpu.VMEM((block_q, _LANES), jnp.float32),   # running denom
+        pltpu.VMEM((block_q, d), q.dtype),            # q * scale
     ]
+    # the operation's name in a trace says which tiles ran
+    name = f"flash_fwd_q{block_q}_k{block_k}"
     if causal:
         # one source of truth for the live-tile set: the gate below must
         # agree exactly with the SMEM index-array size it protects
@@ -288,7 +405,7 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
         )
         o, lse = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=_interpret(),
+            interpret=_interpret(), name=name,
         )(jnp.asarray(qids), jnp.asarray(kids), qp, kp, vp)
         return o[:, :l_real], lse[:, :l_real, 0]
 
@@ -315,7 +432,7 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
         ],
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        interpret=_interpret(),
+        interpret=_interpret(), name=name,
     )(qp, kp, vp)
     return o[:, :l_real], lse[:, :l_real, 0]
 
@@ -739,11 +856,16 @@ def _flash_2d_fwd(q, k, v, causal, scale, block_q, block_k, backward):
 
 
 def _flash_2d_bwd(causal, scale, block_q, block_k, backward, res, do):
+    # a backward whose caller names no block keeps the block it was
+    # measured with, whatever the forward chose for itself
     if backward == "pallas":
-        return _flash_bwd_2d_pallas(res, do, causal=causal, scale=scale,
-                                    block_q=block_q, block_k=block_k)
-    return _flash_bwd_2d(res, do, causal=causal, scale=scale,
-                         block_k=block_k)
+        return _flash_bwd_2d_pallas(
+            res, do, causal=causal, scale=scale,
+            block_q=_BLOCK_Q if block_q is None else block_q,
+            block_k=_BLOCK_K if block_k is None else block_k)
+    return _flash_bwd_2d(
+        res, do, causal=causal, scale=scale,
+        block_k=_BWD_SCAN_BLOCK_K if block_k is None else block_k)
 
 
 _flash_2d.defvjp(_flash_2d_fwd, _flash_2d_bwd)
@@ -756,8 +878,8 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: Optional[float] = None,
-    block_q: int = _BLOCK_Q,
-    block_k: int = _BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     backward: str = "xla",
 ) -> jax.Array:
     """Exact fused softmax attention, ``(B, L, H, D) → (B, L, H, D)``.
@@ -765,6 +887,9 @@ def flash_attention(
     Drop-in for ``parallel.sequence._single_device_attention`` (same
     semantics, tolerances at f32 rounding); differentiable via a
     blockwise custom VJP. ``scale`` defaults to ``D**-0.5``.
+    ``block_q`` / ``block_k``: a caller that names them gets them, in
+    the forward and the backward; left out, the forward's come from the
+    shape (``forward_blocks``) and the backward keeps 128.
     ``backward`` selects the VJP implementation: ``"xla"`` (default —
     blockwise lax.scan) or ``"pallas"`` (two fused kernels, dK/dV then
     dQ; opt-in until timed on hardware, the evidence-gating stance).
