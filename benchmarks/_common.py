@@ -103,9 +103,9 @@ def fetch_sync(out) -> float:
     calling ``block_until_ready``.
 
     A PJRT backend was once caught reporting buffer readiness before
-    execution completed (``tpu_overlap_probe.json``, 2026-07-31, over an
-    access path that is gone: the per-step "blocked" arm timed FASTER
-    than the chained arm). A device-to-host copy cannot complete before
+    execution completed (2026-07-31, over an access path that is gone:
+    the per-step "blocked" arm timed FASTER than the chained arm). A
+    device-to-host copy cannot complete before
     the value exists, so fetching one scalar of the last output is a
     barrier that holds whatever the runtime does. ``chip_smoke.py``
     times its steps under both barriers on the machine there is. For

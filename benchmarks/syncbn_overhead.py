@@ -104,11 +104,6 @@ def main():
         result["sync_pallas_ms_per_step"] = round(pallas_ms, 3)
         result["sync_xla_ms_per_step"] = round(xla_ms, 3)
         result["pallas_speedup_vs_xla"] = round(xla_ms / pallas_ms, 4)
-        # the evidence gate in ops.batch_norm ignores this measurement
-        # once the kernel sources change (it validated a binary)
-        from tpu_syncbn.ops.batch_norm import kernel_code_version
-
-        result["kernel_code_version"] = kernel_code_version()
     print(json.dumps(result))
 
 

@@ -9,7 +9,7 @@ One process, normal entry points only. With no option, on one chip:
   models.resnet50(num_classes=1000, dtype=bf16))`` → ``parallel.
   DataParallel(model, optax.sgd(0.1, momentum=0.9), loss_fn)`` fed by
   ``data.DataLoader`` + ``data.device_prefetch`` from a seeded synthetic
-  dataset, per-chip batch 64 at 224² (``bench.py``'s headline shape). The
+  dataset, per-chip batch 64 at 224². The
   step is compiled twice — ahead of time, then by the first dispatch —
   and the second must be a persistent-cache hit. Then 5 steps ended by
   ``block_until_ready`` and 5 ended by fetching a scalar. Every loss is

@@ -951,6 +951,14 @@ class TestSpanCorrelation:
         names = {e["name"] for e in t.events}
         assert {"checkpoint_save", "checkpoint_load",
                 "checkpoint_verify"} <= names
+        # an async save is counted where the loop pays it and lands in
+        # the same save histogram from the writer thread
+        with ckpt.AsyncCheckpointer() as writer:
+            writer.save(str(tmp_path), 2, tree)
+            assert writer.flush(timeout=30)
+        snap = telemetry.snapshot()
+        assert snap["counters"]["checkpoint.async_saves"] == 1
+        assert snap["histograms"]["checkpoint.save_s"]["count"] == 2
 
     def test_collective_tallies_count_at_trace_time(self):
         telemetry.set_enabled(True)

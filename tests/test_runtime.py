@@ -58,8 +58,8 @@ def test_logger_master_level():
 
 def test_logger_stream_env_knob(monkeypatch):
     """TPU_SYNCBN_LOG_STREAM=stderr reroutes a freshly created package
-    logger off stdout — bench.py sets it so its JSON result line owns
-    stdout (docs/PERFORMANCE.md satellite)."""
+    logger off stdout, for callers whose stdout is a parsed result
+    channel."""
     import sys
 
     monkeypatch.setenv("TPU_SYNCBN_LOG_STREAM", "stderr")

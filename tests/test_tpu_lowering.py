@@ -9,9 +9,8 @@ caught. The rule is enforced during lowering, not execution — so
 ``jax.jit(f).trace(args).lower(lowering_platforms=("tpu",))`` runs the
 full Mosaic pipeline on any host, no chip required.
 
-These tests force ``interpret()`` off via monkeypatch (the kernel
-sources are evidence-frozen; see ops/batch_norm.py::kernel_code_version)
-and TPU-lower every kernel entry point. Lowering proves the block specs
+These tests force ``interpret()`` off via monkeypatch (no edit to the
+kernel sources) and TPU-lower every kernel entry point. Lowering proves the block specs
 legal; ``test_tpu_compile.py`` asks the TPU compiler itself (VMEM,
 tiling); ``chip_smoke.py``'s kernel phase proves numerics on the chip.
 """

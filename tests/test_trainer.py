@@ -331,8 +331,8 @@ def test_grad_compression_validation():
 
 
 def test_lowered_train_step_cost_analysis():
-    # public AOT-lowering hook used by bench.py for MFU reporting: flops
-    # must be available from the lowered (pre-compile) module
+    # public AOT-lowering hook: flops must be available from the lowered
+    # (pre-compile) module
     m = tnn.convert_sync_batchnorm(SmallCNN(nnx.Rngs(0)))
     dp = parallel.DataParallel(m, optax.sgd(0.05), ce_loss, donate=False)
     batch = (
@@ -341,6 +341,74 @@ def test_lowered_train_step_cost_analysis():
     )
     cost = dp.lowered_train_step(batch).cost_analysis()
     assert cost.get("flops", 0) > 0
+
+
+def _resnet_trainer(chips):
+    from tpu_syncbn.models.resnet import Bottleneck, ResNet
+
+    model = tnn.convert_sync_batchnorm(ResNet(
+        Bottleneck, (1, 1, 1, 1), num_classes=10, width=8,
+        dtype=jnp.bfloat16, rngs=nnx.Rngs(0)))
+    dp = parallel.DataParallel(
+        model, optax.sgd(0.1, momentum=0.9),
+        lambda m, b: optax.softmax_cross_entropy_with_integer_labels(
+            m(b[0]).astype(jnp.float32), b[1]).mean(),
+        mesh=runtime.data_parallel_mesh(chips))
+    n = 2 * chips
+    return dp, (jnp.zeros((n, 32, 32, 3)), jnp.zeros((n,), jnp.int32))
+
+
+def _retinanet_trainer():
+    from tpu_syncbn.models import retinanet as rn
+    from tpu_syncbn.models.resnet import BasicBlock, ResNet
+
+    rngs = nnx.Rngs(0)
+    model = tnn.convert_sync_batchnorm(rn.RetinaNet(
+        num_classes=5, image_size=(64, 64), fpn_channels=16,
+        backbone=ResNet(BasicBlock, (1, 1, 1, 1), num_classes=1, width=8,
+                        rngs=rngs), rngs=rngs))
+    opt = optax.chain(optax.clip_by_global_norm(35.0),
+                      optax.add_decayed_weights(1e-4),
+                      optax.sgd(0.01, momentum=0.9))
+    dp = parallel.DataParallel(model, opt, lambda m, b: m.loss(*b),
+                               mesh=runtime.data_parallel_mesh(1))
+    batch = (jnp.zeros((2, 64, 64, 3)), jnp.zeros((2, 4, 4)),
+             jnp.zeros((2, 4), jnp.int32), jnp.zeros((2, 4), bool))
+    return dp, batch
+
+
+def _looped_lm_trainer():
+    from tpu_syncbn.models.looped_lm import LoopedDecoderLM
+
+    model = LoopedDecoderLM(
+        vocab_size=96, hidden_size=32, num_heads=4, head_dim=8,
+        intermediate_size=48, num_layers=2, loops=4, rope_theta=1e4,
+        exit_beta=0.1, attn_impl="xla", rngs=nnx.Rngs(0))
+    dp = parallel.DataParallel(
+        model, optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1),
+        lambda m, b: m.loss(*b), mesh=runtime.data_parallel_mesh(1))
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    return dp, (tokens, tokens)
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(lambda: _resnet_trainer(1), id="resnet-sgd-1chip"),
+    pytest.param(lambda: _resnet_trainer(4), id="resnet-sgd-mesh4"),
+    pytest.param(_retinanet_trainer, id="retinanet-sgd-wd-clip"),
+    pytest.param(_looped_lm_trainer, id="looped-lm-adamw"),
+])
+def test_two_constructions_lower_to_identical_text(build):
+    """Two independent constructions of a trainer lower to the same text:
+    same text + same jit options is the same compile-cache key, so a
+    later process's first step loads what an earlier one compiled (the
+    benchmark's ``setup_s`` and ``cache_misses`` rest on it). One case
+    for each trainer a cell of the benchmark builds, at toy sizes."""
+    texts = []
+    for _ in range(2):
+        dp, batch = build()
+        texts.append(dp.lowered_train_step(batch).as_text())
+    assert "func.func public @main" in texts[0]
+    assert texts[0] == texts[1]
 
 
 def test_vma_unvarying_grad_transpose_pinned():

@@ -112,7 +112,7 @@ def run_audit(
     entirely — no mesh, no trainer construction; the lint rules
     themselves are pure ``ast``. This function touches no environment
     variables — the CLI (``__main__``) forces and *restores* the pinned
-    CPU mesh around it, so calling in-process (tests, bench) leaks no
+    CPU mesh around it, so calling in-process (tests) leaks no
     config into the caller.
 
     ``lint_paths`` restricts the source lint to an explicit file list

@@ -19,7 +19,7 @@ before this module ran, so the platform also goes through
 ``jax.config``. The forced
 variables are snapshotted at import and restored when :func:`main`
 returns — the ``jax.config`` platform override included — so the
-module is callable in-process (tests, bench) without leaking
+module is callable in-process (tests) without leaking
 ``XLA_FLAGS``/``JAX_PLATFORMS`` into the caller; restoration only
 rolls back values *we* set, never a caller's own later changes. (A
 backend jax already initialized during the run stays initialized —

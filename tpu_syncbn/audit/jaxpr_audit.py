@@ -84,10 +84,7 @@ def lossy_collective_bytes(contract: ProgramContract) -> int:
     """The ISSUE 12 'lossy-eligible' wire bytes of a program: every
     collective byte except the ``pmin`` family — the divergence guard's
     finiteness consensus is pinned exact-fp32 and excluded from the
-    compression claim on both sides of the ratio. ONE predicate shared
-    by ``check_invariants`` and bench's ``collectives`` block, so the
-    contract invariant and the BASELINE-anchored ratio can't drift
-    apart."""
+    compression claim on both sides of the ratio."""
     return sum(v for k, v in contract.collective_bytes.items()
                if k != "pmin")
 

@@ -55,7 +55,7 @@ PREFIX_RE = re.compile(r"^[a-z0-9_]+$")
 #: (``telemetry.cardinality_dropped`` — the label-cap overflow tally,
 #: docs/OBSERVABILITY.md "Labels & cardinality").
 KNOWN_METRIC_PREFIXES = frozenset({
-    "audit", "autopilot", "bench", "checkpoint", "collectives", "compile",
+    "audit", "autopilot", "checkpoint", "collectives", "compile",
     "data", "events", "gan", "incident", "loader", "mem", "monitor",
     "numerics", "obs", "pipeline", "planner", "probe", "rendezvous",
     "resilience", "scan", "serve", "slo", "step", "telemetry", "train",
@@ -696,8 +696,7 @@ def check_telemetry_name_schema(
     """``telemetry_name_schema``: literal metric names must be dotted
     lowercase with a subsystem prefix (``serve.latency_s``) and
     ``CounterGroup`` prefixes a single token — the export/merge
-    contract (docs/OBSERVABILITY.md) and the cross-round bench trend
-    tooling both key on it."""
+    contract (docs/OBSERVABILITY.md) keys on it."""
     out: list[Violation] = []
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):

@@ -124,7 +124,7 @@ class FlightRecorder:
     incident-dump trigger machinery (module docstring has the design).
 
     ``aggregator`` shares an existing
-    :class:`~tpu_syncbn.obs.timeseries.WindowedAggregator` (bench, a
+    :class:`~tpu_syncbn.obs.timeseries.WindowedAggregator` (a
     monitored process) — otherwise the recorder owns one and
     :meth:`start` runs its background sampler. ``cooldown_s`` bounds
     dump frequency per recorder (``force=True`` — the manual trigger —
@@ -207,7 +207,7 @@ class FlightRecorder:
     def start(self) -> "FlightRecorder":
         """Arm the recorder: install a bounded
         :class:`~tpu_syncbn.obs.tracing.RingTracer` if no tracer is
-        recording (an existing tracer — e.g. ``bench --trace`` — is
+        recording (an existing tracer is
         tapped, not replaced), and start the owned aggregator's
         background sampler. Idempotent."""
         if tracing.get() is None:

@@ -80,8 +80,7 @@ DEFAULT_WIRE_RATE = 25e9
 
 #: Histogram families whose sums count as in-dispatch step time /
 #: data-wait time (the stepstats seams every loop records through).
-_DISPATCH_HISTS = ("step.time_s", "step.chunk_time_s",
-                   "scan.chunk_dispatch_s")
+_DISPATCH_HISTS = ("step.time_s", "step.chunk_time_s")
 _DATA_WAIT_HISTS = ("step.data_wait_s",)
 
 
@@ -225,7 +224,7 @@ def load_bundle(path: str) -> dict:
 
 def validate_bundle(bundle) -> dict:
     """Schema gate for an incident bundle (what tests/test_incident.py
-    and bench's ``incident`` block pin): raises ``ValueError`` on
+    pins): raises ``ValueError`` on
     drift, returns the bundle on success. The embedded registry and
     windowed snapshots validate against the telemetry schema and the
     trace slice against the Chrome trace-event schema — a bundle is
@@ -473,7 +472,7 @@ def attribution(
 def diff_attribution(a: dict | None, b: dict | None) -> dict:
     """Per-share deltas between two attribution reports (``b - a``) —
     the "which component moved" answer for an incident vs a healthy
-    baseline, or two bench rounds."""
+    baseline, or two runs."""
     sa = (a or {}).get("shares", {})
     sb = (b or {}).get("shares", {})
     keys = sorted(set(sa) | set(sb))

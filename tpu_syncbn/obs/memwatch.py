@@ -182,10 +182,10 @@ class MemorySampler:
     ``registry`` defaults to the process registry; publishing is gated
     on :func:`telemetry.enabled` (the obs cost contract). ``recorder``
     overrides where the mem ring + ``mem_pressure`` trigger go (default:
-    the installed process flight recorder; bench's planted drill passes
-    its own). ``pressure_threshold=None`` disables triggering (the
-    reconciler still publishes). ``device_reader`` / ``host_reader`` /
-    ``now`` are injectable for deterministic tests."""
+    the installed process flight recorder). ``pressure_threshold=None``
+    disables triggering (the reconciler still publishes).
+    ``device_reader`` / ``host_reader`` / ``now`` are injectable for
+    deterministic tests."""
 
     def __init__(
         self,
@@ -227,7 +227,7 @@ class MemorySampler:
         self._contract_source = contract_source
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
-        #: newest reading (JSON scalars), for tests / statusz / bench
+        #: newest reading (JSON scalars), for tests / statusz
         self.last: dict = {}
         self.samples = 0
 

@@ -41,9 +41,7 @@ drift.
 ``WindowedAggregator`` rolling views like every other metric, so
 :func:`numerics_rules` can pin SLO objectives on them
 (``numerics.ef_residual_ratio p99 < 0.5``, clip-saturation budget);
-``/statusz`` gains a numerics section; bench emits a schema-pinned
-``numerics`` block with a ``record_overhead_frac`` anchored in
-BASELINE.json (≤ 2% of step time). docs/OBSERVABILITY.md "Numerics &
+``/statusz`` gains a numerics section. docs/OBSERVABILITY.md "Numerics &
 drift" documents the monitor and metric tables.
 """
 
@@ -319,10 +317,8 @@ class NumericsPublisher:
     flight-recorder trigger, whose bundle carries the pre-drift monitor
     ring. The recorder's cooldown absorbs a monitor that stays hot.
 
-    ``ResilientLoop.run`` and ``bench.py`` drive one of these next to
-    ``flightrec.record_step``; the per-step cost is bench-measured
-    (``numerics.record_overhead_frac`` ≤ 2% of step time, anchored in
-    BASELINE.json)."""
+    ``ResilientLoop.run`` drives one of these next to
+    ``flightrec.record_step``."""
 
     def __init__(
         self,

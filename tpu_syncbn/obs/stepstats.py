@@ -8,9 +8,9 @@ scale and must be measured per step before it can be optimized):
 three seams of a training loop — data-wait (blocking on the input
 iterator), host→device transfer dispatch, and the step call itself —
 each recorded as a trace span (``obs.tracing``) AND a telemetry
-histogram (``obs.telemetry``) in one shot. ``bench.py`` and
-``runtime.resilience.ResilientLoop`` drive their loops through these, so
-a Perfetto timeline of any run shows ``data_wait`` / ``step`` /
+histogram (``obs.telemetry``) in one shot.
+``runtime.resilience.ResilientLoop`` drives its loop through these, so
+a Perfetto timeline of its run shows ``data_wait`` / ``step`` /
 ``checkpoint_*`` spans without code changes. Estimated collective
 traffic comes from ``parallel.collectives``' trace-time tallies
 (``collectives.<op>.calls`` / ``.bytes`` counters — per *compiled

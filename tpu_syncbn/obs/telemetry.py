@@ -5,11 +5,9 @@ printing (``README.md:9``); this module is the queryable replacement:
 every subsystem (trainer, loader, checkpoint store, resilience layer,
 rendezvous, collectives, backend probe) records into ONE process-wide
 :class:`Registry`, exported as JSONL per host and mergeable into a rank-0
-summary. ``bench.py`` embeds the registry snapshot as the ``telemetry``
-block of its JSON line, which is how step-time and sync-cost trends are
-tracked across rounds (DS-Sync, arxiv 2007.03298, and EQuARX, arxiv
-2506.17615, both make the case that per-step sync cost must be measured
-before it can be optimized).
+summary (DS-Sync, arxiv 2007.03298, and EQuARX, arxiv 2506.17615, both
+make the case that per-step sync cost must be measured before it can be
+optimized).
 
 Cost contract: telemetry is **off by default** and gated by the
 ``TPU_SYNCBN_TELEMETRY`` env var (truthy: ``1/true/on/yes``) or an
@@ -42,7 +40,7 @@ _ENV_FLAG = "TPU_SYNCBN_TELEMETRY"
 _TRUTHY = ("1", "true", "on", "yes")
 
 #: Bump when the snapshot/JSONL schema changes incompatibly
-#: (tests/test_bench_tooling.py pins bench's block against this).
+#: (tests/test_obs.py pins a snapshot against this).
 SCHEMA_VERSION = 1
 
 #: Default histogram buckets for durations in seconds: a 1-2.5-5 log
@@ -159,8 +157,7 @@ def enabled() -> bool:
 
 def set_enabled(value: bool | None) -> None:
     """Force telemetry on/off, or ``None`` to re-read the env gate on the
-    next :func:`enabled` call (tests; ``bench.py`` forces True so its
-    ``telemetry`` block is never empty)."""
+    next :func:`enabled` call (tests)."""
     global _enabled
     _enabled = None if value is None else bool(value)
 
@@ -367,7 +364,7 @@ class Registry:
             return len(self._instruments)
 
     def reset(self) -> None:
-        """Drop every instrument (tests; between bench phases)."""
+        """Drop every instrument (tests)."""
         with self._lock:
             self._instruments.clear()
             self._label_seen.clear()
@@ -376,8 +373,8 @@ class Registry:
     def snapshot(self) -> dict:
         """JSON-ready state of every instrument, grouped by kind:
         ``{"schema": 1, "counters": {...}, "gauges": {...},
-        "histograms": {...}}`` — the shape of bench's ``telemetry``
-        block (validated by :func:`validate_snapshot`)."""
+        "histograms": {...}}`` (validated by
+        :func:`validate_snapshot`)."""
         with self._lock:
             instruments = list(self._instruments.values())
         out: dict = {
@@ -525,7 +522,7 @@ def timed(name: str, buckets: Sequence[float] = DEFAULT_TIME_BUCKETS_S,
 
 # once-per-process-per-name DeprecationWarning for renamed metric
 # families (the suffix-metric -> label migration): old flat names keep
-# publishing so dashboards and BASELINE anchors keep resolving, but each
+# publishing so dashboards keep resolving, but each
 # warns once at its first mirror
 _deprecated_lock = threading.Lock()
 _deprecated_warned: set[str] = set()
@@ -570,7 +567,7 @@ class CounterGroup:
     telemetry is enabled, every bump is mirrored into the process
     :data:`REGISTRY` as ``{prefix}.{name}`` — so resilience events
     (rollbacks, rendezvous retries, watchdog stalls) ride the same JSONL
-    export and bench ``telemetry`` block as everything else, while the
+    export as everything else, while the
     instance's own counts keep working unconditionally (ResilientLoop's
     summary does not depend on the telemetry gate)."""
 
@@ -704,9 +701,9 @@ def write_merged_summary(paths: Iterable[str], out_path: str) -> dict:
 
 
 def validate_snapshot(snap: Any) -> dict:
-    """Schema check for a snapshot / bench ``telemetry`` block; returns
-    it on success, raises ``ValueError`` on drift (what
-    tests/test_bench_tooling.py pins, so output drift fails tier-1)."""
+    """Schema check for a snapshot; returns it on success, raises
+    ``ValueError`` on drift (tests/test_obs.py pins it, so output drift
+    fails tier-1)."""
     if not isinstance(snap, dict):
         raise ValueError(f"telemetry block must be a dict, got {type(snap)}")
     if snap.get("schema") != SCHEMA_VERSION:

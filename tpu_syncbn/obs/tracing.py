@@ -3,9 +3,8 @@
 A :class:`Tracer` records *complete* events (``ph: "X"``) with
 microsecond timestamps and durations; :meth:`Tracer.save` writes the
 ``{"traceEvents": [...]}`` JSON object that ``chrome://tracing`` and
-Perfetto (https://ui.perfetto.dev) open directly — ``bench.py --trace
-out.json`` is the one-command producer (docs/OBSERVABILITY.md has the
-how-to).
+Perfetto (https://ui.perfetto.dev) open directly
+(docs/OBSERVABILITY.md has the how-to).
 
 Span identity is the correlation currency: every span gets a
 process-unique integer id, carried in the event's ``args.span_id`` (and
@@ -15,7 +14,7 @@ into watchdog stall dumps and divergence-restore log lines
 timeline can be joined on it.
 
 Two ways on. :func:`install` makes a tracer the process tracer until
-:func:`uninstall` (``bench.py --trace``, the flight recorder). With none
+:func:`uninstall` (``ResilientLoop``, the flight recorder). With none
 installed, spans record for as long as a ``jax.profiler`` capture runs
 in this process (:func:`capture_active`): the first span site that sees
 the capture makes a :class:`RingTracer`, the *capture tracer*, which

@@ -39,21 +39,10 @@ import jax.numpy as jnp
 
 from tpu_syncbn.parallel.collectives import moments_from_stats, reduce_moments
 
-# lazily-resolved 'auto' decision, per process; cleared on every
-# set_pallas_mode call (defined before it — set_pallas_mode runs at
-# import time for the env-var override below)
-_AUTO_PALLAS_CACHE: list = []
-
-
 def set_pallas_mode(mode: str) -> None:
-    """Select the BN kernel backend: 'auto' (on TPU, Pallas if — and only
-    if — a hardware measurement of this kernel version, written by
-    ``benchmarks/syncbn_overhead.py`` to
-    ``benchmarks/artifacts/tpu_syncbn_overhead.json``, shows
-    ``pallas_speedup_vs_xla >= 1``; the XLA-fusion path otherwise — no
-    such record is in the tree today — and on every non-TPU backend),
-    'on' (always Pallas; interpret mode off-TPU), 'off' (always the
-    XLA-fusion path).
+    """Select the BN kernel backend: 'off' (the default: the XLA-fusion
+    path, on every backend) or 'on' (the Pallas kernels of
+    ``tpu_syncbn.ops.pallas_bn``; interpret mode off-TPU).
 
     Read at *trace* time for direct functional calls; the trainers
     (``DataParallel``/``GANTrainer``) additionally snapshot the
@@ -62,17 +51,13 @@ def set_pallas_mode(mode: str) -> None:
     jit-compiled keep the backend they were traced with.
     """
     global _PALLAS_MODE
-    if mode not in ("auto", "on", "off"):
-        raise ValueError(f"pallas mode must be auto/on/off, got {mode!r}")
+    if mode not in ("on", "off"):
+        raise ValueError(f"pallas mode must be on/off, got {mode!r}")
     _PALLAS_MODE = mode
-    # every mode change is a full re-decision: an overhead artifact that
-    # landed (or a kernel edited) mid-process would otherwise be ignored
-    # by a memoized 'auto' until the process restarts
-    _AUTO_PALLAS_CACHE.clear()
 
 
 def get_pallas_mode() -> str:
-    """The active BN kernel-backend mode ('auto'/'on'/'off')."""
+    """The active BN kernel-backend mode ('on'/'off')."""
     return _PALLAS_MODE
 
 
@@ -89,13 +74,12 @@ def pallas_mode(mode: str):
         set_pallas_mode(prev)
 
 
-_PALLAS_MODE = "auto"
+_PALLAS_MODE = "off"
 _ENV_ALIASES = {
     "1": "on", "true": "on", "yes": "on", "on": "on",
-    "0": "off", "false": "off", "no": "off", "off": "off",
-    "auto": "auto", "": "auto",
+    "0": "off", "false": "off", "no": "off", "off": "off", "": "off",
 }
-_env_mode = os.environ.get("TPU_SYNCBN_PALLAS", "auto").strip().lower()
+_env_mode = os.environ.get("TPU_SYNCBN_PALLAS", "off").strip().lower()
 if _env_mode in _ENV_ALIASES:
     set_pallas_mode(_ENV_ALIASES[_env_mode])
 else:
@@ -103,71 +87,12 @@ else:
 
     warnings.warn(
         f"ignoring unrecognized TPU_SYNCBN_PALLAS={_env_mode!r} "
-        "(expected on/off/auto or 1/0/true/false); using 'auto'"
+        "(expected on/off or 1/0/true/false); using 'off'"
     )
 
 
-def kernel_code_version() -> str:
-    """Fingerprint of the BN kernel sources. Hardware evidence (parity
-    cases, the overhead measurement gating 'auto') validates a *binary*,
-    not a file name — artifacts carry this and are ignored on mismatch."""
-    import hashlib
-
-    h = hashlib.sha256()
-    here = os.path.dirname(os.path.abspath(__file__))
-    # _pallas_common is part of the binary under test (pallas_bn imports
-    # its interpret heuristic), so it participates in the fingerprint
-    for name in ("pallas_bn.py", "batch_norm.py", "_pallas_common.py"):
-        with open(os.path.join(here, name), "rb") as f:
-            h.update(f.read())
-    return h.hexdigest()[:16]
-
-
-def _measured_pallas_speedup(path: str | None = None) -> float | None:
-    """The hardware evidence for the Pallas-vs-XLA decision:
-    ``benchmarks/artifacts/tpu_syncbn_overhead.json``'s
-    ``pallas_speedup_vs_xla`` (model-level step-time ratio measured on a
-    real chip by ``benchmarks/syncbn_overhead.py``). None when the
-    artifact is absent (as it is today: the 2026-07-31 record was removed
-    in PR 21), wasn't TPU-tagged, or measured a different kernel version
-    than the one about to trace."""
-    import json
-
-    if path is None:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        path = os.path.join(root, "benchmarks", "artifacts",
-                            "tpu_syncbn_overhead.json")
-    try:
-        with open(path) as f:
-            parsed = (json.load(f).get("parsed") or {})
-    except (OSError, ValueError):
-        return None
-    if parsed.get("backend") != "tpu":
-        return None
-    if parsed.get("kernel_code_version") != kernel_code_version():
-        return None
-    speedup = parsed.get("pallas_speedup_vs_xla")
-    return float(speedup) if isinstance(speedup, (int, float)) else None
-
-
 def _use_pallas() -> bool:
-    if _PALLAS_MODE == "on":
-        return True
-    if _PALLAS_MODE == "off":
-        return False
-    # 'auto' is evidence-gated: a hand kernel that loses to the XLA
-    # fusion it gates out would be a perf regression shipped as the
-    # default, so Pallas becomes the TPU default only once the committed
-    # hardware measurement shows it >= the XLA path. Until that artifact
-    # lands, 'auto' means the XLA-fusion path; Pallas stays one
-    # set_pallas_mode("on") away (parity-validated on chip either way).
-    if jax.default_backend() != "tpu":
-        return False
-    if not _AUTO_PALLAS_CACHE:
-        speedup = _measured_pallas_speedup()
-        _AUTO_PALLAS_CACHE.append(speedup is not None and speedup >= 1.0)
-    return _AUTO_PALLAS_CACHE[0]
+    return _PALLAS_MODE == "on"
 
 
 def _reduction_axes(ndim: int, channel_axis: int) -> tuple[int, ...]:

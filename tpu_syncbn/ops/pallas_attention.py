@@ -167,7 +167,7 @@ def _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref):
     # lse rides a (BH, T, 1) array: a 2-D (BH, T) output would put
     # the BH axis in the block's last-two-dims window, where the TPU
     # lowering rejects a block size of 1 (must divide 8 / equal the
-    # array dim — observed live in tpu_vma_probe.json round 5)
+    # array dim; tests/test_tpu_lowering.py holds the rule)
     lse_ref[0] = (m_ref[...] + jnp.log(l))[:, :1]
 
 

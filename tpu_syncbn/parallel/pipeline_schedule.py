@@ -27,8 +27,7 @@ Bubble accounting (docs/PERFORMANCE.md "Pipeline schedules"):
   ``predicted_bubble_frac = 1 − 2M / 2T = 1 − M/T``
 
   is the fraction of executed compute that is masked waste — the number
-  measured wall-time should track (``bench.py`` pins predicted vs
-  measured in the ``scan`` block).
+  measured wall-time should track.
 * The textbook GPipe figure :func:`canonical_gpipe_bubble`
   ``(N−1)/(M+N−1)`` assumes one-op ticks (idle *slots* over scheduled
   slots). Our lockstep GPipe is strictly worse than the textbook number
@@ -202,7 +201,7 @@ def dense_timing_schedule(m: int, n: int) -> Schedule:
     step trained with it computes garbage — but it executes exactly the
     same per-tick body as the real schedules with every mask on, so its
     wall time is the zero-bubble ideal the measured bubble fraction is
-    computed against (``bench.py``: ``1 − t_dense / t_schedule``)."""
+    computed against (``1 − t_dense / t_schedule``)."""
     _check_mn(m, n)
     col = np.arange(m, dtype=np.int32)
     fwd = np.tile(col[:, None], (1, n))

@@ -46,9 +46,7 @@ Consumption paths:
 * :class:`tpu_syncbn.runtime.autopilot.Autopilot` — planner-backed
   candidate-set mode: the controller walks ``RankedPlans.top(k)``
   when the measured step time violates the current plan's prediction
-  (the ``plan_change`` incident trigger);
-* ``bench.py`` — the ``planner`` block pins predicted-vs-measured
-  ordering (Kendall tau) for the top candidates.
+  (the ``plan_change`` incident trigger).
 
 Telemetry (``planner.*`` — docs/OBSERVABILITY.md "Planner"):
 ``planner.candidates_total`` / ``planner.candidates_feasible`` /
@@ -83,7 +81,7 @@ OBJECTIVES = ("step_time", "wire_bytes", "peak_memory")
 
 #: Host-side dispatch overhead charged per program launch — amortized
 #: by the scan chunk K (one fused K-step program is one dispatch). The
-#: default is the CPU-bench order of magnitude; calibrate via
+#: default is a CPU host's order of magnitude; calibrate via
 #: :class:`Rates` from a measured ``host_gap_s``.
 DEFAULT_DISPATCH_S = 200e-6
 
@@ -137,8 +135,8 @@ class LayerStack:
 
 
 def bench_stack() -> LayerStack:
-    """The bench model's planner description: a stack proxy sized to
-    the bench ResNet's block structure (deep, hidden-dim-heavy) but
+    """The default planner description: a stack proxy sized to
+    a ResNet's block structure (deep, hidden-dim-heavy) but
     traceable in milliseconds — what ``python -m tpu_syncbn.audit
     plan`` ranks by default (docs/PLANNER.md "The bench stack")."""
     return LayerStack(n_layers=8, d_model=64, d_hidden=256,
@@ -351,7 +349,7 @@ def assemble_cost(
 def kendall_tau(order_a: Sequence[str], order_b: Sequence[str]) -> float:
     """Kendall rank correlation between two orderings of the same
     items: +1.0 when every pair agrees, −1.0 when every pair is
-    inverted — the bench's predicted-vs-measured ordering gate."""
+    inverted (predicted against measured ordering)."""
     if sorted(order_a) != sorted(order_b):
         raise ValueError(
             f"orderings rank different items: {order_a} vs {order_b}"

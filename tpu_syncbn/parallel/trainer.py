@@ -1051,12 +1051,11 @@ class DataParallel:
 
         The idiomatic TPU training-loop shape (the step loop lives
         on-device; the chip never waits on the host between steps).
-        Measured against the host loop on one v5e chip on 2026-07-31
-        the two were within 1% — JAX's async dispatch kept the chip fed
-        (``benchmarks/artifacts/tpu_scan_dispatch.json``; not measured
-        at HEAD) — so this is an equivalence-proven alternative, not a
-        known speedup; it matters where dispatch IS the bottleneck (many tiny
-        steps, slow hosts, multi-process contention). The step body's
+        An equivalence-proven alternative to the host loop, not a known
+        speedup (not measured on the chip; JAX's async dispatch already
+        keeps two steps in flight): it matters where dispatch IS the
+        bottleneck (many tiny steps, slow hosts, multi-process
+        contention). The step body's
         stable VMA-typed in/out trees (see ``_make_step_fn``) are what
         make it a legal scan carry."""
         from tpu_syncbn.parallel import scan_driver

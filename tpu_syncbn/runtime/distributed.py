@@ -234,7 +234,7 @@ def _rendezvous_with_retry(
 
     def attempt():
         # attempt/failure counters ride telemetry so a flaky coordinator
-        # is countable from the bench/summary export, not only from the
+        # is countable from the summary export, not only from the
         # retry log lines (docs/OBSERVABILITY.md)
         telemetry.count("rendezvous.attempts")
         try:
@@ -342,7 +342,6 @@ def get_logger(name: str = "tpu_syncbn") -> logging.Logger:
             # default stream is stdout (the reference's master-print
             # console convention); TPU_SYNCBN_LOG_STREAM=stderr reroutes
             # for callers whose stdout is a parsed result channel
-            # (bench.py sets it so its JSON line owns stdout)
             stream = (
                 sys.stderr
                 if os.environ.get("TPU_SYNCBN_LOG_STREAM", "").lower()

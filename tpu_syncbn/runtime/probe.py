@@ -2,7 +2,7 @@
 
 The platform is what ``JAX_PLATFORMS`` names, or JAX's default when it
 is unset; this program never switches it. An entry point that needs the
-chip (``bench.py``, ``__graft_entry__``, ``chip_smoke.py``) calls
+chip (``chipbench/run.py``, ``__graft_entry__``, ``chip_smoke.py``) calls
 :func:`ensure_backend`, which raises when the backend is not a TPU with
 at least the devices asked for — a run that was meant for the chip must
 not quietly become a CPU run and still print a number.

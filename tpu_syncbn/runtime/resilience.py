@@ -476,8 +476,8 @@ class ResilientLoop:
     """
 
     #: Bounded wait for async checkpoint writes while a training failure
-    #: is already propagating: long enough for any healthy write (the
-    #: 204MB bench payload serializes in ~1s), short enough that a
+    #: is already propagating: long enough for any healthy write (a
+    #: 204MB payload serializes in ~1s), short enough that a
     #: wedged writer (stuck filesystem) can't turn a StallError into an
     #: indefinite hang with the watchdog already disarmed.
     _EXC_FLUSH_TIMEOUT_S = 60.0
@@ -803,9 +803,7 @@ class ResilientLoop:
                 steps_run = 0
                 # explicit next() so the wait-for-data seam is measurable:
                 # each blocking fetch is a "data_wait" span + histogram
-                # sample, each step (or fused chunk) a span — the same
-                # seams bench.py instruments, so any loop's trace reads
-                # the same way
+                # sample, each step (or fused chunk) a span
                 for batch in stepstats.instrumented_batches(batches):
                     if max_steps is not None and steps_run >= max_steps:
                         break

@@ -21,9 +21,9 @@ The first non-training subsystem in the codebase (ROADMAP north star:
   :class:`CircuitBreaker` with PR 1 deterministic-jitter backoff
   half-open probes (docs/RESILIENCE.md "Serving failure modes").
 * :mod:`tpu_syncbn.serve.loadgen` — open-loop Poisson/trace-driven
-  load generation (:class:`OpenLoopLoadGen`): the offered-load-sweep
-  harness ``bench --serve`` uses to prove graceful degradation past
-  saturation (bounded p99, rising sheds — never queueing collapse).
+  load generation (:class:`OpenLoopLoadGen`): the offered-load sweep
+  that shows graceful degradation past saturation (bounded p99, rising
+  sheds — never queueing collapse).
 * :mod:`tpu_syncbn.serve.publish` — zero-downtime weight publication:
   :class:`SwapController` hot-swaps manifest-verified published
   versions (or a live trainer's params, re-sharded on the mesh via
@@ -42,10 +42,9 @@ Quickstart::
         fut = batcher.submit(x[i:i + 1])           # per-request future
         logits = fut.result()
 
-``bench.py --serve`` runs a closed-loop offered-load sweep against this
-stack and reports throughput / p50-p99 latency / batch-fill ratio in the
-schema-pinned ``serve`` block (docs/PERFORMANCE.md "Serving";
-docs/OBSERVABILITY.md for the ``serve.*`` metric schemas).
+docs/PERFORMANCE.md "Serving" says how to drive this stack with
+``serve.loadgen``; docs/OBSERVABILITY.md has the ``serve.*`` metric
+schemas.
 """
 
 from tpu_syncbn.parallel.zero import unshard_params  # noqa: F401

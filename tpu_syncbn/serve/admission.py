@@ -1,7 +1,7 @@
 """Overload-aware admission: deadlines, EDF dispatch, load shedding,
 and circuit breaking for the serving stack.
 
-``bench --serve``'s closed-loop sweep (PR 5) can never push the batcher
+A closed-loop sweep can never push the batcher
 past saturation — each client waits for its answer before sending the
 next request, so offered load self-limits. Real traffic is *open-loop*:
 arrivals do not care how backed up the server is, and past the

@@ -102,7 +102,7 @@ class InferenceEngine:
     slices the padding back off; batches larger than the biggest bucket
     are chunked through it.
 
-    Telemetry (``TPU_SYNCBN_TELEMETRY`` / bench force-enable):
+    Telemetry (``TPU_SYNCBN_TELEMETRY``):
     ``serve.infer_s`` per-program-call histogram, ``serve.compiles``
     counter + ``serve.compile_s`` histogram, and a ``serve.infer`` trace
     span per call (docs/OBSERVABILITY.md).

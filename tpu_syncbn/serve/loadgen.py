@@ -1,7 +1,7 @@
 """Open-loop load generation: the only way to see a server past
 saturation.
 
-A *closed-loop* client (``bench --serve``'s PR 5 sweep) waits for each
+A *closed-loop* client waits for each
 answer before sending the next request, so offered load self-limits at
 the server's capacity — queueing collapse is unobservable by
 construction. An *open-loop* generator submits on a fixed arrival
@@ -24,9 +24,7 @@ Usage::
                                       seed=0))
     report.goodput_rps, report.latency_p99_ms, report.shed_rate
 
-``sweep()`` runs several offered-load levels and returns their reports —
-the shape ``bench --serve``'s schema-pinned ``open_loop`` section is
-built from.
+``sweep()`` runs several offered-load levels and returns their reports.
 """
 
 from __future__ import annotations
@@ -139,7 +137,7 @@ class LoadReport:
         return None if v is None else v * 1e3
 
     def summary(self) -> dict:
-        """JSON-ready block (the bench ``open_loop`` level schema)."""
+        """JSON-ready block: one offered-load level."""
         p50 = self.latency_ms(0.50)
         p99 = self.latency_ms(0.99)
         return {

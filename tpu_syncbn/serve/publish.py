@@ -48,8 +48,7 @@ recompile). A :class:`SwapController` rolls a new weight version into a
 The deterministic chaos matrix over this path (corrupt publication,
 SIGTERM mid-swap, crash-on-first-new-version-batch, version skew,
 memwatch abort) lives in :mod:`tpu_syncbn.testing.faults` +
-tests/test_publish.py; ``bench.py --serve`` measures the swap under
-open-loop load in the schema-pinned ``publish`` block.
+tests/test_publish.py.
 """
 
 from __future__ import annotations

@@ -56,8 +56,7 @@ PUBLISHED_POINTER = "published.json"
 
 #: Payloads up to this size also get a CRC32 (serial, ~1 GB/s); above it
 #: only the vectorized ``sum64`` checksum is computed, keeping manifest
-#: verification <5% of the checkpoint round-trip at any size (the
-#: bench.py ``recovery`` block records the measured fraction).
+#: verification a small share of the checkpoint round-trip at any size.
 _CRC32_MAX_BYTES = int(
     float(os.environ.get("TPU_SYNCBN_CKPT_CRC32_MAX_MB", "32")) * (1 << 20)
 )
@@ -738,9 +737,7 @@ class AsyncCheckpointer:
     integrity manifest (PR 1: sum64/CRC32/tree hash, byte-identical to
     the synchronous path's), the atomic writes, and pruning to ONE
     background thread. The step loop pays the fetch and nothing else:
-    steady-state step time stays flat across saves (bench.py's
-    ``recovery`` block tracks ``ckpt_async_enqueue_s`` vs the full
-    synchronous round-trip).
+    steady-state step time stays flat across saves.
 
     Ordering and durability:
 
