@@ -12,6 +12,8 @@ flash kernel's tiles against a whole row). Outputs and losses agree to
 applications and 64 positions, to 1e-4 of the largest entry of a leaf.
 """
 
+import contextlib
+import io
 import os
 import sys
 
@@ -212,6 +214,130 @@ def test_recomputation_and_the_attention_kernel_change_only_rounding(variant):
     np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
     assert_trees_close(grads, want,
                        rel=1e-4 if "attn_impl" in variant else 1e-5)
+
+
+# -- what the layer's checkpoint keeps ------------------------------------------
+
+# the name of each output the layer marks, and the shape it has below
+_NAMED = {"q": "heads", "k": "heads", "v": "heads", "attn_proj": "hidden",
+          "mlp_out": "hidden"}
+
+
+def computed_residuals(fn, *args):
+    """The shapes ``jax.ad_checkpoint.print_saved_residuals`` lists for
+    ``fn``'s backward pass, those that ``fn`` computed: arguments and
+    constants, which cost nothing more to keep, are left out."""
+    printed = io.StringIO()
+    with contextlib.redirect_stdout(printed):
+        jax.ad_checkpoint.print_saved_residuals(fn, *args)
+    shapes = []
+    for line in printed.getvalue().splitlines():
+        aval, _, origin = line.partition(" ")
+        if not origin.startswith(("from the argument", "from a constant",
+                                  "from a literal")):
+            dims = aval[aval.index("[") + 1:aval.index("]")]
+            shapes.append(tuple(int(d) for d in dims.split(",") if d))
+    return shapes
+
+
+def plainly_checkpointed(monkeypatch):
+    """Every ``remat`` of the model a plain ``jax.checkpoint(fn)``: what
+    the layer had before it named its outputs."""
+    monkeypatch.setattr(
+        looped_lm.LoopedDecoderLM, "_checkpointed",
+        lambda self, fn, saved=None: jax.checkpoint(fn) if self.remat else fn)
+
+
+def lowered_step(model, batch) -> str:
+    graphdef, params = nnx.split(model, nnx.Param)
+    return jax.jit(jax.value_and_grad(
+        lambda p: nnx.merge(graphdef, p).loss(*batch)[0])).lower(
+            params).as_text()
+
+
+@pytest.mark.parametrize("saved", [
+    (), ("mlp_out",), ("q", "k"), ("v",), ("attn_proj",), None,
+    tuple(_NAMED)], ids=lambda s: "default" if s is None else
+    "+".join(s) or "nothing")
+def test_the_layers_checkpoint_saves_the_named_outputs_and_nothing_wider(
+        saved, monkeypatch):
+    """One layer application under the model's own checkpoint: beside
+    its arguments the backward pass keeps one tensor for each name in
+    ``_SAVED``, of that output's shape, and none as wide as
+    ``intermediate_size``; the lowered step runs one ``dot_general``
+    fewer for each than under a plain ``jax.checkpoint(fn)``. (``q``
+    and ``k`` pass one barrier together, so they are kept together: to
+    recompute one is to recompute both.)"""
+    if saved is not None:
+        monkeypatch.setattr(looped_lm, "_SAVED", saved)
+    saved = looped_lm._SAVED
+    model, batch = make(loops=2, num_layers=3), batch_of()
+    h = model.embed_tokens(batch[0])
+    p = jax.tree_util.tree_map(lambda a: a[0], model._stacked())
+    layer = model._checkpointed(model._layer, saved)
+    kept = computed_residuals(layer, h, p, *model._angles(SEQ))
+    shape = {"heads": (2, SEQ, SIZES["num_heads"], SIZES["head_dim"]),
+             "hidden": (2, SEQ, SIZES["hidden_size"])}
+    assert sorted(kept) == sorted(shape[_NAMED[name]] for name in saved)
+    assert all(s[-1:] != (SIZES["intermediate_size"],) for s in kept)
+    with_names = lowered_step(model, batch)
+    plainly_checkpointed(monkeypatch)
+    without = lowered_step(make(loops=2, num_layers=3), batch)
+    assert (with_names.count("dot_general")
+            == without.count("dot_general") - len(saved))
+
+
+def test_the_head_is_checkpointed_plainly_and_the_layer_without_barriers(
+        monkeypatch):
+    """The lowered step keeps the barriers of the head's plain
+    ``jax.checkpoint`` and has none for the layer's recomputation
+    (``prevent_cse=False``: the layer runs inside a scan, where they buy
+    nothing); the layer's own, around q and k, is in every step, with
+    ``remat`` or without."""
+    batch = batch_of()
+
+    def barriers(model):
+        return lowered_step(model, batch).count("optimization_barrier")
+
+    ours, none = barriers(make()), barriers(make(remat=False))
+    assert ours > none > 0
+    plainly_checkpointed(monkeypatch)
+    assert barriers(make()) > ours
+
+
+@pytest.mark.parametrize("attn_impl", looped_lm.ATTN_IMPLS)
+def test_saved_outputs_are_the_values_recomputation_would_give(
+        attn_impl, monkeypatch):
+    """In bfloat16, the type the chip runs: loss and every gradient with
+    the policy are bit for bit those of a plain ``jax.checkpoint(fn)``
+    around the same layer."""
+    batch = batch_of()
+    kwargs = dict(attn_impl=attn_impl, dtype=jnp.bfloat16)
+    loss, _, grads = loss_and_grads(make(**kwargs), batch)
+    plainly_checkpointed(monkeypatch)
+    want_loss, _, want = loss_and_grads(make(**kwargs), batch)
+    assert float(loss) == float(want_loss)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, grads, want)
+
+
+def test_the_heads_checkpoint_keeps_nothing_of_vocabulary_width():
+    """``read_pass`` under the model's checkpoint keeps its arguments and
+    the parameters it closes over; through the whole loss no computed
+    residual is as wide as the vocabulary (the logits of a pass) or as
+    ``intermediate_size``."""
+    model, (tokens, targets) = make(), batch_of()
+    h = model.embed_tokens(tokens)
+    assert computed_residuals(
+        model._checkpointed(model.read_pass), h, targets) == []
+    kept = computed_residuals(lambda m: m.loss(tokens, targets)[0], model)
+    stacked = (model.loops, SIZES["num_layers"], 2, SEQ)
+    assert sum(s[:4] == stacked for s in kept) == 1 + len(looped_lm._SAVED)
+    wide = ((SIZES["vocab_size"],), (SIZES["intermediate_size"],))
+    assert not any(s[-1:] in wide for s in kept)
+    # and with no recomputation the logits are kept: the check can see them
+    free = computed_residuals(lambda m: m.loss(tokens, targets)[0],
+                              make(remat=False))
+    assert any(s[-1:] == wide[0] for s in free)
 
 
 def test_rotary_is_a_complex_rotation_of_paired_dimensions():

@@ -34,10 +34,19 @@ The layers are stacked on a leading axis and applied by ``lax.scan``;
 the passes are a second scan around it that closes over the same stacked
 parameters, so the backward pass sums each weight's gradient over its T
 uses. With ``remat`` one layer application and each pass's head +
-cross-entropy are ``jax.checkpoint``ed and nothing inside them is saved:
-the backward pass keeps the layer inputs (T x L x B x S x H) and
-recomputes the rest, and the T sets of (B, S, vocabulary) logits are
-never alive together.
+cross-entropy are ``jax.checkpoint``ed, so the T sets of (B, S,
+vocabulary) logits are never alive together and nothing of the head is
+saved. Of a layer application the backward pass keeps its input and the
+outputs named in ``_SAVED`` (``save_only_these_names``: the down
+projection's output ``mlp_out``, and ``q`` and ``k`` after the rotation),
+and recomputes the rest: ``(1 + len(_SAVED)) x T x L x B x S x H x
+itemsize`` bytes where a plain ``jax.checkpoint`` keeps ``1 x``. Only
+hidden-width outputs are ever named, never the two
+``intermediate_size``-wide ones, so what ``remat`` keeps scales with
+what it always kept. ``q`` and ``k`` pass one
+``lax.optimization_barrier`` before they are named, and the layer's
+checkpoint has ``prevent_cse=False``: both are about what the TPU
+compiler fuses, neither changes a value (measured below).
 
 Measured on a TPU v5e at 8 layers x 4 passes of hidden 2048, 16 heads of
 128, 2 x 2,048 tokens, 612M parameters under AdamW (PERF.md section 6,
@@ -45,7 +54,8 @@ PR 30). The passes as a scan or unrolled: the same step (847 and 850 ms)
 at 14.4 against 16.4 GB, so a scan. Saving every matmul's output
 (``dots_with_no_batch_dims_saveable``) wants 22.6 GiB and without
 recomputation the step wants 71 GiB of the chip's 15.75. ``attn_impl``:
-``"xla"`` 847 ms a step, ``"flash"`` 643 (the kernel's forward with its
+``"xla"`` 847 ms a step, ``"flash"`` 643 (606 since PR 33 with what the
+layer keeps, below; the kernel's forward with its
 backward as an XLA scan over key blocks; 774 in PR 30, before the
 forward chose its tiles from the shape, PERF.md section 6, PR 31; with
 the kernel's own two backward kernels,
@@ -56,6 +66,39 @@ runs everywhere (the kernel's interpret mode does not pass
 chip names ``"flash"``. The two differ in one rounding: ``"xla"``
 rounds the probabilities to ``dtype`` before they meet v, the kernel
 keeps them in float32.
+
+What the layer's checkpoint keeps, measured there with ``"flash"`` (one
+traced run each, PERF.md section 6, PR 33; ms a step, bytes at the peak).
+A saved (T, L, B, S, H) stack is written and read through the two scans
+by four copies, 0.62-0.70 GB and about 7 ms a name where the closed
+form says 0.54 GB and 1.3 ms, so a name pays only where its
+recomputation costs more: ``mlp_out`` (a 16.7 ms product) and ``q``,
+``k`` (a 6 ms product and a 5 ms rotation each) do, ``v`` (a 7 ms
+product) comes out even, ``attn_proj`` slows every neighbour.
+
+=========================================  ======  =========  ========
+kept beside the layer's input              step    recompute  bytes
+=========================================  ======  =========  ========
+nothing, plain ``jax.checkpoint`` (PR 31)  642.59  124.02     13.888e9
+nothing, ``prevent_cse=False``             632.26  124.15     13.790e9
++ the barrier around q and k               625.77  124.12     13.790e9
++ ``mlp_out``                              619.08  109.33     14.444e9
++ ``q``, ``k``: ``_SAVED``                 605.55  84.20      15.786e9
++ ``v``                                    605.70  76.33      16.457e9
+``mlp_out``, ``attn_proj``, no barrier     643.36  117.69     15.139e9
+``mlp_out``, ``q``, ``k``, no barrier      629.88  84.20      15.786e9
+the same, plain ``jax.checkpoint``         634.85  81.11      15.854e9
+=========================================  ======  =========  ========
+
+Without ``prevent_cse=False`` every recomputation reads its operands
+through an ``optimization_barrier``, which made the compiler copy each
+layer's slice of every stacked weight (268 slices, 11 ms a step); the
+layer only runs inside ``stack``'s scan, whose forward and backward
+loops the compiler cannot merge anyway. Without the barrier around q
+and k the compiler folds the rotation into each of its readers: saving
+them then computes it twice more in the forward pass (+20 ms, which is
+all that saving them had bought), and even with nothing saved the
+backward pass pays 6.5 ms for the same reason.
 
 Scopes (``jax.named_scope``, docs/OBSERVABILITY.md): ``loop_stack``
 around the layer scan, ``attention`` around scores-softmax-values (or
@@ -69,10 +112,15 @@ import jax
 import jax.numpy as jnp
 from flax import nnx
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 ATTN_IMPLS = ("xla", "flash")
 _MATRICES = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
 _NORMS = ("norm1", "norm2", "norm3", "norm4")
+# The outputs of a layer application that its ``jax.checkpoint`` keeps
+# for the backward pass, of those the layer names (``v`` and
+# ``attn_proj`` are named, measured and left out: module docstring).
+_SAVED = ("mlp_out", "q", "k")
 
 
 def rms_norm(x, scale, eps):
@@ -202,7 +250,14 @@ class LoopedDecoderLM(nnx.Module):
         n = rms_norm(x, p["norm1"], self.rms_eps)
         q = apply_rotary(self._dot(n, p["wq"]).reshape(heads), cos, sin)
         k = apply_rotary(self._dot(n, p["wk"]).reshape(heads), cos, sin)
-        return q, k, self._dot(n, p["wv"]).reshape(heads)
+        v = self._dot(n, p["wv"]).reshape(heads)
+        # q and k are written once, here, for all their readers (the
+        # attention core, its backward pass, the saved stack): left to
+        # itself the TPU compiler folds the rotation into each reader
+        # and computes it again there (module docstring, measured).
+        q, k = lax.optimization_barrier((q, k))
+        return (checkpoint_name(q, "q"), checkpoint_name(k, "k"),
+                checkpoint_name(v, "v"))
 
     def _attend(self, q, k, v):
         with jax.named_scope("attention"):
@@ -212,13 +267,16 @@ class LoopedDecoderLM(nnx.Module):
         """The rest of a layer from its input ``x`` and the attention
         core's output ``o``."""
         b, s, _ = x.shape
-        o = self._dot(o.reshape(b, s, -1), p["wo"])
+        o = checkpoint_name(self._dot(o.reshape(b, s, -1), p["wo"]),
+                            "attn_proj")
         a = x + rms_norm(o, p["norm2"], self.rms_eps)
         with jax.named_scope("mlp"):
             n = rms_norm(a, p["norm3"], self.rms_eps)
             gate = self._dot(n, p["wg"]).astype(jnp.float32)
             up = self._dot(n, p["wu"]).astype(jnp.float32)
-            m = self._dot((jax.nn.silu(gate) * up).astype(self.dtype), p["wd"])
+            m = checkpoint_name(self._dot(
+                (jax.nn.silu(gate) * up).astype(self.dtype), p["wd"]),
+                "mlp_out")
         return a + rms_norm(m, p["norm4"], self.rms_eps)
 
     def _layer(self, x, p, cos, sin):
@@ -234,8 +292,21 @@ class LoopedDecoderLM(nnx.Module):
     def _angles(self, seq_len: int):
         return rotary_angles(seq_len, self.head_dim, self.rope_theta)
 
-    def _checkpointed(self, fn):
-        return jax.checkpoint(fn) if self.remat else fn
+    def _checkpointed(self, fn, saved=None):
+        """``fn`` under ``jax.checkpoint`` where ``remat`` says so. For
+        the head, plainly. For the layer, ``saved`` names the outputs
+        the backward pass keeps and does not recompute, and
+        ``prevent_cse=False`` drops the barriers that guard a
+        recomputation against being merged with the forward pass: the
+        layer only ever runs inside ``stack``'s scan, whose forward and
+        backward loops are separate programs to the compiler."""
+        if not self.remat:
+            return fn
+        if saved is None:
+            return jax.checkpoint(fn)
+        return jax.checkpoint(
+            fn, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names(*saved))
 
     # -- the pieces a caller may read -----------------------------------------
 
@@ -258,7 +329,7 @@ class LoopedDecoderLM(nnx.Module):
     def stack(self, h):
         """One pass: every layer once, in order."""
         cos, sin = self._angles(h.shape[1])
-        layer = self._checkpointed(self._layer)
+        layer = self._checkpointed(self._layer, _SAVED)
         with jax.named_scope("loop_stack"):
             h, _ = lax.scan(lambda x, p: (layer(x, p, cos, sin), None),
                             h, self._stacked())
