@@ -92,7 +92,11 @@ def test_rejects_mismatched_shapes():
     with pytest.raises(ValueError, match="identical"):
         pa.flash_attention(q, jnp.zeros((1, 32, 2, 8)), q)
     with pytest.raises(ValueError, match="identical"):
-        pa.flash_attention(q, q, jnp.zeros((1, 16, 2, 4)))
+        pa.flash_attention(q, q, jnp.zeros((1, 32, 2, 8)))
+    # v may have a head width of its own (test_flash_attention_widths.py)
+    assert pa.flash_attention(
+        q, q, jnp.zeros((1, 16, 2, 4)), block_q=16, block_k=16
+    ).shape == (1, 16, 2, 4)
 
 
 class TestCausalTileWalk:

@@ -188,3 +188,60 @@ def test_looped_decoder_compiles_for_v5e(v5e, mosaic):
         on_chip(params), tokens, tokens).compile()
     # the forward kernel and its recomputation; the backward is XLA's scan
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_flash_attention_latent_widths_compile_for_v5e(v5e, mosaic):
+    """Latent attention's call in the benchmark's cell
+    (``joyai-l5-train-b1x8192``): q and k 192 wide, which is no multiple
+    of the 128 lanes, v and the output 128, 32 heads, 8,192 tokens; the
+    tiles the shape chooses, the backward an XLA scan."""
+    assert pa.forward_blocks(8192, 192, 2, 128) == (512, 512)
+    qk = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16, sharding=v5e)
+    v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32).sum()
+
+    _compile_fwd_and_grad(loss, qk, qk, v)
+
+
+def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
+    """The latent-attention mixture-of-experts decoder at
+    JoyAI-LLM-Flash's published widths (hidden 2048, 32 heads of 192 /
+    128, ranks 1536 and 512, experts 768 wide, a 256-way router with 16
+    experts held, 8 a token), one sequence of 4,096 tokens, through the
+    flash kernel and the grouped products with per-layer recomputation:
+    loss and gradients in one program. Cut where the compile time is:
+    one dense and one expert layer, the prediction module, a sixteenth of
+    the slice of the vocabulary; the benchmark's cell runs 1 + 4, 8,192
+    tokens and 16,160 ids."""
+    from flax import nnx
+
+    from tpu_syncbn.models.moe_lm import LatentMoEDecoderLM
+
+    abstract = nnx.eval_shape(lambda: LatentMoEDecoderLM(
+        vocab_size=1024, hidden_size=2048, num_heads=32, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_dim=128,
+        dense_layers=1, dense_intermediate=7168, moe_layers=1, n_experts=256,
+        experts_held=16, experts_per_token=8, moe_intermediate=768,
+        shared_intermediate=768, routed_scale=2.5, mtp=True,
+        rope_theta=32e6, dtype=jnp.bfloat16, attn_impl="flash",
+        rngs=nnx.Rngs(0)))
+    graphdef, params, rest = nnx.split(abstract, nnx.Param, ...)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), t)
+    tokens = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=v5e)
+
+    def loss(p, r, tokens, targets, targets2):
+        # copy=True: the router state moves on variables of this trace
+        return nnx.merge(graphdef, p, r, copy=True).loss(
+            tokens, targets, targets2)[0]
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        on_chip(params), on_chip(rest), tokens, tokens, tokens
+    ).compile().as_text()
+    # the attention kernel of three layer applications, forward and
+    # recomputed; the grouped products are the compiler's own kernels
+    assert text.count("flash_fwd_q512_k512") >= 6
+    assert "ragged-dot" in text

@@ -1,10 +1,12 @@
 """Model zoo: the architectures named by the reference's capability configs
 (ResNet-18/50, RetinaNet-R50-FPN, DCGAN/SNGAN — BASELINE.json), plus the
-transformer LM that exercises the long-context path and the looped
-decoder LM that trains through ``DataParallel``."""
+transformer LM that exercises the long-context path, and the two
+decoder LMs that train through ``DataParallel``: the looped one and the
+latent-attention mixture of experts."""
 
-from tpu_syncbn.models import detection, gan, looped_lm, transformer
+from tpu_syncbn.models import detection, gan, looped_lm, moe_lm, transformer
 from tpu_syncbn.models.looped_lm import LoopedDecoderLM
+from tpu_syncbn.models.moe_lm import LatentMoEDecoderLM
 from tpu_syncbn.models.transformer import init_transformer_lm, transformer_lm
 from tpu_syncbn.models.gan import (
     DCGANGenerator,
@@ -50,4 +52,6 @@ __all__ = [
     "transformer_lm",
     "looped_lm",
     "LoopedDecoderLM",
+    "moe_lm",
+    "LatentMoEDecoderLM",
 ]

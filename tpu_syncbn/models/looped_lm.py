@@ -180,6 +180,20 @@ def exit_distribution(lam):
     return reached * exits
 
 
+def checkpointed(fn, saved=None):
+    """``fn`` under ``jax.checkpoint``. For a head, plainly. For a
+    layer, ``saved`` names the outputs the backward pass keeps and does
+    not recompute, and ``prevent_cse=False`` drops the barriers that
+    guard a recomputation against being merged with the forward pass:
+    a layer only ever runs inside a scan, whose forward and backward
+    loops are separate programs to the compiler."""
+    if saved is None:
+        return jax.checkpoint(fn)
+    return jax.checkpoint(
+        fn, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(*saved))
+
+
 def _normal(rngs: nnx.Rngs, std: float):
     """``normal(*shape)``: a Param drawn from normal(0, std)."""
     return lambda *shape: nnx.Param(
@@ -293,20 +307,8 @@ class LoopedDecoderLM(nnx.Module):
         return rotary_angles(seq_len, self.head_dim, self.rope_theta)
 
     def _checkpointed(self, fn, saved=None):
-        """``fn`` under ``jax.checkpoint`` where ``remat`` says so. For
-        the head, plainly. For the layer, ``saved`` names the outputs
-        the backward pass keeps and does not recompute, and
-        ``prevent_cse=False`` drops the barriers that guard a
-        recomputation against being merged with the forward pass: the
-        layer only ever runs inside ``stack``'s scan, whose forward and
-        backward loops are separate programs to the compiler."""
-        if not self.remat:
-            return fn
-        if saved is None:
-            return jax.checkpoint(fn)
-        return jax.checkpoint(
-            fn, prevent_cse=False,
-            policy=jax.checkpoint_policies.save_only_these_names(*saved))
+        """``fn`` under ``jax.checkpoint`` where ``remat`` says so."""
+        return checkpointed(fn, saved) if self.remat else fn
 
     # -- the pieces a caller may read -----------------------------------------
 

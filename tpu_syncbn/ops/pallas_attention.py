@@ -24,6 +24,12 @@ iota. The forward's tiles come from the call's shape
 allows) unless the caller names them: a grid step costs 0.3-0.4 us
 whatever it holds, so the walk is made of few, large steps.
 
+q and k share one head width and v (with the output) may have another
+(latent attention: 192 for q and k, a rotary part beside the 128 that v
+has); neither has to be a multiple of the 128 lanes, a tile holds the
+whole width. Where the two are equal the kernel is the one it was before
+v had a width: the same tiles, grid and name (PERF.md section 6, PR 34).
+
 Backward is a ``jax.custom_vjp`` with two implementations, both
 recomputing P from the saved logsumexp (O(L·block) live memory, never
 (L, L)): the default ``"xla"`` path is one ``lax.scan`` over KV blocks;
@@ -68,12 +74,12 @@ from tpu_syncbn.ops._pallas_common import NEG_BIG as _NEG_BIG
 from tpu_syncbn.ops._pallas_common import interpret as _interpret
 from tpu_syncbn.ops._pallas_common import sds as _sds
 
-# the two Pallas backward kernels' tiles, and the XLA backward scan's
-# key block: what each was measured with (PERF.md section 6, PR 30); the
-# forward's tiles come from the call's shape (``forward_blocks``)
+# the two Pallas backward kernels' tiles: what each was measured with
+# (PERF.md section 6, PR 30); the forward's tiles and the XLA backward
+# scan's key block come from the call's shape (``forward_blocks``,
+# ``backward_scan_block``)
 _BLOCK_Q = 128
 _BLOCK_K = 128
-_BWD_SCAN_BLOCK_K = 128
 
 _LANES = 128
 # the forward's tiles: the widest the sweep on the chip found worth
@@ -89,23 +95,27 @@ _FWD_VMEM_BUDGET = _VMEM_SCOPED_BYTES // 2
 
 
 def forward_vmem_bytes(block_q: int, block_k: int, d: int,
-                       itemsize: int) -> int:
-    """VMEM one grid step of the forward kernel needs: the q, k, v and
-    output tiles (double-buffered by the pipeline) and the log-sum-exp
-    column (lane-padded), the scaled q, the float32 scores and
-    probabilities and the probabilities in the compute type, the
-    accumulator and the two lane-dense statistics."""
+                       itemsize: int, dv: int | None = None) -> int:
+    """VMEM one grid step of the forward kernel needs: the q, k (``d``
+    wide), v and output (``dv`` wide, ``d`` where it is not given) tiles
+    (double-buffered by the pipeline) and the log-sum-exp column
+    (lane-padded), the scaled q, the float32 scores and probabilities
+    and the probabilities in the compute type, the accumulator and the
+    two lane-dense statistics."""
     lanes_d = -(-d // _LANES) * _LANES
-    streamed = 2 * ((2 * block_q + 2 * block_k) * lanes_d * itemsize
+    lanes_dv = lanes_d if dv is None else -(-dv // _LANES) * _LANES
+    streamed = 2 * ((block_q + block_k) * (lanes_d + lanes_dv) * itemsize
                     + block_q * _LANES * 4)
     scores = block_q * block_k * (4 + 4 + itemsize)
-    carried = block_q * (lanes_d * (4 + itemsize) + 2 * _LANES * 4)
+    carried = block_q * (lanes_dv * 4 + lanes_d * itemsize + 2 * _LANES * 4)
     return streamed + scores + carried
 
 
-def forward_blocks(length: int, d: int, itemsize: int) -> tuple[int, int]:
+def forward_blocks(length: int, d: int, itemsize: int,
+                   dv: int | None = None) -> tuple[int, int]:
     """(block_q, block_k) of the forward kernel for a call that names
-    none, from the call's shape alone. The length is padded to a
+    none, from the call's shape alone (``d`` the width of q and k,
+    ``dv`` of v and the output where it differs). The length is padded to a
     multiple of 128 and no further; each block is the largest multiple
     of 128 that divides the padded length, stays under the widest tile
     the sweep found worth having, and with the other keeps
@@ -119,8 +129,8 @@ def forward_blocks(length: int, d: int, itemsize: int) -> tuple[int, int]:
 
     for block_q in fitting(_FWD_MAX_BLOCK_Q):
         for block_k in fitting(_FWD_MAX_BLOCK_K):
-            if forward_vmem_bytes(block_q, block_k, d,
-                                  itemsize) <= _FWD_VMEM_BUDGET:
+            if forward_vmem_bytes(block_q, block_k, d, itemsize,
+                                  dv) <= _FWD_VMEM_BUDGET:
                 return block_q, block_k
     return _LANES, _LANES
 
@@ -337,11 +347,14 @@ def _causal_tiles_kv(n_q: int, n_k: int, block_q: int, block_k: int):
 
 
 def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
-    """(BH, L, D) in → ((BH, L, D) out, (BH, L) logsumexp). A block
-    the caller did not name (None) comes from the shape."""
+    """q, k (BH, L, D) and v (BH, L, Dv) in → ((BH, L, Dv) out, (BH, L)
+    logsumexp). A block the caller did not name (None) comes from the
+    shape. Where Dv = D this is the kernel it was before v had a width of
+    its own: the same tiles, blocks and name."""
     bh, l_real, d = q.shape
+    dv = v.shape[-1]
     if block_q is None or block_k is None:
-        chosen = forward_blocks(l_real, d, q.dtype.itemsize)
+        chosen = forward_blocks(l_real, d, q.dtype.itemsize, dv)
         block_q = chosen[0] if block_q is None else block_q
         block_k = chosen[1] if block_k is None else block_k
     n_q = pl.cdiv(l_real, block_q)
@@ -354,13 +367,13 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
 
     vmem = pltpu.VMEM
     out_shape = [
-        _sds((bh, n_q * block_q, d), q.dtype, qp),
+        _sds((bh, n_q * block_q, dv), q.dtype, qp),
         # trailing singleton keeps BH out of the block's last-two-dims
         # window (TPU tiling rule); squeezed before returning
         _sds((bh, n_q * block_q, 1), jnp.float32, qp),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block_q, d), jnp.float32),        # acc
+        pltpu.VMEM((block_q, dv), jnp.float32),       # acc
         pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
         pltpu.VMEM((block_q, _LANES), jnp.float32),   # running denom
         pltpu.VMEM((block_q, d), q.dtype),            # q * scale
@@ -389,12 +402,12 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
                 pl.BlockSpec((1, block_k, d),
                              lambda b, t, qids, kids: (b, kids[t], 0),
                              memory_space=vmem),
-                pl.BlockSpec((1, block_k, d),
+                pl.BlockSpec((1, block_k, dv),
                              lambda b, t, qids, kids: (b, kids[t], 0),
                              memory_space=vmem),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d),
+                pl.BlockSpec((1, block_q, dv),
                              lambda b, t, qids, kids: (b, qids[t], 0),
                              memory_space=vmem),
                 pl.BlockSpec((1, block_q, 1),
@@ -421,11 +434,11 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
                          memory_space=vmem),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
                          memory_space=vmem),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
                          memory_space=vmem),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0),
                          memory_space=vmem),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0),
                          memory_space=vmem),
@@ -440,16 +453,30 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
 # -- backward (XLA, blockwise scan — O(L·block_k) live memory) ------------
 
 
+def backward_scan_block(length: int) -> int:
+    """The key block of the XLA backward scan for a call that names
+    none: a sixteenth of the length, within 128 and 512. Every step of
+    the scan streams all the queries once (q, dO and the dq accumulator,
+    in float32), so it is the NUMBER of key blocks that the scan's
+    traffic grows with: 16 blocks of 128 is what the 2,048-token call
+    was measured with (PERF.md section 6, PR 30) and stays; at 8,192
+    tokens (32 heads, q and k 192 wide, v 128; PR 34) 64 blocks of 128
+    take 111.7 ms a call, 32 of 256 86.9, 16 of 512 76.0 at 0.74 GB more
+    scratch, 8 of 1,024 69.8 at 1.55 GB more."""
+    return max(128, min(512, length // 16 // _LANES * _LANES))
+
+
 def _flash_bwd_2d(res, do, *, causal, scale, block_k):
-    q, k, v, o, lse = res  # (BH, L, D)*4, (BH, L)
+    q, k, v, o, lse = res  # (BH, L, D)*2, (BH, L, Dv)*2, (BH, L)
     bh, l_real, d = q.shape
+    dv = v.shape[-1]
     n_k = -(-l_real // block_k)
     pad = n_k * block_k - l_real
     if pad:
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0)))
     kb = k.reshape(bh, n_k, block_k, d)
-    vb = v.reshape(bh, n_k, block_k, d)
+    vb = v.reshape(bh, n_k, block_k, dv)
 
     qf = q.astype(jnp.float32) * scale
     dof = do.astype(jnp.float32)
@@ -486,11 +513,11 @@ def _flash_bwd_2d(res, do, *, causal, scale, block_k):
          jnp.arange(n_k)),
     )
     dk = dk_blocks.transpose(1, 0, 2, 3).reshape(bh, n_k * block_k, d)
-    dv = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, n_k * block_k, d)
+    dv_ = dv_blocks.transpose(1, 0, 2, 3).reshape(bh, n_k * block_k, dv)
     return (
         (dq * scale).astype(q.dtype),
         dk[:, :l_real].astype(q.dtype),
-        dv[:, :l_real].astype(q.dtype),
+        dv_[:, :l_real].astype(q.dtype),
     )
 
 
@@ -865,7 +892,8 @@ def _flash_2d_bwd(causal, scale, block_q, block_k, backward, res, do):
             block_k=_BLOCK_K if block_k is None else block_k)
     return _flash_bwd_2d(
         res, do, causal=causal, scale=scale,
-        block_k=_BWD_SCAN_BLOCK_K if block_k is None else block_k)
+        block_k=(backward_scan_block(res[0].shape[1]) if block_k is None
+                 else block_k))
 
 
 _flash_2d.defvjp(_flash_2d_fwd, _flash_2d_bwd)
@@ -882,14 +910,24 @@ def flash_attention(
     block_k: Optional[int] = None,
     backward: str = "xla",
 ) -> jax.Array:
-    """Exact fused softmax attention, ``(B, L, H, D) → (B, L, H, D)``.
+    """Exact fused softmax attention: q and k ``(B, L, H, D)``, v
+    ``(B, L, H, Dv)`` → ``(B, L, H, Dv)``.
 
-    Drop-in for ``parallel.sequence._single_device_attention`` (same
-    semantics, tolerances at f32 rounding); differentiable via a
-    blockwise custom VJP. ``scale`` defaults to ``D**-0.5``.
+    The shape rule: q and k are identical in shape; v shares their
+    batch, length and heads and may have a head width of its own (latent
+    attention: q and k carry a rotary part that v has not). Neither
+    width has to be a multiple of the 128 lanes: a tile holds the whole
+    width. Where ``Dv = D`` the kernel, its tiles and its name are what
+    they were before v had a width. Drop-in for
+    ``parallel.sequence._single_device_attention`` (same semantics,
+    tolerances at f32 rounding); differentiable via a blockwise custom
+    VJP (the ``"pallas"`` backward kernels take equal widths only).
+    ``scale`` defaults to ``D**-0.5``, D the width of q and k.
     ``block_q`` / ``block_k``: a caller that names them gets them, in
     the forward and the backward; left out, the forward's come from the
-    shape (``forward_blocks``) and the backward keeps 128.
+    shape (``forward_blocks``), the backward scan's key block too
+    (``backward_scan_block``: 128 up to 4,095 tokens) and the backward
+    kernels keep 128.
     ``backward`` selects the VJP implementation: ``"xla"`` (default —
     blockwise lax.scan) or ``"pallas"`` (two fused kernels, dK/dV then
     dQ; opt-in until timed on hardware, the evidence-gating stance).
@@ -900,17 +938,22 @@ def flash_attention(
         raise ValueError(f"backward must be 'xla' or 'pallas', got "
                          f"{backward!r}")
     # the 2d lowering takes lengths/padding from q and reuses them for
-    # k/v (no cross-attention support), and the output reshape assumes
-    # v's head_dim == q's — mismatches must fail here with a clear
-    # message, not deep in a pallas lowering error
-    if k.shape != q.shape or v.shape != q.shape:
+    # k/v (no cross-attention support) — mismatches must fail here with
+    # a clear message, not deep in a pallas lowering error
+    if k.shape != q.shape or v.shape[:3] != q.shape[:3] or v.ndim != 4:
         raise ValueError(
-            "flash_attention requires q, k, v of identical (B, L, H, D) "
-            f"shape, got q={q.shape}, k={k.shape}, v={v.shape}"
+            "flash_attention requires q and k of identical (B, L, H, D) "
+            "shape and v of shape (B, L, H, Dv), got "
+            f"q={q.shape}, k={k.shape}, v={v.shape}"
+        )
+    if backward == "pallas" and v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            "backward='pallas' takes q, k and v of one head width, got "
+            f"{q.shape[-1]} and {v.shape[-1]}: use backward='xla'"
         )
     b, l, h, d = q.shape
     s = float(scale) if scale is not None else d ** -0.5
     to2d = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, l, x.shape[-1])
     o = _flash_2d(to2d(q), to2d(k), to2d(v), causal, s, block_q, block_k,
                   backward)
-    return o.reshape(b, h, l, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, l, v.shape[-1]).transpose(0, 2, 1, 3)

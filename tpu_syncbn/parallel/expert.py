@@ -1,5 +1,20 @@
-"""Expert parallelism: Switch-style mixture-of-experts over an ``expert``
-mesh axis.
+"""Expert parallelism: two mixtures of experts, each with its router.
+
+**Which router is which.** (1) *Switch* (``switch_route`` / ``dense_moe``
+/ ``expert_parallel_moe``, below): top-1 softmax routing with a capacity
+that drops what overflows, dispatch and combine as one-hot einsums over
+(T, E, C), two-matrix ReLU experts, and the two ``all_to_all``s over an
+``expert`` mesh axis. (2) *Sigmoid top-k, dropless, the chip's share*
+(``sigmoid_topk_route`` / ``held_expert_moe``, at the end of the file):
+DeepSeek-V3-style routing (sigmoid scores, a selection bias that is no
+parameter, top-k, normalised and scaled weights) over ALL experts of a
+layer that is told which of them this chip holds; SwiGLU experts as
+grouped products over the pairs that really arrive, no capacity, no
+drop, and no exchange: what the absent experts would add is left out.
+``models.moe_lm`` runs (2); the all-to-all of (1) has not met (2) yet
+(ROADMAP.md Queue 2).
+
+The first, Switch over an ``expert`` mesh axis:
 
 The reference recipe has no MoE (absent from ``README.md:1-104``, SURVEY
 §2's parallelism inventory) — this is the expert-parallel member of the
@@ -23,6 +38,8 @@ gradients in ``tests/test_expert_parallel.py``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 from tpu_syncbn.compat import axis_size as _compat_axis_size
@@ -158,3 +175,187 @@ def expert_parallel_moe(
 
     y = jnp.einsum("tec,ecd->td", combine, expert_out)
     return y.astype(x.dtype), lax.pmean(aux, axis_name)
+
+
+# -- sigmoid top-k routing over all experts, the experts held computed ------
+
+
+def sigmoid_topk_route(
+    x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
+    top_k: int, scale: float,
+) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid scores, top-k of scores + bias, weights from the scores.
+
+    ``x`` (T, H); ``router_w`` (H, E), E ALL the layer's experts;
+    ``bias`` (E,) the selection bias, no parameter (no gradient reaches
+    it: it only chooses). Returns ``(idx, gates)``: ``idx`` (T, k) int32,
+    the k experts with the largest ``s + bias`` (ties to the lower
+    index), and ``gates`` (T, k) float32, ``scale * s[idx] /
+    (sum(s[idx]) + 1e-20)``. The scores are float32, their product at
+    full precision: a selection is not a thing to round."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, idx = lax.top_k(s + lax.stop_gradient(bias), top_k)
+    g = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, scale * g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+
+
+def expert_loads(idx: jax.Array, n_experts: int) -> jax.Array:
+    """(E,) float32: the tokens each expert was chosen by, from ``idx``
+    (T, k). A compare-and-sum, no scatter."""
+    hit = idx[..., None] == jnp.arange(n_experts, dtype=idx.dtype)
+    return jnp.sum(hit, axis=(0, 1), dtype=jnp.float32)
+
+
+def update_selection_bias(bias: jax.Array, load: jax.Array,
+                          gamma: float) -> jax.Array:
+    """``b <- b + gamma * sign(mean(load) - load)``: an expert chosen
+    less often than the mean is made easier to choose, one chosen more
+    often harder (auxiliary-loss-free balancing). ``bias`` and ``load``
+    are (.., E), the mean over the experts. ``load`` is over
+    whatever batch the caller summed it over: the global one, if the
+    replicas are to keep one bias."""
+    return bias + gamma * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load)
+
+
+def _held_chunk(x, gates, w_gate, w_up, w_down, order, sizes, lo, *,
+                k, chunk):
+    """(T, H) float32: what rows ``lo .. lo + chunk - 1`` of the sorted
+    pairs add. ``order`` (P,) the pairs sorted held first and by expert,
+    ``sizes`` (E_held,) the held experts' pair counts, ``gates`` (P,)
+    the weights by pair."""
+    t, h = x.shape
+    with jax.named_scope("moe_route"):
+        ends = jnp.cumsum(sizes)
+        window = lambda a: jnp.clip(a, lo, lo + chunk)
+        local = window(ends) - window(ends - sizes)  # group sizes in here
+        pair = lax.dynamic_slice(order, (lo,), (chunk,))
+        valid = (lo + jnp.arange(chunk) < ends[-1])[:, None]
+        token = pair // k
+        weight = jnp.where(valid, gates[pair][:, None], 0.0)
+        # rows past the held pairs belong to no group: the grouped
+        # product leaves them unwritten, so they are masked wherever
+        # they could reach a sum, forward or backward
+        rows = jnp.where(valid, x[token], 0)
+    with jax.named_scope("moe_experts"):
+        def grouped(a, w):
+            return lax.ragged_dot(a, w.astype(x.dtype), local,
+                                  preferred_element_type=jnp.float32)
+
+        act = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+        act = jnp.where(valid, act, 0.0).astype(x.dtype)
+        out = jnp.where(valid, grouped(act, w_down), 0.0)
+    with jax.named_scope("moe_route"):
+        return jnp.zeros((t, h), jnp.float32).at[token].add(out * weight)
+
+
+def _chunks(sizes, chunk):
+    return (jnp.sum(sizes) + chunk - 1) // chunk
+
+
+def _zeros(like, *others):
+    """Zeros of ``like``'s shape and type that vary over the mesh axes
+    ``like`` and ``others`` vary over: inside ``shard_map`` a loop's
+    carry has to enter with the varying type it leaves with."""
+    from tpu_syncbn.parallel.collectives import pcast_varying
+
+    axes = set().union(*(getattr(jax.typeof(a), "vma", ()) or ()
+                         for a in (like, *others)))
+    zeros = jnp.zeros_like(like)
+    return pcast_varying(zeros, tuple(axes)) if axes else zeros
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_experts(x, gates, w_gate, w_up, w_down, order, sizes, k, chunk):
+    """The held pairs, ``chunk`` rows of the sorted order at a time, for
+    as many chunks as hold a held pair: a loop whose trip count is the
+    step's own, so neither a buffer nor a product is ever the size of
+    the bound (all T*k pairs held). Reverse-mode differentiation cannot
+    run such a loop backwards, so the backward pass is written out: the
+    same walk, each chunk recomputed and pulled back."""
+    part = functools.partial(_held_chunk, k=k, chunk=chunk)
+    return lax.fori_loop(
+        0, _chunks(sizes, chunk),
+        lambda i, y: y + part(x, gates, w_gate, w_up, w_down, order, sizes,
+                              i * chunk),
+        _zeros(x.astype(jnp.float32), gates, w_gate, w_up, w_down, order,
+               sizes))
+
+
+def _held_experts_fwd(x, gates, w_gate, w_up, w_down, order, sizes,
+                      k, chunk):
+    y = _held_experts(x, gates, w_gate, w_up, w_down, order, sizes, k, chunk)
+    return y, (x, gates, w_gate, w_up, w_down, order, sizes)
+
+
+def _held_experts_bwd(k, chunk, res, dy):
+    *inputs, order, sizes = res
+    part = functools.partial(_held_chunk, k=k, chunk=chunk)
+
+    def pull(i, grads):
+        _, back = jax.vjp(
+            lambda *a: part(*a, order, sizes, i * chunk), *inputs)
+        return jax.tree_util.tree_map(jnp.add, grads, back(dy))
+
+    grads = lax.fori_loop(0, _chunks(sizes, chunk), pull,
+                          tuple(_zeros(a) for a in inputs))
+    return (*grads, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def held_expert_moe(
+    x: jax.Array, idx: jax.Array, gates: jax.Array,
+    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+    chunk: int, first_expert: int = 0,
+) -> tuple[jax.Array, jax.Array]:
+    """The part of ``sum_k g_k E_k(x)`` that the experts held here give.
+
+    ``x`` (T, H); ``idx`` / ``gates`` (T, k) from the router over all E
+    experts; ``w_gate`` / ``w_up`` (E_held, H, F) and ``w_down`` (E_held,
+    F, H): experts ``first_expert .. first_expert + E_held - 1``, each
+    ``(silu(x Wg) * (x Wu)) Wd``. A chosen pair whose expert is not held
+    adds nothing (its chip would); **every pair whose expert is held is
+    computed**, whatever the imbalance: there is no capacity. Shapes are
+    static all the same: the T*k pairs are sorted, held ones first and by
+    expert, and walked ``chunk`` rows at a time (the caller's: some small
+    multiple of what arrives if the loads are even, in whole row tiles
+    of the grouped product) for as many chunks as hold a held pair, so the gather, the
+    three grouped products (``lax.ragged_dot``, a Mosaic kernel on the
+    TPU that walks only the row tiles its group sizes cover) and the
+    weighted scatter-add cost what the pairs that arrived cost, in steps
+    of ``chunk``, and all T*k pairs held is only the slowest case, not
+    the size of a buffer. Products take operands of x's type and
+    accumulate in float32; SiLU, the gated product and the weighted sum
+    over k are float32.
+
+    Returns ``(y, pairs_not_computed)``: ``y`` (T, H) in x's type, and
+    a float32 scalar, the pairs chosen on held experts less those whose
+    row lies in the group of their own expert and in a chunk the walk
+    reaches: 0 unless the sort, the group sizes and the trip count
+    disagree, and there to be checked."""
+    t, _ = x.shape
+    k = idx.shape[1]
+    e_held = w_gate.shape[0]
+    chunk = min(chunk, t * k)
+    with jax.named_scope("moe_route"):
+        local = idx.reshape(-1) - first_expert
+        key = jnp.where((local >= 0) & (local < e_held), local, e_held)
+        order = jnp.argsort(key, stable=True)  # held first, by expert
+        sizes = jnp.sum(key[:, None] == jnp.arange(e_held, dtype=key.dtype),
+                        axis=0, dtype=jnp.int32)
+        # the check: a held pair is computed if the row it was sorted to
+        # lies in the group of its own expert and in a chunk the walk
+        # reaches
+        row = jnp.arange(t * k)
+        group = jnp.sum(row[:, None] >= jnp.cumsum(sizes), axis=1)
+        computed = jnp.sum((key[order] == group) & (group < e_held)
+                           & (row < _chunks(sizes, chunk) * chunk))
+        missed = jnp.sum(key < e_held) - computed
+        order = jnp.pad(order, (0, -(t * k) % chunk))
+    y = _held_experts(x, gates.reshape(-1), w_gate, w_up, w_down,
+                      lax.stop_gradient(order), sizes, k, chunk)
+    return y.astype(x.dtype), missed.astype(jnp.float32)
