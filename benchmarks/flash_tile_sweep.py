@@ -62,6 +62,14 @@ def parse_args():
     p.add_argument("--against", default=None,
                    help="file of another version of pallas_attention.py")
     p.add_argument("--layer-probe", action="store_true")
+    p.add_argument("--backward", action="store_true",
+                   help="time the two backward kernels and the XLA scan "
+                        "instead of the forward, and compare their "
+                        "gradients with a float32 reference")
+    p.add_argument("--dv", type=int, default=None,
+                   help="head width of v where it is not D")
+    p.add_argument("--reference-heads", type=int, default=2,
+                   help="--backward: heads the float32 reference computes")
     p.add_argument("--allow-cpu", action="store_true")
     p.add_argument("--out", default=None)
     return p.parse_args()
@@ -130,13 +138,17 @@ def by_program(planes) -> dict:
     return out
 
 
+def named_ms(executions: list, name: str) -> float:
+    """Median ms, over a program's executions, of the operations whose
+    HLO text holds ``name``."""
+    return statistics.median(
+        sum(d for n, _, d in ex if name in n) / 1e6 for ex in executions)
+
+
 def kernel_ms(executions: list) -> tuple:
     """(median ms of the kernel's operation, median ms of all the
     program's operations) over a program's executions."""
-    kernel = [sum(d for n, _, d in ex if KERNEL_OP in n) / 1e6
-              for ex in executions]
-    whole = [sum(d for _, _, d in ex) / 1e6 for ex in executions]
-    return statistics.median(kernel), statistics.median(whole)
+    return named_ms(executions, KERNEL_OP), named_ms(executions, "")
 
 
 def sweep(pa, q, k, v, points: list, calls: int) -> list:
@@ -197,6 +209,22 @@ def fit(rows: list) -> dict:
             "points": len(timed)}
 
 
+def dense(q, k, v):
+    """Causal softmax attention in float32 at the highest matmul
+    precision, on the values as stored."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision="highest") * q.shape[-1] ** -0.5
+    n = q.shape[1]
+    s = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :],
+                  s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
 def compare(pa, other, q, k, v, same: int = 128) -> dict:
     """This tree's kernel against ``other``'s on the same inputs, twice:
     both at ``same`` x ``same`` tiles, where the two walk the same pairs
@@ -210,16 +238,6 @@ def compare(pa, other, q, k, v, same: int = 128) -> dict:
     def run(module, **blocks):
         return jax.jit(lambda q, k, v: module.flash_attention(
             q, k, v, causal=True, **blocks))(q, k, v).astype(jnp.float32)
-
-    def dense(q, k, v):
-        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       precision="highest") * q.shape[-1] ** -0.5
-        n = q.shape[1]
-        s = jnp.where(jnp.arange(n)[:, None] >= jnp.arange(n)[None, :],
-                      s, -jnp.inf)
-        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v,
-                          precision="highest")
 
     def distance(a, b) -> dict:
         diff = jnp.abs(a - b)
@@ -237,6 +255,118 @@ def compare(pa, other, q, k, v, same: int = 128) -> dict:
         "new_vs_float32": distance(o_new, o_ref),
         "old_vs_float32": distance(o_old, o_ref),
     }
+
+
+def sweep_backward(pa, q, k, v, points: list, calls: int) -> list:
+    """The backward alone (residuals from one forward call, dO from a
+    fixed key) at every point: device time a call of the dK/dV kernel,
+    of the dQ kernel and of the whole program (delta and padding too),
+    each kernel's grid steps and scores; and one row for the XLA scan."""
+    import jax
+    import jax.numpy as jnp
+
+    b, length, h, d = q.shape
+    scale = d ** -0.5
+    to2d = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, length,
+                                                     x.shape[-1])
+    q2, k2, v2 = to2d(q), to2d(k), to2d(v)
+    o2, lse = jax.jit(lambda q, k, v: pa._flash_fwd_2d(
+        q, k, v, causal=True, scale=scale, block_q=None, block_k=None))(
+            q2, k2, v2)
+    do2 = jax.random.normal(jax.random.PRNGKey(7), o2.shape,
+                            jnp.float32).astype(o2.dtype)
+    args = (q2, k2, v2, o2, lse, do2)
+    programs, rows = {}, []
+
+    def add(name, fn, row):
+        fn.__name__ = name
+        jitted = jax.jit(fn)
+        try:
+            jax.block_until_ready(jitted(*args))
+        except Exception as e:  # the compiler refusing a tile is a finding
+            row["error"] = " ".join(f"{type(e).__name__}: {e}".split())[:300]
+            log(f"[sweep] {name}: {row['error']}")
+        else:
+            programs[f"jit_{name}"] = (jitted, row)
+        rows.append(row)
+
+    for bq, bk in points:
+        n_q, n_k = -(-length // bq), -(-length // bk)
+        walks = {"dkv": len(pa._causal_tiles_kv(n_q, n_k, bq, bk)[0]),
+                 "dq": len(pa._causal_tiles(n_q, n_k, bq, bk)[0])}
+        add(f"flash_bwd_q{bq}_k{bk}",
+            lambda q, k, v, o, lse, do, bq=bq, bk=bk:
+                pa._flash_bwd_2d_pallas(
+                    (q, k, v, o, lse), do, causal=True, scale=scale,
+                    block_q=bq, block_k=bk),
+            {"block_q": bq, "block_k": bk,
+             **{f"{kernel}_steps": b * h * n for kernel, n in walks.items()},
+             **{f"{kernel}_scores": b * h * n * bq * bk
+                for kernel, n in walks.items()}})
+    add("flash_bwd_scan",
+        lambda q, k, v, o, lse, do: pa._flash_bwd_2d(
+            (q, k, v, o, lse), do, causal=True, scale=scale,
+            block_k=pa.backward_scan_block(length)),
+        {"scan_block": pa.backward_scan_block(length)})
+
+    def run():
+        for fn, _ in programs.values():
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+
+    executions = by_program(capture(run))
+    for name, (_, row) in programs.items():
+        if name in executions:
+            ex = executions[name]
+            row["program_ms"] = named_ms(ex, "")
+            if "block_q" in row:
+                row["dkv_ms"] = named_ms(ex, "flash_bwd_dkv")
+                row["dq_ms"] = named_ms(ex, "flash_bwd_dq")
+            row["calls"] = len(ex)
+            log(f"[sweep] {name}: {row}")
+    return rows
+
+
+def fit_backward(rows: list) -> dict:
+    """``fit`` for each backward kernel."""
+    return {kernel: fit([
+        {"steps": r[f"{kernel}_steps"], "scores": r[f"{kernel}_scores"],
+         "kernel_ms": r[f"{kernel}_ms"]}
+        for r in rows if f"{kernel}_ms" in r]) for kernel in ("dkv", "dq")}
+
+
+def gradients(modules: dict, q, k, v, heads: int) -> dict:
+    """dq, dk, dv of ``sum(flash_attention(q, k, v) * w)`` on the first
+    ``heads`` heads, for each ``{label: (module, backward)}``, as the
+    relative L2 distance to the gradients of a float32 softmax at the
+    highest matmul precision on the same (stored) values."""
+    import jax
+    import jax.numpy as jnp
+
+    q, k, v = (x[:, :, :heads] for x in (q, k, v))
+    w = jax.random.normal(jax.random.PRNGKey(11), v.shape, jnp.float32)
+
+    def grads(attend):
+        return jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * w),
+            argnums=(0, 1, 2)))(q, k, v)
+
+    rel = lambda a, b: float(
+        jnp.linalg.norm((a.astype(jnp.float32) - b).ravel())
+        / jnp.linalg.norm(b.ravel()))
+    want = grads(dense)
+    out = {"heads": heads}
+    for label, (module, backward) in modules.items():
+        try:
+            got = grads(lambda q, k, v: module.flash_attention(
+                q, k, v, causal=True, backward=backward))
+        except Exception as e:  # the parent refuses unequal widths
+            out[label] = " ".join(f"{type(e).__name__}: {e}".split())[:200]
+            continue
+        out[label] = {name: rel(a, b)
+                      for name, a, b in zip(("dq", "dk", "dv"), got, want)}
+    return out
 
 
 def layer_probe(calls: int = 3) -> list:
@@ -296,19 +426,36 @@ def main():
         sys.exit(0)
 
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(key, tuple(args.shape), jnp.float32)
-               .astype(jnp.bfloat16) for key in keys)
+    widths = [args.shape[3], args.shape[3], args.dv or args.shape[3]]
+    q, k, v = (jax.random.normal(key, (*args.shape[:3], width), jnp.float32)
+               .astype(jnp.bfloat16) for key, width in zip(keys, widths))
     grid = [(bq, bk) for bq in args.blocks for bk in args.blocks]
     device = jax.devices()[0]
     report = {"metric": "flash_tile_sweep", "shape": args.shape,
+              "dv": widths[2],
               "device": {"platform": device.platform,
                          "kind": device.device_kind}}
+    _, length, _, d = args.shape
+    if args.backward:
+        report["chosen"] = pa.backward_blocks(length, d, q.dtype.itemsize,
+                                              widths[2])
+        rows = sweep_backward(pa, q, k, v, grid, args.calls)
+        report["rows"] = rows
+        report["fit"] = fit_backward(rows)
+        modules = {"kernels": (pa, "pallas"), "scan": (pa, "xla")}
+        if args.against:
+            other = load_module(args.against)
+            modules.update({"against_kernels": (other, "pallas"),
+                            "against_scan": (other, "xla")})
+        report["gradients"] = gradients(modules, q, k, v,
+                                        args.reference_heads)
+        finish(report, args.out)
+        return
     # the tiles the kernel takes when given none: timed under their own
     # names (a program that differs from a point's in its name alone is
     # the same executable to the compile cache, and to the trace)
     chosen = []
     if hasattr(pa, "forward_blocks"):  # the parent's module has none
-        _, length, _, d = args.shape
         chosen = [pa.forward_blocks(length, d, q.dtype.itemsize)]
         report["chosen"] = list(chosen[0])
     rows = sweep(pa, q, k, v, grid + [c for c in chosen if c not in grid],
@@ -320,10 +467,14 @@ def main():
         report["against"] = compare(pa, load_module(args.against), q, k, v)
     if args.layer_probe:
         report["layer_probe"] = layer_probe()
+    finish(report, args.out)
+
+
+def finish(report: dict, out) -> None:
     text = json.dumps(report)
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
+    if out:
+        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+        with open(out, "w") as f:
             f.write(text + "\n")
     print(text)
 

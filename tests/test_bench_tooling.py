@@ -120,6 +120,30 @@ class TestFlashTileSweep:
         assert got["b_ps_per_score"] == pytest.approx(3.0, rel=1e-6)
         assert mod.fit(rows[:2]) == {}
 
+    def test_backward_fit_is_one_fit_a_kernel(self):
+        mod = _load_flash_sweep()
+        rows = [{"dkv_steps": s, "dq_steps": s, "dkv_scores": n,
+                 "dq_scores": n, "dkv_ms": 4e-4 * s + 5e-9 * n,
+                 "dq_ms": 4e-4 * s + 4e-9 * n}
+                for s, n in [(4352, 71e6), (320, 84e6), (96, 100e6),
+                             (1152, 75e6)]] + [{"scan_block": 128}]
+        got = mod.fit_backward(rows)
+        assert got["dkv"]["b_ps_per_score"] == pytest.approx(5.0, rel=1e-6)
+        assert got["dq"]["b_ps_per_score"] == pytest.approx(4.0, rel=1e-6)
+        assert got["dq"]["a_us_per_step"] == pytest.approx(0.4, rel=1e-6)
+
+    def test_a_kernels_time_is_read_by_its_name(self):
+        mod = _load_flash_sweep()
+        executions = [
+            [("%flash_bwd_dkv_q512_k512 = custom-call()", "a", 2e6),
+             ("%flash_bwd_dq_q512_k512 = custom-call()", "a", 1e6),
+             ("%fusion.1 = fusion()", None, 5e5)],
+            [("%flash_bwd_dkv_q512_k512 = custom-call()", "a", 4e6),
+             ("%flash_bwd_dq_q512_k512 = custom-call()", "a", 3e6)],
+        ]
+        assert mod.named_ms(executions, "flash_bwd_dkv") == 3.0
+        assert mod.named_ms(executions, "flash_bwd_dq") == 2.0
+
     def test_operations_go_to_the_execution_that_holds_them(self):
         mod = _load_flash_sweep()
         plane = {

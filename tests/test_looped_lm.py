@@ -206,7 +206,7 @@ def test_the_exit_distribution_sums_to_one_and_the_last_pass_takes_the_rest(
 def test_recomputation_and_the_attention_kernel_change_only_rounding(variant):
     """``jax.checkpoint`` around a layer application and a pass's head,
     and ``ops.pallas_attention.flash_attention`` (interpret mode here;
-    its backward XLA's scan over key blocks) in place of
+    its backward the kernel file's two backward kernels) in place of
     XLA's attention, inside the model: same loss, same gradients."""
     batch = batch_of()
     want_loss, _, want = loss_and_grads(make(), batch)
@@ -359,9 +359,9 @@ def test_rotary_is_a_complex_rotation_of_paired_dimensions():
 
 @pytest.mark.parametrize("impl", ["ring", "flash_pallas_bwd"])
 def test_an_unknown_attention_is_refused(impl):
-    """``flash_pallas_bwd`` among them: the kernel's own two backward
-    kernels measured slowest on the chip (PERF.md section 6, PR 30) and
-    stay an option of ``ops.pallas_attention`` alone."""
+    """``flash_pallas_bwd`` among them: since PR 35 ``flash`` itself
+    differentiates through the kernel's own two backward kernels
+    (PERF.md section 6), so there is no second value to name."""
     with pytest.raises(ValueError, match="attn_impl"):
         make(attn_impl=impl)
 

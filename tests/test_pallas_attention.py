@@ -156,8 +156,8 @@ class TestCausalTileWalk:
 
 class TestForwardBlocks:
     """A call that names no blocks gets the forward's from its shape
-    (``forward_blocks``); the backward keeps 128; a call that names them
-    gets them."""
+    (``forward_blocks``), and each backward kernel its own
+    (``backward_blocks``); a call that names them gets them."""
 
     @pytest.mark.parametrize("itemsize", [2, 4])
     @pytest.mark.parametrize("d", [64, 128])
@@ -171,6 +171,22 @@ class TestForwardBlocks:
         assert pa.forward_vmem_bytes(
             *pa.forward_blocks(l, d, itemsize), d, itemsize
         ) <= pa._FWD_VMEM_BUDGET < pa._VMEM_SCOPED_BYTES
+
+    @pytest.mark.parametrize("itemsize", [2, 4])
+    @pytest.mark.parametrize("d,dv", [(64, None), (128, None), (192, 128)])
+    @pytest.mark.parametrize("l", [1, 32, 100, 128, 300, 512, 640, 2048,
+                                   8192])
+    def test_backward_choice_fits_the_shape(self, l, d, dv, itemsize):
+        padded = -(-l // 128) * 128
+        chosen = pa.backward_blocks(l, d, itemsize, dv)
+        assert sorted(chosen) == ["dkv", "dq"]
+        for kernel, blocks in chosen.items():
+            for block in blocks:
+                assert block % 128 == 0 and 0 < block <= padded
+                assert padded % block == 0  # no padding beyond 128's
+            assert pa.backward_vmem_bytes(
+                kernel, *blocks, d, itemsize, dv
+            ) <= pa._BWD_VMEM_BUDGET < pa._BWD_VMEM_SCOPED_BYTES
 
     def test_choice_at_the_timed_shape(self):
         # the benchmark cell's call: 320 grid steps where 128 x 128

@@ -186,24 +186,59 @@ def test_looped_decoder_compiles_for_v5e(v5e, mosaic):
 
     compiled = jax.jit(jax.value_and_grad(loss)).lower(
         on_chip(params), tokens, tokens).compile()
-    # the forward kernel and its recomputation; the backward is XLA's scan
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    # the forward kernel and its recomputation, and the two backward
+    # kernels at the tiles the cell's 2,048 tokens choose
+    text = compiled.as_text()
+    assert text.count("flash_fwd_q512_k512") >= 2
+    assert "flash_bwd_dkv_q512_k512" in text
+    assert "flash_bwd_dq_q512_k512" in text
 
 
-def test_flash_attention_latent_widths_compile_for_v5e(v5e, mosaic):
+@pytest.mark.parametrize("backward", ["xla", "pallas"])
+def test_flash_attention_latent_widths_compile_for_v5e(v5e, mosaic, backward):
     """Latent attention's call in the benchmark's cell
     (``joyai-l5-train-b1x8192``): q and k 192 wide, which is no multiple
     of the 128 lanes, v and the output 128, 32 heads, 8,192 tokens; the
-    tiles the shape chooses, the backward an XLA scan."""
+    tiles the shape chooses, the backward as an XLA scan and as the two
+    kernels the cell runs (dk 192 wide, dv 128)."""
     assert pa.forward_blocks(8192, 192, 2, 128) == (512, 512)
     qk = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16, sharding=v5e)
     v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=v5e)
 
     def loss(q, k, v):
         return pa.flash_attention(
-            q, k, v, causal=True).astype(jnp.float32).sum()
+            q, k, v, causal=True, backward=backward
+        ).astype(jnp.float32).sum()
 
     _compile_fwd_and_grad(loss, qk, qk, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("call", [(2, 2048, 16, 128, 128),
+                                  (1, 2000, 16, 128, 128),
+                                  (1, 8192, 32, 192, 128)],
+                         ids=lambda c: "B{}S{}h{}d{}v{}".format(*c))
+def test_backward_kernels_chosen_blocks_compile_for_v5e(v5e, mosaic, call,
+                                                        dtype):
+    """The two backward kernels at the tiles they choose from the shape,
+    the largest they can return, so that Mosaic's VMEM limit (the
+    kernels scope ``_BWD_VMEM_SCOPED_BYTES``) and tiling rules judge
+    them: the two cells' calls and a ragged length, in both types."""
+    b, s, h, d, dv = call
+    qk = jax.ShapeDtypeStruct((b, s, h, d), dtype, sharding=v5e)
+    v = jax.ShapeDtypeStruct((b, s, h, dv), dtype, sharding=v5e)
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, causal=True, backward="pallas"
+        ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile()
+    chosen = pa.backward_blocks(s, d, jnp.dtype(dtype).itemsize, dv)
+    for kernel, (bq, bk) in chosen.items():
+        assert f"flash_bwd_{kernel}_q{bq}_k{bk}" in compiled.as_text()
 
 
 def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
@@ -242,6 +277,9 @@ def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
         on_chip(params), on_chip(rest), tokens, tokens, tokens
     ).compile().as_text()
     # the attention kernel of three layer applications, forward and
-    # recomputed; the grouped products are the compiler's own kernels
+    # recomputed, and their backward kernels; the grouped products are
+    # the compiler's own kernels
     assert text.count("flash_fwd_q512_k512") >= 6
+    assert text.count("flash_bwd_dkv_q512_k512") >= 3
+    assert text.count("flash_bwd_dq_q512_k512") >= 3
     assert "ragged-dot" in text

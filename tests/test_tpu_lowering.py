@@ -83,6 +83,21 @@ def test_flash_grad_lowers_for_tpu(mosaic, causal, backward):
     )
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("backward", ["xla", "pallas"])
+def test_flash_grad_latent_widths_lower_for_tpu(mosaic, causal, backward):
+    # q and k 192 wide, no multiple of the 128 lanes, v and dO 128: the
+    # backward kernels' dk is as wide as k, their dv as wide as v
+    qk = jnp.zeros((1, 512, 4, 192), jnp.bfloat16)
+    v = jnp.zeros((1, 512, 4, 128), jnp.bfloat16)
+    _tpu_lower(
+        jax.grad(lambda q, k, v: pa.flash_attention(
+            q, k, v, causal=causal, backward=backward
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        qk, qk, v,
+    )
+
+
 def test_flash_ragged_lowers_for_tpu(mosaic):
     # non-multiple length exercises the padded final blocks and, under
     # causal, the compressed scalar-prefetch tile walk with a partial row

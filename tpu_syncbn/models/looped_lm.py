@@ -54,21 +54,24 @@ PR 30). The passes as a scan or unrolled: the same step (847 and 850 ms)
 at 14.4 against 16.4 GB, so a scan. Saving every matmul's output
 (``dots_with_no_batch_dims_saveable``) wants 22.6 GiB and without
 recomputation the step wants 71 GiB of the chip's 15.75. ``attn_impl``:
-``"xla"`` 847 ms a step, ``"flash"`` 643 (606 since PR 33 with what the
-layer keeps, below; the kernel's forward with its
-backward as an XLA scan over key blocks; 774 in PR 30, before the
-forward chose its tiles from the shape, PERF.md section 6, PR 31; with
-the kernel's own two backward kernels,
-``flash_attention(backward="pallas")``, 848 then, so the model offers
-no such value); the default stays ``"xla"`` because it
+``"xla"`` 847 ms a step, ``"flash"`` 569: the kernel's forward and
+its two backward kernels, each with its tiles from the call's shape
+(PERF.md section 6, PR 35). On the way there: 774 in PR 30 with the
+kernel's backward as an XLA scan over key blocks and 128 x 128 forward
+tiles, 643 when the forward chose its tiles (PR 31), 606 with what the
+layer keeps (PR 33, below), all three with the scan; the two backward
+kernels at 128 x 128 tiles and float32 operands read 848 in PR 30,
+slower than the scan, and at 512 x 512 in the inputs' type they are
+what ``"flash"`` means; the default stays ``"xla"`` because it
 runs everywhere (the kernel's interpret mode does not pass
 ``shard_map``'s ``check_vma`` on the CPU), and a configuration for the
 chip names ``"flash"``. The two differ in one rounding: ``"xla"``
 rounds the probabilities to ``dtype`` before they meet v, the kernel
 keeps them in float32.
 
-What the layer's checkpoint keeps, measured there with ``"flash"`` (one
-traced run each, PERF.md section 6, PR 33; ms a step, bytes at the peak).
+What the layer's checkpoint keeps, measured there with ``"flash"`` while
+its backward was the scan (one traced run each, PERF.md section 6,
+PR 33; ms a step, bytes at the peak; every row is 37 ms shorter now).
 A saved (T, L, B, S, H) stack is written and read through the two scans
 by four copies, 0.62-0.70 GB and about 7 ms a name where the closed
 form says 0.54 GB and 1.3 ms, so a name pays only where its
@@ -152,12 +155,13 @@ def apply_rotary(x, cos, sin):
 def causal_attention(q, k, v, impl: str):
     """``softmax(q k^T / sqrt(d) + causal mask) v`` for (B, S, heads, d)
     arrays. ``"xla"`` materialises the float32 scores; ``"flash"`` runs
-    ``ops.pallas_attention.flash_attention`` (its backward an XLA scan
-    over key blocks)."""
+    ``ops.pallas_attention.flash_attention``, forward and backward in
+    the kernel file's own kernels (the backward's dK/dV and dQ; each
+    takes its tiles from the call's shape)."""
     if impl == "flash":
         from tpu_syncbn.ops.pallas_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True, backward="xla")
+        return flash_attention(q, k, v, causal=True, backward="pallas")
     s = q.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)

@@ -25,7 +25,7 @@ router, softmax, SiLU, logits and loss in float32:
   ``q = [q_nope ; q_rope]``, ``k = [k_nope ; k_rope]``,
   ``softmax(q k^T / sqrt(d_qk) + causal mask) v``, heads joined, ``W_o``.
   q and k are wider than v (``ops.pallas_attention.flash_attention``
-  takes that)
+  takes that, in its forward kernel and in its two backward kernels)
 * mixture of experts (``parallel.expert``): ``s = sigmoid(x W_r)`` over
   ALL ``n_experts``, in float32 at full precision; chosen: the top k of
   ``s + b``; ``g = scale * s[chosen] / (sum s[chosen] + 1e-20)``;
