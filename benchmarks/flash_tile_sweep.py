@@ -87,7 +87,7 @@ def load_module(path: str):
 def walk(pa, length: int, block_q: int, block_k: int) -> int:
     """Tile pairs of one batch-head's causal walk."""
     n_q, n_k = -(-length // block_q), -(-length // block_k)
-    return len(pa._causal_tiles(n_q, n_k, block_q, block_k)[0])
+    return len(pa._live_tiles(pa.CAUSAL, n_q, n_k, block_q, block_k)[0])
 
 
 def capture(run):
@@ -271,7 +271,7 @@ def sweep_backward(pa, q, k, v, points: list, calls: int) -> list:
                                                      x.shape[-1])
     q2, k2, v2 = to2d(q), to2d(k), to2d(v)
     o2, lse = jax.jit(lambda q, k, v: pa._flash_fwd_2d(
-        q, k, v, causal=True, scale=scale, block_q=None, block_k=None))(
+        q, k, v, rule=pa.CAUSAL, scale=scale, block_q=None, block_k=None))(
             q2, k2, v2)
     do2 = jax.random.normal(jax.random.PRNGKey(7), o2.shape,
                             jnp.float32).astype(o2.dtype)
@@ -292,12 +292,13 @@ def sweep_backward(pa, q, k, v, points: list, calls: int) -> list:
 
     for bq, bk in points:
         n_q, n_k = -(-length // bq), -(-length // bk)
-        walks = {"dkv": len(pa._causal_tiles_kv(n_q, n_k, bq, bk)[0]),
-                 "dq": len(pa._causal_tiles(n_q, n_k, bq, bk)[0])}
+        walks = {"dkv": len(pa._live_tiles(pa.CAUSAL, n_q, n_k, bq, bk,
+                                           by_key=True)[0]),
+                 "dq": len(pa._live_tiles(pa.CAUSAL, n_q, n_k, bq, bk)[0])}
         add(f"flash_bwd_q{bq}_k{bk}",
             lambda q, k, v, o, lse, do, bq=bq, bk=bk:
                 pa._flash_bwd_2d_pallas(
-                    (q, k, v, o, lse), do, causal=True, scale=scale,
+                    (q, k, v, o, lse), do, rule=pa.CAUSAL, scale=scale,
                     block_q=bq, block_k=bk),
             {"block_q": bq, "block_k": bk,
              **{f"{kernel}_steps": b * h * n for kernel, n in walks.items()},
@@ -305,7 +306,7 @@ def sweep_backward(pa, q, k, v, points: list, calls: int) -> list:
                 for kernel, n in walks.items()}})
     add("flash_bwd_scan",
         lambda q, k, v, o, lse, do: pa._flash_bwd_2d(
-            (q, k, v, o, lse), do, causal=True, scale=scale,
+            (q, k, v, o, lse), do, rule=pa.CAUSAL, scale=scale,
             block_k=pa.backward_scan_block(length)),
         {"scan_block": pa.backward_scan_block(length)})
 

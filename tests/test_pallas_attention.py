@@ -107,7 +107,7 @@ class TestCausalTileWalk:
 
     def test_equal_blocks_triangle(self):
         n = 8
-        qids, kids = pa._causal_tiles(n, n, 128, 128)
+        qids, kids = pa._live_tiles(pa.CAUSAL, n, n, 128, 128)
         assert len(qids) == n * (n + 1) // 2  # vs n*n rectangular
         live = set(zip(qids.tolist(), kids.tolist()))
         expect = {(qi, ki) for qi in range(n) for ki in range(qi + 1)}
@@ -116,7 +116,7 @@ class TestCausalTileWalk:
     def test_walk_order_contract(self):
         for (nq, nk, bq, bk) in [(8, 8, 128, 128), (4, 8, 256, 128),
                                  (8, 4, 128, 256), (5, 5, 64, 64)]:
-            qids, kids = pa._causal_tiles(nq, nk, bq, bk)
+            qids, kids = pa._live_tiles(pa.CAUSAL, nq, nk, bq, bk)
             # qi non-decreasing; within each qi, ki = 0, 1, 2, ...
             assert list(qids) == sorted(qids)
             for qi in range(nq):
@@ -192,7 +192,7 @@ class TestForwardBlocks:
         # the benchmark cell's call: 320 grid steps where 128 x 128
         # tiles took 4,352 (PERF.md section 6, PR 31)
         assert pa.forward_blocks(2048, 128, 2) == (512, 512)
-        walk = len(pa._causal_tiles(4, 4, 512, 512)[0])
+        walk = len(pa._live_tiles(pa.CAUSAL, 4, 4, 512, 512)[0])
         assert 32 * walk == 320
 
     # (length, causal) -> the chosen tiles, and how many of the walk's
@@ -212,10 +212,11 @@ class TestForwardBlocks:
         (bq, bk), masked, unmasked = self.CASES[(l, causal)]
         assert pa.forward_blocks(l, D, 4) == (bq, bk)
         n_q, n_k = -(-l // bq), -(-l // bk)
-        pairs = (zip(*pa._causal_tiles(n_q, n_k, bq, bk)) if causal else
+        pairs = (zip(*pa._live_tiles(pa.CAUSAL, n_q, n_k, bq, bk)) if causal else
                  ((qi, ki) for qi in range(n_q) for ki in range(n_k)))
         took = [bool(pa._holds_masked_scores(
-            int(qi), int(ki), causal=causal, block_q=bq, block_k=bk,
+            int(qi), int(ki), rule=pa.CAUSAL if causal else pa.FULL,
+            block_q=bq, block_k=bk,
             n_k=n_k, pad_k=n_k * bk - l)) for qi, ki in pairs]
         assert (sum(took), len(took) - sum(took)) == (masked, unmasked)
 
@@ -242,7 +243,7 @@ class TestForwardBlocks:
 
     def test_named_blocks_give_todays_walk(self):
         program = self._program(200, block_q=64, block_k=128)
-        walk = len(pa._causal_tiles(4, 2, 64, 128)[0])
+        walk = len(pa._live_tiles(pa.CAUSAL, 4, 2, 64, 128)[0])
         assert "name=flash_fwd_q64_k128" in program
         assert f"grid=(1, {walk})" in program
         # the backward scans the key blocks the caller named
@@ -297,14 +298,14 @@ class TestPallasBackward:
         # ascending from the first query tile reaching the KV columns
         for (nq, nk, bq, bk) in [(8, 8, 128, 128), (4, 8, 256, 128),
                                  (8, 4, 128, 256)]:
-            kis, qis = pa._causal_tiles_kv(nq, nk, bq, bk)
+            kis, qis = pa._live_tiles(pa.CAUSAL, nq, nk, bq, bk, by_key=True)
             assert list(kis) == sorted(kis)
             for ki in range(nk):
                 qs = [q for k2, q in zip(kis, qis) if k2 == ki]
                 lo = (ki * bk) // bq
                 assert qs == list(range(lo, nq))
             # same live set as the forward walk, transposed
-            fwd = set(zip(*pa._causal_tiles(nq, nk, bq, bk)))
+            fwd = set(zip(*pa._live_tiles(pa.CAUSAL, nq, nk, bq, bk)))
             assert {(q2, k2) for k2, q2 in zip(kis, qis)} == fwd
 
     @pytest.mark.parametrize("l,bq,bk", [(256, 128, 128), (300, 64, 128)])

@@ -283,3 +283,74 @@ def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
     assert text.count("flash_bwd_dkv_q512_k512") >= 3
     assert text.count("flash_bwd_dq_q512_k512") >= 3
     assert "ragged-dot" in text
+
+
+@pytest.mark.parametrize("call", [(1, 8192, 32, 4, 128, (4096, 4)),
+                                  (1, 600, 8, 2, 128, (300, 4)),
+                                  (2, 1024, 8, 2, 128, (512, 12)),
+                                  (1, 2048, 8, 2, 128, None)],
+                         ids=lambda c: "B{}S{}h{}kv{}d{}m{}".format(*c))
+def test_grouped_block_masked_kernels_compile_for_v5e(v5e, mosaic, call):
+    """The three kernels with fewer k/v heads than q heads, under the
+    block-diffusion mask (and, the last case, causal), at the tiles the
+    shape chooses: the benchmark cell's call (``sdar-l6-train-b1x4096``:
+    8,192 positions, 32 q heads over 4 k/v heads of 128, blocks of 4:
+    80 tile pairs a q head in the forward and dQ, 640 a k/v head in
+    dK/dV), a ragged length whose halves meet inside a tile, and a block
+    that is no power of two (integer division in the kernel)."""
+    b, s, h, kv, d, mask = call
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=v5e)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=v5e)
+    how = dict(causal=True) if mask is None else dict(
+        block_diffusion_mask=mask)
+
+    def loss(q, k, v):
+        return pa.flash_attention(
+            q, k, v, backward="pallas", **how).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    if s == 8192:
+        for name in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+            assert f"{name}_q512_k512" in text
+
+
+def test_block_diffusion_decoder_compiles_for_v5e(v5e, mosaic):
+    """The block-diffusion decoder at SDAR-30B-A3B's published widths
+    (hidden 2048, 32 q heads over 4 k/v heads of 128, experts 768 wide, a
+    128-way router with 16 experts held, 8 a token), one sequence of
+    2,048 tokens with its noisy copy, through the three kernels and the
+    grouped products with per-layer recomputation: loss and gradients in
+    one program. Cut where the compile time is: 2 layers and a sixteenth
+    of the slice of the vocabulary; the benchmark's cell runs 6, 4,096
+    tokens and 18,992 ids."""
+    from flax import nnx
+
+    from tpu_syncbn.models.block_diffusion_lm import BlockDiffusionMoELM
+
+    abstract = nnx.eval_shape(lambda: BlockDiffusionMoELM(
+        vocab_size=1187, hidden_size=2048, num_heads=32, num_kv_heads=4,
+        head_dim=128, num_layers=2, block_length=4, n_experts=128,
+        experts_held=16, experts_per_token=8, moe_intermediate=768,
+        rope_theta=1e6, embed_std=1.0, dtype=jnp.bfloat16,
+        attn_impl="flash", rngs=nnx.Rngs(0)))
+    graphdef, params, rest = nnx.split(abstract, nnx.Param, ...)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), t)
+    tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32, sharding=v5e)
+    weights = jax.ShapeDtypeStruct((1, 2048), jnp.float32, sharding=v5e)
+
+    def loss(p, r, x0, xt, w):
+        # copy=True: the loads are counted on variables of this trace
+        return nnx.merge(graphdef, p, r, copy=True).loss(x0, xt, w)[0]
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        on_chip(params), on_chip(rest), tokens, tokens, weights
+    ).compile().as_text()
+    # a layer's forward kernel call and its recomputation, its two
+    # backward kernels; the grouped products are the compiler's own
+    assert text.count("flash_fwd_q512_k512") >= 2
+    assert "flash_bwd_dkv_q512_k512" in text
+    assert "flash_bwd_dq_q512_k512" in text
+    assert "ragged-dot" in text

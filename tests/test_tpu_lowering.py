@@ -98,6 +98,36 @@ def test_flash_grad_latent_widths_lower_for_tpu(mosaic, causal, backward):
     )
 
 
+@pytest.mark.parametrize("mask", [dict(causal=True), dict(),
+                                  dict(block_diffusion_mask=(128, 4)),
+                                  dict(block_diffusion_mask=(128, 12))],
+                         ids=["causal", "full", "block4", "block12"])
+def test_flash_grad_grouped_heads_and_block_mask_lower_for_tpu(mosaic, mask):
+    """8 q heads over 2 k/v heads (dK/dV sweeping a group's q heads, k
+    and v indexed ``b // group``), under each mask: the block-diffusion
+    mask's iotas, shifts (a block of 4) and integer divisions (12)."""
+    q = jnp.zeros((1, 256, 8, 128), jnp.float32)
+    kv = jnp.zeros((1, 256, 2, 128), jnp.float32)
+    _tpu_lower(
+        jax.grad(lambda q, k, v: pa.flash_attention(
+            q, k, v, backward="pallas", **mask).sum(), argnums=(0, 1, 2)),
+        q, kv, kv,
+    )
+
+
+def test_flash_block_mask_ragged_lowers_for_tpu(mosaic):
+    """A clean length that is no multiple of the tiles: the halves'
+    border falls inside a tile and the last tile is padded."""
+    q = jnp.zeros((1, 600, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 600, 2, 128), jnp.bfloat16)
+    _tpu_lower(
+        jax.grad(lambda q, k, v: pa.flash_attention(
+            q, k, v, block_diffusion_mask=(300, 4), backward="pallas"
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        q, kv, kv,
+    )
+
+
 def test_flash_ragged_lowers_for_tpu(mosaic):
     # non-multiple length exercises the padded final blocks and, under
     # causal, the compressed scalar-prefetch tile walk with a partial row

@@ -21,6 +21,8 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from tpu_syncbn.obs import tracing
+
 
 class _Draws:
     """Lock-protected RandomState shared safely across loader threads.
@@ -283,3 +285,51 @@ class ToFloat:
         if x.dtype == np.uint8:
             return x.astype(np.float32) / 255.0
         return np.asarray(x, np.float32)
+
+
+class BlockDiffusionNoise:
+    """The noising of block-diffusion training (BD3-LM, arXiv:2503.09573),
+    a sample at a time on the loader's threads: ``(x0, key) -> (x0, xt,
+    w)``. ``x0`` (L,) token ids below ``mask_id``, in blocks of
+    ``block`` (the last may be short); each block draws a noise level
+    ``t`` uniform on ``[t_min, 1]`` (the linear schedule ``alpha_t = 1 -
+    t``), each of its tokens is replaced by ``mask_id`` independently
+    with probability ``t``, giving ``xt``; ``w`` (L,) float32 is ``1 /
+    t`` at a replaced position and 0 elsewhere: the weight of that
+    position's cross-entropy.
+
+    Deterministic: the draws come from a generator seeded by ``seed``
+    and the sample's own ``key`` (an integer the data set carries beside
+    the tokens), so a sample gets the same noise whenever and on
+    whichever thread it is built, a run repeats, and a reference can be
+    handed the same ``xt`` and ``w``. No state, no lock.
+
+    Under tracing (``obs.tracing``) each call is a span ``noise``, on the
+    building thread inside ``loader.build``, whose ``masked`` is the
+    share of the sample's positions it replaced."""
+
+    def __init__(self, *, block: int, mask_id: int, seed: int,
+                 t_min: float = 1e-3):
+        if block < 1 or not 0.0 < t_min <= 1.0:
+            raise ValueError("block must be positive and t_min in (0, 1]")
+        self.block, self.mask_id = int(block), int(mask_id)
+        self.seed, self.t_min = int(seed), float(t_min)
+
+    def __call__(self, sample):
+        x0, key = sample
+        x0 = np.asarray(x0)
+        if x0.size and x0.max() >= self.mask_id:
+            raise ValueError(f"a clean token is the mask id {self.mask_id} "
+                             "or above it")
+        tracer = tracing.get()
+        token = tracer.begin("noise") if tracer is not None else None
+        rng = np.random.default_rng([self.seed, int(key)])
+        blocks = -(-x0.shape[0] // self.block)
+        t = rng.uniform(self.t_min, 1.0, size=blocks).astype(np.float32)
+        t = np.repeat(t, self.block)[:x0.shape[0]]
+        masked = rng.random(x0.shape[0], dtype=np.float32) < t
+        xt = np.where(masked, self.mask_id, x0).astype(x0.dtype)
+        w = np.where(masked, 1.0 / t, 0.0).astype(np.float32)
+        if token is not None:
+            tracer.end(token, masked=float(masked.mean()))
+        return x0, xt, w

@@ -173,6 +173,36 @@ def causal_attention(q, k, v, impl: str):
                       preferred_element_type=jnp.float32).astype(v.dtype)
 
 
+def block_diffusion_attention(q, k, v, clean_len: int, block: int, impl: str):
+    """``softmax(q k^T / sqrt(d) + M) v`` over ``2 * clean_len``
+    positions laid out ``[clean ; noisy]``, M the block-diffusion mask
+    (``ops.pallas_attention.Visibility``), for q (B, S, heads, d) and k,
+    v (B, S, kv_heads, d), kv_heads dividing heads: q head h reads k/v
+    head ``h // (heads / kv_heads)``. The sibling of ``causal_attention``
+    under the same switch: ``"xla"`` materialises the masked float32
+    scores, a group's q heads against their one k/v head; ``"flash"``
+    runs the kernels, forward and backward, k and v at their own heads."""
+    from tpu_syncbn.ops import pallas_attention
+
+    if impl == "flash":
+        return pallas_attention.flash_attention(
+            q, k, v, block_diffusion_mask=(clean_len, block),
+            backward="pallas")
+    b, s, heads, d = q.shape
+    grouped = q.reshape(b, s, k.shape[2], -1, d)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (d ** -0.5)
+    at = jnp.arange(s)
+    visible = pallas_attention.block_diffusion(clean_len, block).visible(
+        at[:, None], at[None, :])
+    scores = jnp.where(visible, scores, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, heads, v.shape[-1]).astype(v.dtype)
+
+
 def exit_distribution(lam):
     """``lam`` (T, ...) gate values in (0, 1) -> ``p`` (T, ...): exit at
     pass t with ``lam_t`` times the probability of having stayed through

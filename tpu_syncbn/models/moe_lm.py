@@ -119,6 +119,67 @@ def apply_rotary_pairs(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+class MoEDecoderBase(nnx.Module):
+    """What the decoders with a held share of experts have in common
+    (this module's and ``models.block_diffusion_lm``'s), whatever their
+    attention and router: the products' precision, the embedding, the
+    head under its scope, and the expert loads summed over the replicas
+    and kept in ``rest``. Reads ``dtype``, ``rms_eps``, ``axis_name``,
+    ``embed`` and ``head`` of the model it is a base of."""
+
+    def _dot(self, x, w):
+        """Operands in the compute type, products accumulated in float32,
+        the result stored in the compute type."""
+        return jnp.dot(x, w.astype(self.dtype),
+                       preferred_element_type=jnp.float32).astype(self.dtype)
+
+    def embed_tokens(self, tokens):
+        return self.embed[...][tokens].astype(self.dtype)
+
+    def read(self, h, scale):
+        """``z = N(h)``: what the head reads."""
+        return rms_norm(h, scale, self.rms_eps)
+
+    def logits(self, z):
+        """Float32 logits of ``z`` (.., H) over the vocabulary held."""
+        return jnp.dot(z, self.head[...].astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+    def cross_entropy(self, h, scale, targets):
+        """Per position, (B, S) float32."""
+        with jax.named_scope("lm_head"):
+            logits = self.logits(self.read(h, scale))
+            picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)
+            return jax.nn.logsumexp(logits, axis=-1) - picked[..., 0]
+
+    def global_load(self, load):
+        """``load`` summed over the replicas: the identity where
+        ``axis_name`` is not in scope."""
+        load = lax.stop_gradient(load)
+        if self.axis_name is not None and _axis_in_scope(self.axis_name):
+            load = collectives.psum(load, self.axis_name)
+        return load
+
+    @staticmethod
+    def _counted(block, load):
+        """The global ``load`` (n, E) of this step joins ``block``'s
+        cumulative load, and its recent steps' loads shift by one."""
+        block.load[...] = block.load[...] + load
+        block.recent_load[...] = jnp.concatenate(
+            [block.recent_load[...][:, 1:], load[:, None]], axis=1)
+
+
+def held_chunk(pairs: int, held: int, experts: int) -> int:
+    """Rows a chunk of ``expert.held_expert_moe``'s walk: twice what
+    arrives on the ``held`` of ``experts`` experts if the loads of the
+    ``pairs`` chosen pairs are even, in whole row tiles (at
+    initialisation a layer that holds one of the few experts nearly
+    every token chooses gets one to two such chunks; four times the even
+    share read 0.75% slower and spread wider over seeds, PERF.md section
+    6, PR 34)."""
+    return -(-int(2 * pairs * held / experts) // _ROW_TILE) * _ROW_TILE
+
+
 class _Block(nnx.Module):
     """``n`` layers of one kind, each parameter stacked on a leading
     axis of n: latent attention, two norms, and the feed-forward: a
@@ -167,7 +228,7 @@ class _Block(nnx.Module):
         return {name: getattr(self, name)[...] for name in self.names}
 
 
-class LatentMoEDecoderLM(nnx.Module):
+class LatentMoEDecoderLM(MoEDecoderBase):
     """See the module docstring. ``tokens``, ``targets`` (the next
     token) and ``targets2`` (the one after) are (B, S) integers; nothing
     here knows about replicas but the sum of the experts' loads over
@@ -225,12 +286,6 @@ class LatentMoEDecoderLM(nnx.Module):
 
     # -- one layer ----------------------------------------------------------
 
-    def _dot(self, x, w):
-        """Operands in the compute type, products accumulated in float32,
-        the result stored in the compute type."""
-        return jnp.dot(x, w.astype(self.dtype),
-                       preferred_element_type=jnp.float32).astype(self.dtype)
-
     def _swiglu(self, x, wg, wu, wd):
         gate = self._dot(x, wg).astype(jnp.float32)
         up = self._dot(x, wu).astype(jnp.float32)
@@ -281,17 +336,11 @@ class LatentMoEDecoderLM(nnx.Module):
         with jax.named_scope("moe"):
             flat = n.reshape(-1, n.shape[-1])
             idx, gates = self._route(flat, p, bias)
-            # a chunk of the sorted pairs: twice what arrives on the
-            # experts held if the loads are even, in whole row tiles
-            # (at initialisation a layer that holds one of the few
-            # experts nearly every token chooses gets one to two such
-            # chunks; four times the even share read 0.75% slower and
-            # spread wider over seeds, PERF.md section 6, PR 34)
-            held = idx.size * p["eg"].shape[0] / p["router"].shape[-1]
             routed, missed = expert.held_expert_moe(
                 flat, idx, gates, p["eg"], p["eu"], p["ed"],
                 first_expert=self.first_expert,
-                chunk=-(-int(2 * held) // _ROW_TILE) * _ROW_TILE)
+                chunk=held_chunk(idx.size, p["eg"].shape[0],
+                                 p["router"].shape[-1]))
             with jax.named_scope("moe_route"):
                 load = expert.expert_loads(idx, p["router"].shape[-1])
             with jax.named_scope("moe_shared"):
@@ -334,9 +383,6 @@ class LatentMoEDecoderLM(nnx.Module):
 
     # -- the pieces a caller may read -----------------------------------------
 
-    def embed_tokens(self, tokens):
-        return self.embed[...][tokens].astype(self.dtype)
-
     def hidden(self, tokens):
         """The stack's output before its final norm, (B, S, H), and the
         expert layers' (loads, pairs not computed)."""
@@ -377,36 +423,12 @@ class LatentMoEDecoderLM(nnx.Module):
                 "load": load, "moe": moe, "pairs_not_computed": missed,
                 "out": a + moe}
 
-    def read(self, h, scale):
-        """``z = N(h)``: what the head reads."""
-        return rms_norm(h, scale, self.rms_eps)
-
-    def logits(self, z):
-        """Float32 logits of ``z`` (.., H) over the vocabulary held."""
-        return jnp.dot(z, self.head[...].astype(self.dtype),
-                       preferred_element_type=jnp.float32)
-
-    def cross_entropy(self, h, scale, targets):
-        """Per position, (B, S) float32."""
-        with jax.named_scope("lm_head"):
-            logits = self.logits(self.read(h, scale))
-            picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)
-            return jax.nn.logsumexp(logits, axis=-1) - picked[..., 0]
-
     def __call__(self, tokens):
         """The next token's logits, (B, S, vocabulary) float32."""
         return self.logits(self.read(self.hidden(tokens)[0],
                                      self.final_norm[...]))
 
     # -- the loss ---------------------------------------------------------------
-
-    def global_load(self, load):
-        """``load`` summed over the replicas: the identity where
-        ``axis_name`` is not in scope."""
-        load = lax.stop_gradient(load)
-        if self.axis_name is not None and _axis_in_scope(self.axis_name):
-            load = collectives.psum(load, self.axis_name)
-        return load
 
     def _moved(self, block: _Block, load):
         """The step's router state: the bias moved against the global
@@ -415,9 +437,7 @@ class LatentMoEDecoderLM(nnx.Module):
         load = self.global_load(load)
         block.bias[...] = expert.update_selection_bias(
             block.bias[...], load, self.bias_gamma)
-        block.load[...] = block.load[...] + load
-        block.recent_load[...] = jnp.concatenate(
-            [block.recent_load[...][:, 1:], load[:, None]], axis=1)
+        self._counted(block, load)
         return load
 
     def loss(self, tokens, targets, targets2=None):

@@ -12,17 +12,39 @@ holds one (block_q, D) query tile and streams (block_k, D) KV tiles
 through VMEM, carrying the online-softmax running (max, denominator,
 accumulator) in f32 scratch — the same algorithm
 ``parallel.sequence._block_attend`` runs at the ring level, pushed down
-to the tile level. Under ``causal=True`` the grid itself is compressed:
-only the at-or-below-diagonal (qi, ki) tile pairs are enumerated (a 1-D
-tile walk mapped through scalar-prefetched index arrays), so tiles
-strictly above the diagonal cost neither MXU work NOR VMEM streaming —
-the BlockSpec pipeline never touches their DMA (~2x bandwidth cut at
-long L vs the rectangular grid); only the tiles the diagonal crosses
-(and the last key tile of a padded length) build a mask, with a 2-D
-iota. The forward's tiles come from the call's shape
+to the tile level. Under a mask the grid itself is compressed: only the
+(qi, ki) tile pairs that hold a visible score are enumerated (a 1-D
+tile walk mapped through scalar-prefetched index arrays), so a tile
+without one costs neither MXU work NOR VMEM streaming — the BlockSpec
+pipeline never touches its DMA (~2x bandwidth cut under ``causal`` at
+long L vs the rectangular grid); only the tiles the mask does not fill
+(the diagonal's, and the last key tile of a padded length) build it,
+from two 2-D iotas. The forward's tiles come from the call's shape
 (:func:`forward_blocks`: 512 x 512 from 512 tokens up, where the length
 allows) unless the caller names them: a grid step costs 0.3-0.4 us
 whatever it holds, so the walk is made of few, large steps.
+
+**The masks** are one thing, a :class:`Visibility` rule: ``FULL``,
+``CAUSAL``, or ``block_diffusion(clean_len, block)`` over positions laid
+out ``[clean ; noisy]`` (block-diffusion training, BD3-LM's vectorised
+form: a clean position sees the clean past block-causally, a noisy one
+the clean blocks strictly before its own and its own noisy block, both
+ways; ``L^2 + block L`` live scores of the ``(2L)^2``). The rule says
+which pairs count (``visible``, on numpy arrays or a kernel's iotas
+alike); every kernel's walk is ONE enumeration of the live tile pairs
+of the rule, computed with numpy from the call's shape
+(:func:`_live_tiles`: at 512 x 512 tiles and 4,096 clean positions 80
+tile pairs a head where a causal walk of the 8,192 visits 136, and a
+mask on 24 of them). A window or a segment mask would be a fourth kind
+with its ``visible``, its ``hides_in_tile`` and nothing else.
+
+**The shape rule.** q is (B, L, H, D); k (B, L, H_kv, D) and v (B, L,
+H_kv, Dv) share ``H_kv`` heads, which divides H (grouped-query
+attention): q head h reads k/v head ``h // (H / H_kv)``, through the
+block index maps (``b // group``); dK/dV runs one sweep a k/v head over
+the query tiles of all its group's q heads into one accumulator, reading
+q, dO and the statistics as (k/v head, group x length), which is how
+they lie in memory. No copy of k, v, dk or dv a q head ever exists.
 
 q and k share one head width and v (with the output) may have another
 (latent attention: 192 for q and k, a rotary part beside the 128 that v
@@ -35,23 +57,25 @@ recomputing P from the saved logsumexp (O(L·block) live memory, never
 (L, L)). ``backward="pallas"`` is two fused kernels in the
 FlashAttention-2 structure — a dK/dV kernel sweeping query tiles per KV
 tile and a dQ kernel sweeping KV tiles per query tile, f32 VMEM
-accumulators, compressed causal walks that never visit a dead tile —
+accumulators, compressed walks that never visit a dead tile —
 built as the forward is: tiles from the call's shape
 (:func:`backward_blocks`, each kernel its own), operands in the inputs'
-type with float32 sums, a mask only on the tiles the diagonal or the
-padding crosses, v and dO of a width of their own, operations named
+type with float32 sums, a mask only on the tiles the rule does not fill
+or the padding crosses, v and dO of a width of their own, operations named
 after their tiles (``flash_bwd_dkv_q512_k512``,
 ``flash_bwd_dq_q512_k512``). dK/dV is computed transposed (keys down the
 rows), so that no tile is ever transposed and the log-sum-exp arrives as
 a lane-dense row. ``backward="xla"``, the default of
 :func:`flash_attention`, is one ``lax.scan`` over KV blocks: the path of
 the callers that name it (``parallel.sequence``'s Ulysses local
-attention, ``models.transformer``), which no benchmark cell runs.
+attention, ``models.transformer``), which no benchmark cell runs; it
+takes equal heads under no mask or the causal one, and refuses grouped
+heads and the block-diffusion mask by name.
 
 Like the BN kernels, everything runs under ``interpret=True`` off-TPU
 (the CPU suite exercises the real kernel code path), and the kernel is
 an *opt-in* backend (``attn_impl="flash"`` of ``models.transformer``,
-``models.looped_lm`` and ``models.moe_lm``). The hardware measurement
+``models.looped_lm``, ``models.moe_lm`` and ``models.block_diffusion_lm``). The hardware measurement
 (TPU v5e, PERF.md section 6, PR 30, PR 31 and PR 35): inside the looped
 decoder at 16 heads of 128, 2 x 2,048 tokens, causal, forward +
 recomputed forward + backward of 32 layer applications a step, XLA's
@@ -79,7 +103,7 @@ forward and backward in these kernels.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -161,15 +185,97 @@ def _across(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _holds_masked_scores(qi, ki, *, causal, block_q, block_k, n_k, pad_k):
+class Visibility(NamedTuple):
+    """Which (query, key) pairs of a call count: the rule every kernel
+    here walks and masks by. Hashable and static: the tile walks are
+    enumerated from it with numpy when the call is traced, and inside a
+    kernel it is asked only on the tiles it does not fill.
+
+    * ``FULL``: every pair.
+    * ``CAUSAL``: key r counts for query p iff ``r <= p``.
+    * ``block_diffusion(clean_len, block)``: queries and keys are laid
+      out ``[clean ; noisy]``, two copies of one sequence of
+      ``clean_len`` positions in blocks of ``block`` (the vectorised
+      training form of block diffusion, BD3-LM, arXiv:2503.09573). With
+      ``blk(p) = (p mod clean_len) // block``: a clean query sees the
+      clean keys of its own and earlier blocks; a noisy query sees the
+      clean keys of strictly earlier blocks and the noisy keys of its
+      own block, both ways; nothing clean sees anything noisy. Every
+      query sees itself, so no row of the softmax is empty.
+      ``clean_len^2 + block * clean_len`` live scores of the
+      ``(2 clean_len)^2``.
+    """
+
+    kind: str = "full"
+    clean_len: int = 0
+    block: int = 0
+
+    def _block_of(self, x):
+        """``blk(x)`` of positions ``x >= 0``: ints, numpy or jax."""
+        within = x - self.clean_len * (x >= self.clean_len)
+        shift = self.block.bit_length() - 1
+        return (within >> shift if self.block == 1 << shift
+                else within // self.block)
+
+    def visible(self, rows, cols):
+        """The boolean map of the pairs that count, from integer
+        positions that broadcast against each other: numpy arrays (the
+        host's enumeration) or a kernel's iotas. Not asked of ``FULL``."""
+        if self.kind == "causal":
+            return rows >= cols
+        noisy_q, noisy_k = rows >= self.clean_len, cols >= self.clean_len
+        blk_q, blk_k = self._block_of(rows), self._block_of(cols)
+        return ((~noisy_k & ((~noisy_q & (blk_k <= blk_q))
+                             | (noisy_q & (blk_k < blk_q))))
+                | (noisy_q & noisy_k & (blk_k == blk_q)))
+
+    def hides_in_tile(self, qi, ki, block_q: int, block_k: int):
+        """Whether tile (qi, ki) can hold a pair that does not count
+        (``qi`` / ``ki`` traced or plain); None where no tile can. May
+        say yes of a tile the rule fills, which then builds a mask that
+        hides nothing; never no of one it does not."""
+        if self.kind == "full":
+            return None
+        if self.kind == "causal":
+            return ki * block_k + block_k - 1 > qi * block_q
+        # filled: clean keys only, all of them in blocks before (for
+        # clean queries: up to) the block of the tile's first query
+        first_q, last_k = qi * block_q, ki * block_k + block_k - 1
+        last_q = first_q + block_q - 1
+        blk_q, blk_k = self._block_of(first_q), self._block_of(last_k)
+        filled = (last_k < self.clean_len) & (
+            ((last_q < self.clean_len) & (blk_k <= blk_q))
+            | ((first_q >= self.clean_len) & (blk_k < blk_q)))
+        return jnp.logical_not(filled)
+
+    def live_tile(self, qi, ki, block_q: int, block_k: int):
+        """On a rectangular grid, whether tile (qi, ki) holds any pair
+        that counts; None: visit every tile (what a tile without one
+        adds is masked to nothing)."""
+        if self.kind == "causal":
+            return ki * block_k <= qi * block_q + block_q - 1
+        return None
+
+
+FULL = Visibility()
+CAUSAL = Visibility("causal")
+
+
+def block_diffusion(clean_len: int, block: int) -> Visibility:
+    if clean_len < 1 or block < 1:
+        raise ValueError(f"clean_len and block must be positive, got "
+                         f"{clean_len} and {block}")
+    return Visibility("block_diffusion", int(clean_len), int(block))
+
+
+def _holds_masked_scores(qi, ki, *, rule, block_q, block_k, n_k, pad_k):
     """Whether tile (qi, ki) can hold a score that must not count: only
-    a tile the diagonal crosses, or the last key tile of a padded
-    length. Every other live tile skips the iotas, the compares and the
-    select. ``qi`` / ``ki`` traced or plain; None where the shape alone
-    says that no tile can (full attention, no padding)."""
-    edge = None
-    if causal:
-        edge = ki * block_k + block_k - 1 > qi * block_q
+    a tile the rule does not fill (the diagonal's, under ``CAUSAL``), or
+    the last key tile of a padded length. Every other live tile skips
+    the iotas, the compares and the select. ``qi`` / ``ki`` traced or
+    plain; None where the shape alone says that no tile can (full
+    attention, no padding)."""
+    edge = rule.hides_in_tile(qi, ki, block_q, block_k)
     if pad_k:
         last = ki == n_k - 1
         edge = last if edge is None else edge | last
@@ -194,11 +300,12 @@ def _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref):
 
 
 def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, last_ki, *,
-                 scale, causal, block_q, block_k, n_k, l_real):
+                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, first_ki, last_ki, *,
+                 scale, rule, block_q, block_k, n_k, l_real):
     """One (qi, ki) online-softmax step; ``qi``/``ki`` may be traced
-    scalars (compressed causal grid) or program ids (rectangular grid).
-    The ki sweep for a fixed (bh, qi) is contiguous in the grid walk, so
+    scalars (a compressed walk) or program ids (rectangular grid).
+    The ki sweep for a fixed (bh, qi) is contiguous in the grid walk,
+    from ``first_ki`` to ``last_ki``, so
     the VMEM scratch carries the running (max, denom, acc) across it,
     the two statistics lane-dense: (block_q, 128), the value of a row in
     every lane. Each product's operands reach the MXU in the inputs'
@@ -207,9 +314,11 @@ def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
     second product (the sum that normalises them takes them unrounded).
     For bf16 inputs this is what the MXU did to float32 operands
     already (PERF.md section 6, PR 30 and PR 31); float32 inputs stay
-    float32."""
+    float32. A row whose scores so far were all hidden carries the
+    stand-in maximum and a sum of ones; its first visible score rescales
+    both to exactly zero, and every row has one (itself)."""
 
-    @pl.when(ki == 0)
+    @pl.when(ki == first_ki)
     def _init():
         _init_carry(acc_ref, m_ref, l_ref)
         qs_ref[...] = (q_ref[0].astype(jnp.float32) * scale).astype(
@@ -223,17 +332,9 @@ def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32,
         )  # (block_q, block_k)
         if masked:
-            cols = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            mask = cols < l_real if pad_k else None  # right-pad KV rows
-            if causal:
-                rows = qi * block_q + lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
-                )
-                visible = rows >= cols
-                mask = visible if mask is None else mask & visible
-            s = jnp.where(mask, s, _NEG_BIG)
+            s = _mask_scores(s, qi, ki, keys_axis=1, rule=rule,
+                             block_q=block_q, block_k=block_k,
+                             l_real=l_real, pad_k=pad_k)
 
         m_prev = m_ref[...]  # (block_q, 128)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -249,7 +350,7 @@ def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
         )
         m_ref[...] = m_new
 
-    edge = _holds_masked_scores(qi, ki, causal=causal, block_q=block_q,
+    edge = _holds_masked_scores(qi, ki, rule=rule, block_q=block_q,
                                 block_k=block_k, n_k=n_k, pad_k=pad_k)
     if edge is None:
         step(masked=False)
@@ -264,19 +365,22 @@ def _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _attn_kernel_rect(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, qs_ref, *,
-                      scale, causal, block_q, block_k, n_k, l_real):
-    """Full rectangular grid (BH, n_q, n_k), ki innermost. Non-causal
-    always; also the causal fallback when the compressed walk's index
-    arrays would be too large for scalar memory — there, above-diagonal
-    tiles still stream through VMEM but skip their matmuls."""
+                      scale, rule, block_q, block_k, n_k, l_real):
+    """Full rectangular grid (BH, n_q, n_k), ki innermost. Full
+    attention always; also the fallback when a compressed walk's index
+    arrays would be too large for scalar memory: there, under
+    ``CAUSAL``, above-diagonal tiles still stream through VMEM but skip
+    their matmuls, and under any other rule every tile is computed and
+    masked."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     attend = functools.partial(
         _attend_tile, q_ref, k_ref, v_ref, o_ref, lse_ref,
-        acc_ref, m_ref, l_ref, qs_ref, qi, ki, n_k - 1,
-        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        acc_ref, m_ref, l_ref, qs_ref, qi, ki, 0, n_k - 1,
+        scale=scale, rule=rule, block_q=block_q, block_k=block_k,
         n_k=n_k, l_real=l_real)
-    if not causal:
+    live = rule.live_tile(qi, ki, block_q, block_k)
+    if live is None:
         attend()
         return
 
@@ -286,7 +390,6 @@ def _attn_kernel_rect(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     # a KV tile strictly right of this query tile's last row touches
     # nothing — skip its matmuls (its DMA still streams in this path)
-    live = ki * block_k <= qi * block_q + block_q - 1
     pl.when(live)(attend)
 
     @pl.when(ki == n_k - 1)
@@ -297,23 +400,30 @@ def _attn_kernel_rect(q_ref, k_ref, v_ref, o_ref, lse_ref,
         _write_out(o_ref, lse_ref, acc_ref, m_ref, l_ref)
 
 
-def _attn_kernel_causal(qids_ref, kids_ref, q_ref, k_ref, v_ref,
-                        o_ref, lse_ref, acc_ref, m_ref, l_ref, qs_ref, *,
-                        scale, block_q, block_k, n_k, l_real):
-    """Causal: compressed 1-D tile walk (BH, T) over ONLY the live
-    (qi, ki) pairs, decoded from the scalar-prefetched index arrays —
-    above-diagonal tiles are never visited, so their KV DMA never
-    happens. last live ki for a query tile is where the diagonal exits
-    its rows (clamped to the KV extent)."""
+def _attn_kernel_walk(qids_ref, kids_ref, q_ref, k_ref, v_ref,
+                      o_ref, lse_ref, acc_ref, m_ref, l_ref, qs_ref, *,
+                      scale, rule, n_tiles, block_q, block_k, n_k, l_real):
+    """A compressed 1-D tile walk (BH, T) over ONLY the live (qi, ki)
+    pairs of the rule, decoded from the scalar-prefetched index arrays:
+    a tile without a visible score is never visited, so its KV DMA never
+    happens. Under ``CAUSAL`` a query tile's sweep runs from key tile 0
+    to where the diagonal exits its rows (clamped to the KV extent);
+    under any other rule its ends are read off the walk itself."""
     t = pl.program_id(1)
     qi = qids_ref[t]
     ki = kids_ref[t]
-    last_ki = jnp.minimum(
-        n_k - 1, (qi * block_q + block_q - 1) // block_k
-    )
+    if rule.kind == "causal":
+        first_ki = 0
+        last_ki = jnp.minimum(
+            n_k - 1, (qi * block_q + block_q - 1) // block_k
+        )
+    else:
+        is_start, is_end = _walk_group_bounds(qids_ref, t, n_tiles)
+        first_ki = jnp.where(is_start, ki, -1)
+        last_ki = jnp.where(is_end, ki, -1)
     _attend_tile(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, last_ki,
-                 scale=scale, causal=True, block_q=block_q,
+                 acc_ref, m_ref, l_ref, qs_ref, qi, ki, first_ki, last_ki,
+                 scale=scale, rule=rule, block_q=block_q,
                  block_k=block_k, n_k=n_k, l_real=l_real)
 
 
@@ -326,45 +436,62 @@ _MAX_CAUSAL_TILES = 16384
 
 
 @functools.lru_cache(maxsize=64)
-def _causal_tiles(n_q: int, n_k: int, block_q: int, block_k: int):
-    """Enumerate live (qi, ki) pairs for the causal lower triangle, qi
-    ascending and ki ascending within qi (the scratch-carry contract).
-    ~T = n_q(n_q+1)/2 of the rectangular n_q*n_k when blocks match."""
+def _live_tiles(rule: Visibility, n_q: int, n_k: int, block_q: int,
+                block_k: int, by_key: bool = False, group: int = 1):
+    """The walk of every kernel here: the tile pairs that hold a pair
+    the rule lets count, enumerated once with numpy from the shape (over
+    the padded lengths: a padded row sees what the rule says of its
+    position).
+
+    By query tile (the forward's and dQ's): ``(qids, kids)``, qi
+    ascending and ki ascending within qi, the scratch-carry contract.
+    Under ``CAUSAL`` ~n_q(n_q+1)/2 of the rectangular n_q*n_k when
+    blocks match; under ``block_diffusion`` at 512 x 512 and 4,096
+    clean positions 80 of 256 (a causal walk of the 8,192: 136).
+
+    ``by_key`` (dK/dV's): ``(kis, qis)`` grouped by ki ascending, the
+    scratch carries one KV tile's (dk, dv) across its contiguous sweep.
+    With ``group`` q heads a k/v head the sweep runs over the live query
+    tiles of each of them in turn, and ``qis`` holds ``g * n_q + qi``:
+    the query tile of q laid out (k/v head, group * length)."""
     import numpy as np
 
-    qids, kids = [], []
+    live = np.zeros((n_q, n_k), bool)
+    cols = np.arange(n_k * block_k)[None, :]
     for qi in range(n_q):
-        k_hi = min(n_k - 1, (qi * block_q + block_q - 1) // block_k)
-        for ki in range(k_hi + 1):
-            qids.append(qi)
-            kids.append(ki)
-    return np.asarray(qids, np.int32), np.asarray(kids, np.int32)
-
-
-@functools.lru_cache(maxsize=64)
-def _causal_tiles_kv(n_q: int, n_k: int, block_q: int, block_k: int):
-    """The transposed walk for the dK/dV backward kernel: live (ki, qi)
-    pairs grouped by ki ascending, qi ascending within ki starting at
-    the first query tile that reaches this KV tile's columns
-    (qi_lo = (ki*block_k) // block_q) — the scratch carries one KV
-    tile's (dk, dv) across its contiguous qi sweep."""
-    import numpy as np
-
+        rows = qi * block_q + np.arange(block_q)[:, None]
+        live[qi] = rule.visible(rows, cols).reshape(
+            block_q, n_k, block_k).any(axis=(0, 2))
+    if not by_key:
+        qids, kids = np.nonzero(live)
+        return qids.astype(np.int32), kids.astype(np.int32)
     kis, qis = [], []
     for ki in range(n_k):
-        for qi in range((ki * block_k) // block_q, n_q):
-            kis.append(ki)
-            qis.append(qi)
-    return np.asarray(kis, np.int32), np.asarray(qis, np.int32)
+        reach = np.nonzero(live[:, ki])[0]
+        for g in range(group):
+            kis.append(np.full(len(reach), ki))
+            qis.append(g * n_q + reach)
+    return (np.concatenate(kis).astype(np.int32),
+            np.concatenate(qis).astype(np.int32))
 
 
-def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
-    """q, k (BH, L, D) and v (BH, L, Dv) in → ((BH, L, Dv) out, (BH, L)
-    logsumexp). A block the caller did not name (None) comes from the
-    shape. Where Dv = D this is the kernel it was before v had a width of
-    its own: the same tiles, blocks and name."""
+def _kv_head(group: int):
+    """The k/v batch-head that q's batch-head ``b`` reads: heads are
+    laid out (batch, head) and q head h reads k/v head ``h // group``,
+    so ``b // group``; ``b`` itself where every q head has its own."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
+def _flash_fwd_2d(q, k, v, *, rule, scale, block_q, block_k):
+    """q (BH, L, D), k (BH / group, L, D) and v (BH / group, L, Dv) in →
+    ((BH, L, Dv) out, (BH, L) logsumexp). A block the caller did not
+    name (None) comes from the shape. Where Dv = D, group = 1 and the
+    rule is ``CAUSAL`` or ``FULL`` this is the kernel it was before v had
+    a width of its own, k and v heads of their own or the walk a rule:
+    the same tiles, blocks, walk and name."""
     bh, l_real, d = q.shape
     dv = v.shape[-1]
+    kv = _kv_head(bh // k.shape[0])
     if block_q is None or block_k is None:
         chosen = forward_blocks(l_real, d, q.dtype.itemsize, dv)
         block_q = chosen[0] if block_q is None else block_q
@@ -392,13 +519,13 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
     ]
     # the operation's name in a trace says which tiles ran
     name = f"flash_fwd_q{block_q}_k{block_k}"
-    if causal:
+    if rule != FULL:
         # one source of truth for the live-tile set: the gate below must
         # agree exactly with the SMEM index-array size it protects
-        qids, kids = _causal_tiles(int(n_q), int(n_k), block_q, block_k)
-    if causal and len(qids) <= _MAX_CAUSAL_TILES:
+        qids, kids = _live_tiles(rule, int(n_q), int(n_k), block_q, block_k)
+    if rule != FULL and len(qids) <= _MAX_CAUSAL_TILES:
         kernel = functools.partial(
-            _attn_kernel_causal, scale=scale,
+            _attn_kernel_walk, scale=scale, rule=rule, n_tiles=len(qids),
             block_q=block_q, block_k=block_k, n_k=n_k, l_real=l_real,
         )
         # index maps see (b, t, qids_ref, kids_ref): the tile walk is
@@ -412,10 +539,10 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
                              lambda b, t, qids, kids: (b, qids[t], 0),
                              memory_space=vmem),
                 pl.BlockSpec((1, block_k, d),
-                             lambda b, t, qids, kids: (b, kids[t], 0),
+                             lambda b, t, qids, kids: (kv(b), kids[t], 0),
                              memory_space=vmem),
                 pl.BlockSpec((1, block_k, dv),
-                             lambda b, t, qids, kids: (b, kids[t], 0),
+                             lambda b, t, qids, kids: (kv(b), kids[t], 0),
                              memory_space=vmem),
             ],
             out_specs=[
@@ -435,7 +562,7 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
         return o[:, :l_real], lse[:, :l_real, 0]
 
     kernel = functools.partial(
-        _attn_kernel_rect, scale=scale, causal=causal,
+        _attn_kernel_rect, scale=scale, rule=rule,
         block_q=block_q, block_k=block_k, n_k=n_k, l_real=l_real,
     )
     o, lse = pl.pallas_call(
@@ -444,9 +571,9 @@ def _flash_fwd_2d(q, k, v, *, causal, scale, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0),
                          memory_space=vmem),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0),
                          memory_space=vmem),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0),
                          memory_space=vmem),
         ],
         out_specs=[
@@ -478,8 +605,14 @@ def backward_scan_block(length: int) -> int:
     return max(128, min(512, length // 16 // _LANES * _LANES))
 
 
-def _flash_bwd_2d(res, do, *, causal, scale, block_k):
-    q, k, v, o, lse = res  # (BH, L, D)*2, (BH, L, Dv)*2, (BH, L)
+def _flash_bwd_2d(res, do, *, rule, scale, block_k):
+    q, k, v, o, lse = res  # (BH, L, D) x2, (BH, L, Dv) x2, (BH, L)
+    if k.shape[0] != q.shape[0] or rule not in (FULL, CAUSAL):
+        raise ValueError(
+            "the XLA backward scan takes as many k/v heads as q heads and "
+            "no mask or the causal one: name backward='pallas' for grouped "
+            f"heads or another rule (got {q.shape[0]} over {k.shape[0]} "
+            f"heads under {rule})")
     bh, l_real, d = q.shape
     dv = v.shape[-1]
     n_k = -(-l_real // block_k)
@@ -502,7 +635,7 @@ def _flash_bwd_2d(res, do, *, causal, scale, block_k):
         cols = ki * block_k + jnp.arange(block_k)
         s = jnp.einsum("bqd,bkd->bqk", qf, k_blk.astype(jnp.float32))
         mask = cols[None, :] < l_real
-        if causal:
+        if rule == CAUSAL:
             mask = mask & (rows[:, None] >= cols[None, :])
         s = jnp.where(mask[None], s, _NEG_BIG)
         p = jnp.exp(s - lse[..., None])  # (BH, L, block_k)
@@ -633,30 +766,32 @@ _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def _mask_scores(s, qi, ki, *, keys_axis, causal, block_q, block_k,
+def _mask_scores(s, qi, ki, *, keys_axis, rule, block_q, block_k,
                  l_real, pad_k):
     """``s`` with the scores that must not count at ``_NEG_BIG`` (their
     exp against any log-sum-exp is exactly zero): the keys of a padded
-    length's last tile and, under ``causal``, what lies above the
-    diagonal. The keys run along ``keys_axis`` of the tile."""
+    length's last tile and what the rule hides (under ``CAUSAL``, what
+    lies above the diagonal), from two iotas. The keys run along
+    ``keys_axis`` of the tile."""
     cols = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, keys_axis)
     mask = cols < l_real if pad_k else None
-    if causal:
+    if rule != FULL:
         rows = qi * block_q + lax.broadcasted_iota(
             jnp.int32, s.shape, 1 - keys_axis)
-        visible = rows >= cols
+        visible = rule.visible(rows, cols)
         mask = visible if mask is None else mask & visible
     return jnp.where(mask, s, _NEG_BIG)
 
 
-def _on_tile(step, qi, ki, live, *, causal, block_q, block_k, n_k, l_real):
+def _on_tile(step, qi, ki, live, *, rule, block_q, block_k, n_k, l_real):
     """``step(masked)`` once, where ``live`` (None: always): masked where
     tile (qi, ki) can hold a score that must not count, plain everywhere
     else (``_holds_masked_scores``). Padded QUERY rows need no mask:
     their q, dO, log-sum-exp and delta are zero-padded, so their p is
-    exp(0 - 0) = 1 against a dO and a ``dp - delta`` of exactly zero."""
+    at most exp(0 - 0) = 1 against a dO and a ``dp - delta`` of exactly
+    zero."""
     edge = _holds_masked_scores(
-        qi, ki, causal=causal, block_q=block_q, block_k=block_k, n_k=n_k,
+        qi, ki, rule=rule, block_q=block_q, block_k=block_k, n_k=n_k,
         pad_k=n_k * block_k - l_real)
 
     def once():
@@ -675,7 +810,7 @@ def _on_tile(step, qi, ki, live, *, causal, block_q, block_k, n_k, l_real):
 def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
               dk_ref, dv_ref, dk_acc, dv_acc, ks_ref,
               qi, ki, first, last, live, *,
-              scale, causal, block_q, block_k, n_k, l_real):
+              scale, rule, block_q, block_k, n_k, l_real):
     """One (ki, qi) step of dK/dV. The scratch carries one key tile's
     (dk, dv) in float32 across its sweep over query tiles (``first`` /
     ``last`` of the sweep; ``live`` None, or whether this tile holds any
@@ -701,7 +836,7 @@ def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = lax.dot_general(ks_ref[...], q, _NT,
                              preferred_element_type=jnp.float32)
         if masked:
-            st = _mask_scores(st, qi, ki, keys_axis=0, causal=causal,
+            st = _mask_scores(st, qi, ki, keys_axis=0, rule=rule,
                               block_q=block_q, block_k=block_k,
                               l_real=l_real, pad_k=n_k * block_k - l_real)
         pt = jnp.exp(st - lse_ref[0])  # (block_k, block_q) - (1, block_q)
@@ -713,7 +848,7 @@ def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] += lax.dot_general(dst.astype(q.dtype), q, _NN,
                                        preferred_element_type=jnp.float32)
 
-    _on_tile(step, qi, ki, live, causal=causal, block_q=block_q,
+    _on_tile(step, qi, ki, live, rule=rule, block_q=block_q,
              block_k=block_k, n_k=n_k, l_real=l_real)
 
     @pl.when(last)
@@ -724,7 +859,7 @@ def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
              dq_ref, dq_acc, qs_ref, qi, ki, first, last, live, *,
-             scale, causal, block_q, block_k, n_k, l_real):
+             scale, rule, block_q, block_k, n_k, l_real):
     """One (qi, ki) step of dQ: the scratch carries one query tile's dq
     in float32 across its sweep over key tiles. ``s = (q * scale) k^T``
     as the forward computes it (``q * scale`` rounded once a query tile),
@@ -742,7 +877,7 @@ def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = lax.dot_general(qs_ref[...], k, _NT,
                             preferred_element_type=jnp.float32)
         if masked:
-            s = _mask_scores(s, qi, ki, keys_axis=1, causal=causal,
+            s = _mask_scores(s, qi, ki, keys_axis=1, rule=rule,
                              block_q=block_q, block_k=block_k,
                              l_real=l_real, pad_k=n_k * block_k - l_real)
         p = jnp.exp(s - lse_ref[0])  # (block_q, block_k) - (block_q, 1)
@@ -752,7 +887,7 @@ def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[...] += lax.dot_general(ds.astype(k.dtype), k, _NN,
                                        preferred_element_type=jnp.float32)
 
-    _on_tile(step, qi, ki, live, causal=causal, block_q=block_q,
+    _on_tile(step, qi, ki, live, rule=rule, block_q=block_q,
              block_k=block_k, n_k=n_k, l_real=l_real)
 
     @pl.when(last)
@@ -760,19 +895,23 @@ def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_kernel_rect(tile, swept_axis, n_swept, *refs, causal, block_q,
+def _bwd_kernel_rect(tile, swept_axis, n_swept, fold, *refs, rule, block_q,
                      block_k, **static):
     """Either backward kernel on the full rectangular grid, the swept
-    tile innermost: (BH, n_k, n_q) for dK/dV (``swept_axis`` 0: the
-    query tile is program id 2), (BH, n_q, n_k) for dQ. Full attention
-    always; the causal fallback when a compressed walk's index arrays
-    would be too large for scalar memory, where a tile that holds no
-    visible score still streams through VMEM but skips its products."""
+    tile innermost: (BH / group, n_k, group * n_q) for dK/dV
+    (``swept_axis`` 0: the query tile is program id 2, ``fold`` = n_q
+    where the sweep runs over a group's q heads in turn, else None),
+    (BH, n_q, n_k) for dQ. Full attention always; the fallback when a
+    compressed walk's index arrays would be too large for scalar memory,
+    where under ``CAUSAL`` a tile that holds no visible score still
+    streams through VMEM but skips its products."""
     kept, swept = pl.program_id(1), pl.program_id(2)
     qi, ki = (swept, kept) if swept_axis == 0 else (kept, swept)
-    live = (ki * block_k <= qi * block_q + block_q - 1) if causal else None
+    if fold is not None:
+        qi = lax.rem(qi, fold)
+    live = rule.live_tile(qi, ki, block_q, block_k)
     tile(*refs, qi, ki, swept == 0, swept == n_swept - 1, live,
-         causal=causal, block_q=block_q, block_k=block_k, **static)
+         rule=rule, block_q=block_q, block_k=block_k, **static)
 
 
 def _walk_group_bounds(group_ref, t, n_tiles):
@@ -791,35 +930,47 @@ def _walk_group_bounds(group_ref, t, n_tiles):
     return is_start, is_end
 
 
-def _bwd_kernel_walk(tile, q_slot, n_tiles, *refs, **static):
-    """Either backward kernel on a compressed causal walk: a 1-D grid
-    (BH, T) over ONLY the live tile pairs, decoded from the two scalar-
-    prefetched index arrays, the first of which groups the walk (the key
-    tile for dK/dV, whose query tile is prefetch array 1: ``q_slot``; the
-    query tile for dQ, ``q_slot`` 0). A tile that holds no visible score
-    is never visited, so its DMA never happens."""
+def _bwd_kernel_walk(tile, q_slot, n_tiles, fold, *refs, **static):
+    """Either backward kernel on a compressed walk: a 1-D grid
+    (BH, T) over ONLY the live tile pairs of the rule, decoded from the
+    two scalar-prefetched index arrays, the first of which groups the
+    walk (the key tile for dK/dV, whose query tile is prefetch array 1:
+    ``q_slot``; the query tile for dQ, ``q_slot`` 0). A tile that holds
+    no visible score is never visited, so its DMA never happens. Where
+    dK/dV sweeps a group's q heads in turn the walk's query entry is
+    ``g * n_q + qi`` and ``fold`` = n_q gives the tile back; else None."""
     t = pl.program_id(1)
     qi, ki = refs[q_slot][t], refs[1 - q_slot][t]
+    if fold is not None:
+        qi = lax.rem(qi, fold)
     first, last = _walk_group_bounds(refs[0], t, n_tiles)
-    tile(*refs[2:], qi, ki, first, last, None, causal=True, **static)
+    tile(*refs[2:], qi, ki, first, last, None, **static)
 
 
-def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
+def _flash_bwd_2d_pallas(res, do, *, rule, scale, block_q, block_k):
     """The backward as two ``pallas_call``s (dK/dV, then dQ), each named
     after its tiles (``flash_bwd_dkv_q512_k512``, ``flash_bwd_dq_...``:
     a trace says which ran, with which tiles, how often). P is recomputed
     tile by tile from the saved log-sum-exp, (L, L) is never
     materialised; ``delta = rowsum(dO * O)`` is computed once, in
-    float32, and both kernels read it. Under ``causal`` both walk only
+    float32, and both kernels read it. Under a rule both walk only
     the live tile pairs (the forward's compressed walk for dQ, the
-    transposed enumeration for dK/dV), with the rectangular grid as the
+    enumeration by key tile for dK/dV), with the rectangular grid as the
     fallback over the cap. A block the caller did not name (None) comes
     from the shape, for each kernel its own (``backward_blocks``).
     q and k ``d`` wide, v and dO ``dv`` wide, neither a multiple of 128
-    by need: a tile holds the whole width."""
+    by need: a tile holds the whole width.
+
+    With ``group`` q heads a k/v head (k and v (BH / group, L, .)): dQ
+    reads k/v head ``b // group``; dK/dV runs one sweep a k/v head over
+    the query tiles of all its q heads into ONE accumulator, q, dO and
+    the two statistics seen as (BH / group, group * length, .), which is
+    how they lie in memory: dk and dv leave at BH / group heads and no
+    copy of k, v, dk or dv a q head ever exists."""
     q, k, v, o, lse = res
     bh, l_real, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
     chosen = backward_blocks(l_real, d, q.dtype.itemsize, dv)
     # softmax-jacobian diagonal correction
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -847,13 +998,21 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
             # row block — see forward)
             stats = [pad(x[..., None], n_q * bq, 1) for x in (lse, delta)]
             stat_block, stat_index = (1, bq, 1), lambda b, i: (b, i, 0)
+        # dK/dV of grouped heads: the query side by k/v head, the q
+        # heads of a group one after the other along the length
+        fold = int(n_q) if dkv and group > 1 else None
+        if fold:
+            qp, dop = (x.reshape(bh // group, -1, x.shape[-1])
+                       for x in (qp, dop))
+            stats = [x.reshape(bh // group, 1, -1) for x in stats]
+        kv = _kv_head(1 if dkv else group)
         operands = (qp, kp, vp, dop, *stats)
         static = dict(scale=scale, block_q=bq, block_k=bk, n_k=n_k,
                       l_real=l_real)
         if dkv:
             tile = _dkv_tile
-            out_shape = [_sds((bh, n_k * bk, d), q.dtype, qp),
-                         _sds((bh, n_k * bk, dv), q.dtype, qp)]
+            out_shape = [_sds((bh // group, n_k * bk, d), q.dtype, qp),
+                         _sds((bh // group, n_k * bk, dv), q.dtype, qp)]
             out_blocks = [(1, bk, d), (1, bk, dv)]
             scratch = [pltpu.VMEM((bk, d), jnp.float32),
                        pltpu.VMEM((bk, dv), jnp.float32),
@@ -873,7 +1032,7 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
             which index means what, and a drifted copy would compile but
             misindex."""
             q3 = lambda *a: (a[0], q_index(*a), 0)
-            k3 = lambda *a: (a[0], k_index(*a), 0)
+            k3 = lambda *a: (kv(a[0]), k_index(*a), 0)
             stat = lambda *a: stat_index(a[0], q_index(*a))
             in_specs = [
                 pl.BlockSpec((1, bq, d), q3, memory_space=vmem),    # q
@@ -888,12 +1047,14 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
                          for block in out_blocks]
             return in_specs, out_specs
 
+        heads = bh // group if dkv else bh  # the grid's leading axis
+        sweeps = group if dkv else 1
         walk = None
-        if causal:
+        if rule != FULL:
             # (kis, qis) grouped by key tile for dK/dV, (qids, kids)
             # grouped by query tile for dQ
-            walk = (_causal_tiles_kv if dkv else _causal_tiles)(
-                int(n_q), int(n_k), bq, bk)
+            walk = _live_tiles(rule, int(n_q), int(n_k), bq, bk,
+                               by_key=dkv, group=sweeps)
             if len(walk[0]) > _MAX_CAUSAL_TILES:
                 walk = None
         if walk is not None:
@@ -903,9 +1064,9 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
                 lambda b, t, *refs: refs[1 - q_slot][t])
             outs = pl.pallas_call(
                 functools.partial(_bwd_kernel_walk, tile, q_slot,
-                                  len(walk[0]), **static),
+                                  len(walk[0]), fold, rule=rule, **static),
                 grid_spec=pltpu.PrefetchScalarGridSpec(
-                    num_scalar_prefetch=2, grid=(bh, len(walk[0])),
+                    num_scalar_prefetch=2, grid=(heads, len(walk[0])),
                     in_specs=in_specs, out_specs=out_specs,
                     scratch_shapes=scratch),
                 out_shape=out_shape, compiler_params=params,
@@ -919,8 +1080,9 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
             outs = pl.pallas_call(
                 functools.partial(
                     _bwd_kernel_rect, tile, 0 if dkv else 1,
-                    n_q if dkv else n_k, causal=causal, **static),
-                grid=(bh, n_k, n_q) if dkv else (bh, n_q, n_k),
+                    sweeps * n_q if dkv else n_k, fold, rule=rule, **static),
+                grid=((heads, n_k, sweeps * n_q) if dkv
+                      else (heads, n_q, n_k)),
                 in_specs=in_specs, out_specs=out_specs,
                 out_shape=out_shape, scratch_shapes=scratch,
                 compiler_params=params, interpret=_interpret(), name=name,
@@ -936,26 +1098,26 @@ def _flash_bwd_2d_pallas(res, do, *, causal, scale, block_q, block_k):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_2d(q, k, v, causal, scale, block_q, block_k, backward):
-    o, _ = _flash_fwd_2d(q, k, v, causal=causal, scale=scale,
+def _flash_2d(q, k, v, rule, scale, block_q, block_k, backward):
+    o, _ = _flash_fwd_2d(q, k, v, rule=rule, scale=scale,
                          block_q=block_q, block_k=block_k)
     return o
 
 
-def _flash_2d_fwd(q, k, v, causal, scale, block_q, block_k, backward):
-    o, lse = _flash_fwd_2d(q, k, v, causal=causal, scale=scale,
+def _flash_2d_fwd(q, k, v, rule, scale, block_q, block_k, backward):
+    o, lse = _flash_fwd_2d(q, k, v, rule=rule, scale=scale,
                            block_q=block_q, block_k=block_k)
     return o, (q, k, v, o, lse)
 
 
-def _flash_2d_bwd(causal, scale, block_q, block_k, backward, res, do):
+def _flash_2d_bwd(rule, scale, block_q, block_k, backward, res, do):
     # a block the caller did not name comes from the shape: each
     # backward kernel's own tiles, or the scan's key block
     if backward == "pallas":
-        return _flash_bwd_2d_pallas(res, do, causal=causal, scale=scale,
+        return _flash_bwd_2d_pallas(res, do, rule=rule, scale=scale,
                                     block_q=block_q, block_k=block_k)
     return _flash_bwd_2d(
-        res, do, causal=causal, scale=scale,
+        res, do, rule=rule, scale=scale,
         block_k=(backward_scan_block(res[0].shape[1]) if block_k is None
                  else block_k))
 
@@ -969,23 +1131,37 @@ def flash_attention(
     v: jax.Array,
     *,
     causal: bool = False,
+    block_diffusion_mask: Optional[tuple[int, int]] = None,
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     backward: str = "xla",
 ) -> jax.Array:
-    """Exact fused softmax attention: q and k ``(B, L, H, D)``, v
-    ``(B, L, H, Dv)`` → ``(B, L, H, Dv)``.
+    """Exact fused softmax attention: q ``(B, L, H, D)``, k ``(B, L,
+    H_kv, D)``, v ``(B, L, H_kv, Dv)`` → ``(B, L, H, Dv)``.
 
-    The shape rule: q and k are identical in shape; v shares their
-    batch, length and heads and may have a head width of its own (latent
-    attention: q and k carry a rotary part that v has not). Neither
-    width has to be a multiple of the 128 lanes: a tile holds the whole
-    width. Where ``Dv = D`` the kernel, its tiles and its name are what
-    they were before v had a width. Drop-in for
-    ``parallel.sequence._single_device_attention`` (same semantics,
-    tolerances at f32 rounding); differentiable via a blockwise custom
-    VJP, which takes the same widths.
+    The shape rule: q, k and v share batch and length; q and k share one
+    head width and v may have another (latent attention: q and k carry a
+    rotary part that v has not); k and v share ``H_kv`` heads, which
+    divides q's ``H`` (grouped-query attention: q head h reads k/v head
+    ``h // (H / H_kv)``; the kernels index it so, dK/dV sums a group's q
+    heads in its accumulator, and neither k, v nor their gradients are
+    ever repeated a q head). Neither width has to be a multiple of the
+    128 lanes: a tile holds the whole width. Where ``Dv = D`` and
+    ``H_kv = H`` the kernel, its tiles, its walk and its name are what
+    they were before v had a width and k heads of their own.
+
+    The masks (``Visibility``): none; ``causal``; or
+    ``block_diffusion_mask=(clean_len, block)`` over ``L = 2 *
+    clean_len`` positions laid out ``[clean ; noisy]`` (block-diffusion
+    training: a clean position sees the clean past block-causally, a
+    noisy one the clean blocks before its own and its own noisy block).
+    Each kernel walks only the tile pairs that hold a visible score and
+    builds a mask only on those the rule does not fill.
+
+    Drop-in for ``parallel.sequence._single_device_attention`` (same
+    semantics, tolerances at f32 rounding); differentiable via a
+    blockwise custom VJP (the kernels' takes the same shapes and masks).
     ``scale`` defaults to ``D**-0.5``, D the width of q and k.
     ``block_q`` / ``block_k``: a caller that names them gets them, in
     the forward and the backward; left out, the forward's come from the
@@ -993,7 +1169,9 @@ def flash_attention(
     (``backward_blocks``) and the backward scan's key block
     (``backward_scan_block``: 128 up to 4,095 tokens).
     ``backward`` selects the VJP implementation: ``"xla"`` (default —
-    blockwise lax.scan) or ``"pallas"`` (two fused kernels, dK/dV then
+    blockwise lax.scan over equal heads under no mask or the causal
+    one; it refuses grouped heads and the block-diffusion mask by name
+    when it is differentiated) or ``"pallas"`` (two fused kernels, dK/dV then
     dQ: what ``models.looped_lm.causal_attention`` names, 2.1 to 3.7
     times as fast as the scan at the benchmark cells' shapes; the
     module's docstring has the numbers).
@@ -1006,15 +1184,27 @@ def flash_attention(
     # the 2d lowering takes lengths/padding from q and reuses them for
     # k/v (no cross-attention support) — mismatches must fail here with
     # a clear message, not deep in a pallas lowering error
-    if k.shape != q.shape or v.shape[:3] != q.shape[:3] or v.ndim != 4:
+    if (k.ndim != 4 or v.ndim != 4 or k.shape[:2] != q.shape[:2]
+            or k.shape[3] != q.shape[3] or v.shape[:3] != k.shape[:3]
+            or q.shape[2] % k.shape[2]):
         raise ValueError(
-            "flash_attention requires q and k of identical (B, L, H, D) "
-            "shape and v of shape (B, L, H, Dv), got "
-            f"q={q.shape}, k={k.shape}, v={v.shape}"
+            "flash_attention requires q (B, L, H, D), k of identical "
+            "batch, length and width with H_kv heads that divide H, and "
+            f"v of shape (B, L, H_kv, Dv), got q={q.shape}, k={k.shape}, "
+            f"v={v.shape}"
         )
     b, l, h, d = q.shape
+    rule = CAUSAL if causal else FULL
+    if block_diffusion_mask is not None:
+        if causal:
+            raise ValueError("name causal or block_diffusion_mask, not both")
+        rule = block_diffusion(*block_diffusion_mask)
+        if l != 2 * rule.clean_len:
+            raise ValueError(
+                f"block_diffusion_mask: [clean ; noisy] of clean_len "
+                f"{rule.clean_len} is {2 * rule.clean_len} positions, got {l}")
     s = float(scale) if scale is not None else d ** -0.5
-    to2d = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, l, x.shape[-1])
-    o = _flash_2d(to2d(q), to2d(k), to2d(v), causal, s, block_q, block_k,
+    to2d = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, l, x.shape[-1])
+    o = _flash_2d(to2d(q), to2d(k), to2d(v), rule, s, block_q, block_k,
                   backward)
     return o.reshape(b, h, l, v.shape[-1]).transpose(0, 2, 1, 3)

@@ -1,18 +1,26 @@
-"""Expert parallelism: two mixtures of experts, each with its router.
+"""Expert parallelism: two mixtures of experts and their three routers.
 
 **Which router is which.** (1) *Switch* (``switch_route`` / ``dense_moe``
 / ``expert_parallel_moe``, below): top-1 softmax routing with a capacity
 that drops what overflows, dispatch and combine as one-hot einsums over
 (T, E, C), two-matrix ReLU experts, and the two ``all_to_all``s over an
-``expert`` mesh axis. (2) *Sigmoid top-k, dropless, the chip's share*
-(``sigmoid_topk_route`` / ``held_expert_moe``, at the end of the file):
-DeepSeek-V3-style routing (sigmoid scores, a selection bias that is no
-parameter, top-k, normalised and scaled weights) over ALL experts of a
+``expert`` mesh axis. (2) *Sigmoid top-k* (``sigmoid_topk_route``):
+DeepSeek-V3-style routing: sigmoid scores, a selection bias that is no
+parameter and is moved after each step (``update_selection_bias``),
+top-k of score + bias, weights normalised and scaled; no auxiliary loss.
+(3) *Softmax top-k* (``softmax_topk_route``): Qwen3-MoE-style routing: a
+softmax over ALL experts, the top k of it, the chosen probabilities
+renormalised to sum to one, no bias; balanced by an auxiliary loss
+(``load_balance_loss``: the Switch form over the k chosen pairs a token,
+the loads of the global batch against this replica's mean probabilities).
+
+(2) and (3) route for the same mixture, *dropless, the chip's share*
+(``held_expert_moe``, at the end of the file): over ALL experts of a
 layer that is told which of them this chip holds; SwiGLU experts as
 grouped products over the pairs that really arrive, no capacity, no
 drop, and no exchange: what the absent experts would add is left out.
-``models.moe_lm`` runs (2); the all-to-all of (1) has not met (2) yet
-(ROADMAP.md Queue 2).
+``models.moe_lm`` runs (2), ``models.block_diffusion_lm`` (3); the
+all-to-all of (1) has not met either yet (ROADMAP.md Queue 2).
 
 The first, Switch over an ``expert`` mesh axis:
 
@@ -199,6 +207,39 @@ def sigmoid_topk_route(
     _, idx = lax.top_k(s + lax.stop_gradient(bias), top_k)
     g = jnp.take_along_axis(s, idx, axis=-1)
     return idx, scale * g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+
+
+def softmax_topk_route(
+    x: jax.Array, router_w: jax.Array, *, top_k: int,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Softmax probabilities over all experts, the top k of them, their
+    weights renormalised.
+
+    ``x`` (T, H); ``router_w`` (H, E), E ALL the layer's experts.
+    Returns ``(idx, gates, probs)``: ``idx`` (T, k) int32, the k experts
+    with the largest probability (ties to the lower index); ``gates``
+    (T, k) float32, ``p[idx] / sum(p[idx])``; ``probs`` (T, E) float32,
+    what an auxiliary balance loss reads. Float32, the product at full
+    precision, as in ``sigmoid_topk_route``."""
+    p = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    g, idx = lax.top_k(p, top_k)
+    return idx, g / jnp.sum(g, axis=-1, keepdims=True), p
+
+
+def load_balance_loss(probs: jax.Array, load: jax.Array) -> jax.Array:
+    """``E * sum_e f_e P_e``: ``f_e`` the share of the chosen pairs that
+    fell on expert e, from ``load`` (.., E) (counts over whatever batch
+    the caller summed them over: the global one, if the replicas are to
+    be balanced together; no gradient passes through them), ``P_e`` the
+    mean router probability of expert e, ``probs`` (.., E) already meaned
+    over this replica's tokens (the gradient's way into the router). 1
+    where routing is even and the probabilities flat; E / k where every
+    token chooses the same k experts with all its probability."""
+    load = lax.stop_gradient(load)
+    share = load / jnp.maximum(jnp.sum(load, axis=-1, keepdims=True), 1.0)
+    return probs.shape[-1] * jnp.sum(share * probs, axis=-1)
 
 
 def expert_loads(idx: jax.Array, n_experts: int) -> jax.Array:
