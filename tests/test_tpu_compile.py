@@ -354,3 +354,51 @@ def test_block_diffusion_decoder_compiles_for_v5e(v5e, mosaic):
     assert "flash_bwd_dkv_q512_k512" in text
     assert "flash_bwd_dq_q512_k512" in text
     assert "ragged-dot" in text
+
+
+@pytest.mark.parametrize("n, strips", [(2, 4), (8, 1)])
+def test_bottlenecks_on_strips_keep_space_to_batch_away(v5e, n, strips):
+    """Two bottleneck blocks of RetinaNet's ``layer1`` at 800x1344
+    (``retinanet-train-b2``: 2 x 200 x 336 x 256 bf16), loss and
+    gradients in one program, the way ``ResNet.features`` runs a stage.
+    On whole images the batch of 2 makes the compiler convert every
+    convolution space-to-batch (the TPU's layout tiles batch x channels
+    by 8 x 128): 10 ``copy`` and 4 ``pad`` in the entry computation,
+    74.2 KB accessed a position. On 4 row strips an image the batch is 8:
+    1 ``copy``, no ``pad``, 35.8 KB: the guard that the conversion stays
+    away, without a chip. At 8 images the rule leaves the stage alone
+    (there the plain program is the 14-fusion one, 23.1 KB a position,
+    and every folded variant read worse)."""
+    import re
+
+    from flax import nnx
+
+    from tpu_syncbn.models import resnet
+    from tpu_syncbn.nn import BatchNorm2d
+
+    shape = (n, 200, 336, 256)
+    assert resnet.strip_count(n, shape[1], False) == strips
+    abstract = nnx.eval_shape(lambda: nnx.List([
+        resnet.Bottleneck(256, 64, 1, BatchNorm2d, nnx.Rngs(0),
+                          dtype=jnp.bfloat16) for _ in range(2)]))
+    graphdef, params, rest = nnx.split(abstract, nnx.Param, ...)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), t)
+
+    def loss(p, r, x):
+        stage = nnx.merge(graphdef, p, r, copy=True)
+        return resnet._run_stage(stage, x).astype(jnp.float32).mean()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 2))).lower(
+        on_chip(params), on_chip(rest),
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)).compile()
+    text = compiled.as_text()
+    assert ("halo" in text) == (strips > 1)
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    # (dimensions, operation) of the entry's copies and pads
+    ops = re.findall(r"= \w+\[([\d,]*)\]\S* (copy|pad)\(", entry)
+    assert sum(op == "copy" for _, op in ops) <= 2, ops
+    assert not [d for d, op in ops if op == "pad" and d.count(",") >= 3], ops
+    kb_a_position = (compiled.cost_analysis()["bytes accessed"]
+                     / (n * 200 * 336) / 1e3)
+    assert kb_a_position < 45, kb_a_position

@@ -11,6 +11,31 @@ in without touching the architecture.
 ``small_input=True`` selects the CIFAR stem (3×3/1 conv, no max-pool) used
 by the ResNet-18/CIFAR-10 capability config; default is the ImageNet stem
 (7×7/2 + 3×3/2 max-pool).
+
+**Row strips at a small device batch.** The TPU's convolution layout tiles
+batch x channels by 8 x 128, so under a batch of 8 the compiler converts
+every convolution space-to-batch (a piece of the width folded into the
+batch, a halo column padded on) and back, around each convolution of the
+backward pass: at 2 images of 800x1344 a device 24.0 ms of an 82.5 ms
+RetinaNet step were such ``copy`` and ``pad`` operations (PERF.md, PR 37; a
+device batch under 8 is the case the reference's recipe is for: detection
+at 2 images a chip). So ``ResNet.features`` folds it itself where the
+batch N it is called with is under 8: a stage runs on G row strips of
+each image laid along the batch axis, ``(N, H, W, C) -> (N*G, H/G, W, C)``,
+a reshape that moves nothing. A 1x1 convolution, BN (its sums run over N,
+H and W alike, so SyncBN's statistics are those of the same elements),
+ReLU and the residual add do not see the fold; a 3x3 convolution sees it
+in the one row above and below a strip, which the neighbouring strip
+holds (``_conv_on_strips``). The maps ``features`` returns are unfolded.
+``strip_count`` is the rule, from the static shape alone: the least G
+with N*G >= 8 that divides the stage's input height H, with H/G even
+where the stage's first block strides (so that "SAME" pads a strip as it
+pads the image); no such G, or N >= 8: the stage runs on whole images, as
+it always did (there every folded variant reads worse). Both block types
+go through it. Scopes ``strips`` (the fold into a stage and the unfold
+out of it: reshapes, which compile to nothing) and ``halo`` (the exchange,
+``layer<i>/block<b>/halo``) say in the lowered step and in a trace where it
+engaged and what it costs; every other path is what it is on whole images.
 """
 
 from __future__ import annotations
@@ -37,10 +62,54 @@ def _conv(cin, cout, kernel, stride, rngs, *, padding="SAME", dtype=None):
     )
 
 
+# the TPU's convolution layout tiles batch x channels by 8 x 128: a
+# batch under it is what the compiler converts space-to-batch
+_MIN_CONV_BATCH = 8
+
+
+def strip_count(n: int, h: int, strided: bool) -> int:
+    """Row strips an image that a stage entered by ``n`` images of
+    height ``h`` runs on (1: whole images); ``strided``: its first block
+    halves the height. The module docstring has the rule and why."""
+    if n >= _MIN_CONV_BATCH:
+        return 1
+    for g in range(-(-_MIN_CONV_BATCH // n), h + 1):
+        if h % g == 0 and not (strided and (h // g) % 2):
+            return g
+    return 1
+
+
+def _conv_on_strips(conv: nnx.Conv, x: jax.Array, strips: int) -> jax.Array:
+    """``conv`` (3x3, "SAME", stride 1 or 2, no bias) of images that lie
+    as ``strips`` row strips each, consecutive along the batch axis:
+    ``conv(x)`` but for each strip's edge rows, which read the
+    neighbouring strip's row where "SAME" would pad zeros. At stride 2
+    (an even strip height) "SAME" pads (0, 1): the row below alone."""
+    if strips == 1:
+        return conv(x)
+    with jax.named_scope("halo"):
+        strip = (jnp.arange(x.shape[0]) % strips)[:, None, None, None]
+        # the wrapped-around row of the roll is an image's edge: zeroed
+        rows = [x, jnp.where(strip == strips - 1, 0,
+                             jnp.roll(x[:, :1], -1, axis=0))]
+        if conv.strides[0] == 1:
+            rows.insert(0, jnp.where(strip == 0, 0,
+                                     jnp.roll(x[:, -1:], 1, axis=0)))
+        x = jnp.concatenate(rows, axis=1)
+    x, kernel = conv.promote_dtype((x, conv.kernel[...]), dtype=conv.dtype)
+    pad_w, = jax.lax.padtype_to_pads(
+        x.shape[2:3], conv.kernel_size[1:], conv.strides[1:], conv.padding)
+    return jax.lax.conv_general_dilated(
+        x, kernel, conv.strides, [(0, 0), pad_w],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=conv.precision,
+    )
+
+
 class BasicBlock(nnx.Module):
     expansion = 1
 
     def __init__(self, cin, planes, stride, norm, rngs, dtype=None):
+        self.stride = stride
         self.conv1 = _conv(cin, planes, 3, stride, rngs, dtype=dtype)
         self.bn1 = norm(planes)
         self.conv2 = _conv(planes, planes, 3, 1, rngs, dtype=dtype)
@@ -52,10 +121,12 @@ class BasicBlock(nnx.Module):
             self.down_conv = None
             self.down_bn = None
 
-    def __call__(self, x):
+    def __call__(self, x, strips: int = 1):
+        """``strips``: row strips an image that ``x`` lies as
+        (``ResNet.features``); 1 is whole images."""
         identity = x
-        out = nnx.relu(self.bn1(self.conv1(x)))
-        out = self.bn2(self.conv2(out))
+        out = nnx.relu(self.bn1(_conv_on_strips(self.conv1, x, strips)))
+        out = self.bn2(_conv_on_strips(self.conv2, out, strips))
         if self.down_conv is not None:
             identity = self.down_bn(self.down_conv(x))
         return nnx.relu(out + identity)
@@ -65,6 +136,7 @@ class Bottleneck(nnx.Module):
     expansion = 4
 
     def __init__(self, cin, planes, stride, norm, rngs, dtype=None):
+        self.stride = stride
         self.conv1 = _conv(cin, planes, 1, 1, rngs, dtype=dtype)
         self.bn1 = norm(planes)
         # torchvision places the stride on the 3x3 (resnet v1.5)
@@ -79,14 +151,35 @@ class Bottleneck(nnx.Module):
             self.down_conv = None
             self.down_bn = None
 
-    def __call__(self, x):
+    def __call__(self, x, strips: int = 1):
+        """``strips``: row strips an image that ``x`` lies as
+        (``ResNet.features``); 1 is whole images."""
         identity = x
         out = nnx.relu(self.bn1(self.conv1(x)))
-        out = nnx.relu(self.bn2(self.conv2(out)))
+        out = nnx.relu(self.bn2(_conv_on_strips(self.conv2, out, strips)))
         out = self.bn3(self.conv3(out))
         if self.down_conv is not None:
             identity = self.down_bn(self.down_conv(x))
         return nnx.relu(out + identity)
+
+
+def _run_stage(stage, x: jax.Array) -> jax.Array:
+    """The stage's blocks on ``x``: on row strips folded into the batch
+    where ``strip_count`` says so, the result unfolded. The scope
+    ``strips`` holds the fold and the unfold alone, so that a block's
+    path is ``layer<i>/block<b>/...`` folded or not."""
+    n, h = x.shape[:2]
+    strips = strip_count(n, h, stage[0].stride == 2)
+    if strips > 1:
+        with jax.named_scope("strips"):
+            x = x.reshape(n * strips, h // strips, *x.shape[2:])
+    for b, blk in enumerate(stage):
+        with jax.named_scope(f"block{b}"):
+            x = blk(x, strips)
+    if strips > 1:
+        with jax.named_scope("strips"):
+            x = x.reshape(n, -1, *x.shape[2:])
+    return x
 
 
 class ResNet(nnx.Module):
@@ -155,9 +248,7 @@ class ResNet(nnx.Module):
         feats = []
         for i, stage in enumerate(self.stages):
             with jax.named_scope(f"layer{i + 1}"):
-                for b, blk in enumerate(stage):
-                    with jax.named_scope(f"block{b}"):
-                        x = blk(x)
+                x = _run_stage(stage, x)
             feats.append(x)
         return feats
 
