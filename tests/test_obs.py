@@ -9,6 +9,7 @@ measurement substrate (docs/OBSERVABILITY.md), so its semantics are
 pinned directly.
 """
 
+import gc
 import json
 import threading
 import time
@@ -458,6 +459,91 @@ class TestCaptureSwitch:
 
     def test_off_path_is_the_shared_null_context(self, capture):
         assert tracing.span("x") is tracing.span("y", step=1)
+
+
+class TestCollectorPauses:
+    """The garbage collector's pauses are recorded from
+    ``watch_collector()`` on, tracer or not, on the spans' clock."""
+
+    def test_watching_twice_leaves_one_callback(self):
+        tracing.watch_collector()
+        tracing.watch_collector()
+        assert gc.callbacks.count(tracing._on_collection) == 1
+
+    def test_initialize_is_where_it_is_switched_on(self, monkeypatch):
+        from tpu_syncbn import runtime
+        from tpu_syncbn.runtime import distributed
+
+        monkeypatch.setattr(gc, "callbacks", [])
+        monkeypatch.setattr(distributed, "_initialized", False)
+        runtime.initialize()
+        assert gc.callbacks == [tracing._on_collection]
+
+    def test_a_full_collection_is_recorded_between_two_clock_reads(self):
+        tracing.watch_collector()
+        before = time.perf_counter()
+        gc.collect()
+        after = time.perf_counter()
+        t0, t1, generation, collected = tracing.collector_pauses()[-1]
+        assert generation == 2 and collected >= 0
+        assert before <= t0 <= t1 <= after
+        assert tracing.collector_pauses(since=after) == []
+        assert tracing.collector_pauses(since=before)[-1][0] == t0
+
+    def test_the_ring_is_bounded(self):
+        tracing.watch_collector()
+        assert tracing.PAUSE_CAPACITY == 8192
+        for _ in range(tracing.PAUSE_CAPACITY + 3):
+            gc.collect(0)
+        pauses = tracing.collector_pauses()
+        assert len(pauses) == tracing.PAUSE_CAPACITY
+        assert [p[0] for p in pauses] == sorted(p[0] for p in pauses)
+
+    def test_collections_switch_no_tracer_on(self, capture):
+        tracing.watch_collector()
+        gc.collect()
+        assert tracing.get() is None and tracing.last_capture() is None
+
+    def test_a_saved_trace_holds_them_on_a_track_of_their_own(
+            self, tmp_path):
+        tracing.watch_collector()
+        t = tracing.Tracer()
+        with t.span("train_step"):
+            gc.collect()
+        events = tracing.validate_trace(tracing.load_trace(
+            t.save(str(tmp_path / "trace.json"))))
+        step, = [e for e in events if e["name"] == "train_step"]
+        pauses = [e for e in events if e["name"] == "gc"]
+        full = [e for e in pauses if e["args"]["generation"] == 2]
+        assert len(full) == 1 and set(full[0]["args"]) == {"generation",
+                                                           "collected"}
+        # the collection stands over the span it stopped, on another track
+        assert step["ts"] <= full[0]["ts"]
+        assert full[0]["ts"] + full[0]["dur"] <= step["ts"] + step["dur"]
+        assert all(e["ph"] == "X" and e["cat"] == "tpu_syncbn"
+                   and e["tid"] == tracing.COLLECTOR_TID != step["tid"]
+                   for e in pauses)
+        track, = [e for e in events if e["ph"] == "M"
+                  and e["name"] == "thread_name"]
+        assert track["tid"] == tracing.COLLECTOR_TID
+        assert track["args"] == {"name": "collector"}
+        # the file alone: the span sites' record is as it was
+        assert [s[0] for s in t.spans()] == ["train_step"]
+        assert [e["name"] for e in t.recent_events()] == ["train_step"]
+        assert [e["name"] for e in t.events] == ["train_step"]
+
+    def test_a_pause_before_the_tracer_is_not_in_its_file(self, tmp_path):
+        tracing.watch_collector()
+        gc.collect()
+        before = len(tracing.collector_pauses())
+        t = tracing.RingTracer(8)
+        with t.span("h2d"):
+            pass
+        assert before and len(tracing.collector_pauses(t.t0)) == 0
+        events = tracing.load_trace(t.save(str(tmp_path / "trace.json")))
+        # no pause, no track
+        assert [e["name"] for e in events if e["ph"] != "M"
+                or e["name"] == "thread_name"] == ["h2d"]
 
 
 class TestLoaderSpans:

@@ -34,11 +34,22 @@ A span records its wall time and its thread's CPU time (``args.cpu_us``,
 from ``time.thread_time``): a span whose wall time is far above its CPU
 time was blocked (the GIL, a queue, the runtime); one whose two times
 agree was computing.
+
+One record is NOT behind that switch: the garbage collector's pauses
+(:func:`watch_collector`, which ``runtime.initialize()`` calls). A
+collection holds the interpreter lock, so no thread of the process runs
+Python while it lasts, a full one for a tenth of a second a few times
+in twenty: a span that records only while a capture of a second runs
+would almost never hold one. The hook costs two clock reads and a
+``deque.append`` a collection; :func:`collector_pauses` returns what it
+kept, on the spans' clock, and :meth:`Tracer.save` lays it into the file
+as a track of its own.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import sys
@@ -239,7 +250,12 @@ class Tracer:
         """Write the Chrome trace JSON object. Adds process metadata so
         Perfetto labels the track with the host index when the
         distributed runtime can answer (never initializes a backend to
-        ask)."""
+        ask), and the collector's pauses that began in this tracer's
+        lifetime as ``gc`` slices on a track named ``collector``
+        (:func:`collector_pauses`): a slice there stands over the spans
+        of every thread that the collection stopped. The file alone
+        holds them; :attr:`events`, :meth:`spans` and
+        :meth:`recent_events` are the span sites' record."""
         meta: list[dict] = []
         try:
             # only ask jax for the host index if a backend is ALREADY
@@ -261,9 +277,32 @@ class Tracer:
         os.makedirs(parent, exist_ok=True)
         with self._lock:
             events = meta + list(self.events)
+        events += self._collector_track()
         with open(path, "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms"}, f)
         return path
+
+    def _collector_track(self) -> list[dict]:
+        pauses = collector_pauses(self.t0)
+        if not pauses:
+            return []
+        pid = os.getpid()
+        track: list[dict] = [{
+            "name": "thread_name", "ph": "M", "pid": pid,
+            "tid": COLLECTOR_TID, "args": {"name": "collector"},
+        }]
+        for t0, t1, generation, collected in pauses:
+            track.append({
+                "name": "gc",
+                "ph": "X",
+                "ts": round((t0 - self.t0) * 1e6, 3),
+                "dur": round((t1 - t0) * 1e6, 3),
+                "pid": pid,
+                "tid": COLLECTOR_TID,
+                "cat": "tpu_syncbn",
+                "args": {"generation": generation, "collected": collected},
+            })
+        return track
 
 
 class RingTracer(Tracer):
@@ -389,20 +428,6 @@ def instant(name: str, **args) -> None:
         t.instant(name, **args)
 
 
-def flow_start(name: str, flow_id: int, **args) -> None:
-    """Flow-arrow start on the process tracer (no-op when off)."""
-    t = get()
-    if t is not None:
-        t.flow_start(name, flow_id, **args)
-
-
-def flow_end(name: str, flow_id: int, **args) -> None:
-    """Flow-arrow end on the process tracer (no-op when off)."""
-    t = get()
-    if t is not None:
-        t.flow_end(name, flow_id, **args)
-
-
 def current_span_id() -> int | None:
     t = get()
     return t.current_span_id() if t is not None else None
@@ -411,6 +436,53 @@ def current_span_id() -> int | None:
 def latest_open_span_id() -> int | None:
     t = get()
     return t.latest_open_span_id() if t is not None else None
+
+
+# ---------------------------------------------------------------------------
+# the collector's pauses: recorded from watch_collector() on, tracer or not
+
+
+#: pauses kept: about one collection a step (nearly all of generation 0),
+#: so more than a benchmark window of them and hours of the full ones
+PAUSE_CAPACITY = 8192
+#: the ``tid`` of the ``collector`` track in a saved file (no thread's:
+#: ``threading.get_ident()`` is an address)
+COLLECTOR_TID = 1
+_pauses: deque = deque(maxlen=PAUSE_CAPACITY)
+_pause_t0 = 0.0  # the clock at the ``start`` of the running collection
+
+
+def _on_collection(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` hook. Collections do not nest (the
+    interpreter holds a ``collecting`` flag), so one slot holds the
+    start; the tuple is all it makes."""
+    global _pause_t0
+    if phase == "start":
+        _pause_t0 = time.perf_counter()
+    else:
+        _pauses.append((_pause_t0, time.perf_counter(),
+                        info["generation"], info["collected"]))
+
+
+def watch_collector() -> None:
+    """Record every garbage collection of this process from now on
+    (idempotent). Not behind the capture switch: see the module's
+    docstring."""
+    with _install_lock:
+        if _on_collection not in gc.callbacks:
+            gc.callbacks.append(_on_collection)
+
+
+def collector_pauses(since: float = 0.0) -> list[tuple]:
+    """The recorded collections that began at or after ``since`` as
+    ``(t0_s, t1_s, generation, collected)``, oldest first, in absolute
+    ``time.perf_counter()`` seconds: the clock of :meth:`Tracer.spans`.
+    No thread of the process ran Python from ``t0_s`` to ``t1_s``.
+    The newest :data:`PAUSE_CAPACITY` are kept; empty until
+    :func:`watch_collector` has run."""
+    # one C call copies the ring: the hook cannot append in the middle of
+    # it (a collection starts between two bytecodes), and takes no lock
+    return [p for p in tuple(_pauses) if p[0] >= since]
 
 
 # ---------------------------------------------------------------------------

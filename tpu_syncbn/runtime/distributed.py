@@ -33,6 +33,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from tpu_syncbn.obs import tracing
+
 _loggers: dict[str, logging.Logger] = {}
 _initialized: bool = False
 _jax_distributed_active: bool = False
@@ -131,8 +133,9 @@ def initialize(
 
     Single-host (including the 1-chip and forced-host-device test cases):
     turns on the persistent compilation cache
-    (:func:`enable_persistent_compilation_cache`) and marks the runtime
-    initialized — JAX already sees all local devices.
+    (:func:`enable_persistent_compilation_cache`), starts the record of
+    the garbage collector's pauses (``obs.tracing.watch_collector``) and
+    marks the runtime initialized — JAX already sees all local devices.
 
     Multi-host: calls ``jax.distributed.initialize``, which performs the
     rendezvous the reference does through ``env://`` + TCPStore
@@ -155,6 +158,7 @@ def initialize(
     if _initialized:
         return
     enable_persistent_compilation_cache()
+    tracing.watch_collector()
     if config is None:
         config = DistributedConfig.from_env()
 
