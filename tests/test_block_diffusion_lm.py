@@ -28,6 +28,7 @@ if ROOT not in sys.path:
 from chipbench import reference_block_diffusion_lm as reference  # noqa: E402
 from tpu_syncbn import parallel, runtime  # noqa: E402
 from tpu_syncbn.data.transforms import BlockDiffusionNoise  # noqa: E402
+from tpu_syncbn.models import block_diffusion_lm  # noqa: E402
 from tpu_syncbn.models.block_diffusion_lm import BlockDiffusionMoELM  # noqa: E402
 from tpu_syncbn.obs import tracing  # noqa: E402
 
@@ -284,11 +285,10 @@ def test_k_and_v_reach_and_leave_every_kernel_at_their_own_heads():
         graphdef, p, rest, copy=True).loss(x0, xt, w)[0]))(params)
     calls = pallas_calls(jaxpr.jaxpr)
     names = [c.params["name"] for c in calls]
-    # a layer's forward, its recomputation, dK/dV and dQ (one scan body)
-    assert sorted(set(names)) == ["flash_bwd_dkv_q128_k128",
-                                  "flash_bwd_dq_q128_k128",
-                                  "flash_fwd_q128_k128"]
-    assert names.count("flash_fwd_q128_k128") == 2
+    # a layer's forward (once: the layer's checkpoint keeps its output
+    # and log-sum-exp), dK/dV and dQ, each in one scan body
+    assert sorted(names) == ["flash_bwd_dkv_q128_k128",
+                             "flash_bwd_dq_q128_k128", "flash_fwd_q128_k128"]
     heads = lambda v: v.aval.shape[0]
     for call, name in zip(calls, names):
         q, k, v = call.invars[2:5]
@@ -360,3 +360,61 @@ def test_the_noising_opens_a_noise_span_with_the_share_it_masked():
     assert event["args"]["masked"] == float(np.mean(xt == 99))
     assert event["args"]["parent_id"] == build["args"]["span_id"]
     assert span[0] == "noise" and span[2] >= span[1]
+
+
+# -- what the layer's checkpoint keeps ------------------------------------------
+
+
+def loss_and_grads(model, x0, xt, w):
+    graphdef, params, rest = nnx.split(model, nnx.Param, ...)
+    return jax.value_and_grad(lambda p: nnx.merge(
+        graphdef, p, rest, copy=True).loss(x0, xt, w)[0])(params)
+
+
+def keeps_the_kernels_output_one_row_and_the_projection(monkeypatch):
+    """A layer application under the model's own checkpoint: beside its
+    arguments the backward pass keeps exactly the kernel's output (BH,
+    2L, head width), its log-sum-exp as a (BH, 2L) float32 row and the
+    output projection's result (B, 2L, H): no (BH, 2L, 1) column,
+    nothing as wide as an expert, the router or the vocabulary. With
+    nothing saved the step runs the forward kernel once more a layer
+    application."""
+    from tests.test_looped_lm import computed_residuals
+
+    model, (x0, xt, w) = make(attn_impl="flash"), batch()
+    x = model.embed_tokens(jnp.concatenate([x0, xt], axis=1))
+    p = jax.tree_util.tree_map(lambda a: a[0], model.layers.stacked())
+    layer = block_diffusion_lm.checkpointed(model._layer,
+                                            block_diffusion_lm._saved())
+    kept = computed_residuals(layer, x, p, *model._angles(2 * L))
+    rows = x0.shape[0] * SIZES["num_heads"]
+    assert sorted(kept) == sorted([
+        (rows, 2 * L), (rows, 2 * L, SIZES["head_dim"]), x.shape])
+    calls = lambda: str(jax.make_jaxpr(lambda m: loss_and_grads(
+        m, x0, xt, w))(make(attn_impl="flash"))).count("name=flash_fwd")
+    ours = calls()  # the scan's body holds its call once
+    monkeypatch.setattr(block_diffusion_lm, "_saved", lambda: ())
+    assert calls() == 2 * ours == 2
+
+
+def equals_the_plain_checkpoint_bit_for_bit(monkeypatch):
+    """In bfloat16, the type the chip runs: the saved output and
+    log-sum-exp are the values the recomputation would give, so loss and
+    every gradient are those of a plain ``jax.checkpoint(fn)`` around the
+    same layer."""
+    model = lambda: make(attn_impl="flash", dtype=jnp.bfloat16)
+    loss, grads = loss_and_grads(model(), *batch())
+    monkeypatch.setattr(block_diffusion_lm, "checkpointed",
+                        lambda fn, saved=None: jax.checkpoint(fn))
+    want_loss, want = loss_and_grads(model(), *batch())
+    assert float(loss) == float(want_loss)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, grads, want)
+
+
+@pytest.mark.parametrize(
+    "check", [keeps_the_kernels_output_one_row_and_the_projection,
+              equals_the_plain_checkpoint_bit_for_bit],
+    ids=lambda c: c.__name__)
+def test_the_layers_checkpoint_keeps_the_attention_kernels_results(
+        check, monkeypatch):
+    check(monkeypatch)

@@ -285,7 +285,9 @@ def test_each_kernels_grid_is_the_enumerations_count_at_the_cells_shape():
 
 # sha256 of str(jax.make_jaxpr(grad(flash_attention(causal=True,
 # backward="pallas")))) as the PARENT of PR 36 traced it (jax 0.9.0,
-# kernels not interpreted), at Ouro's call (2 x 2,048 tokens, 16 heads of
+# kernels not interpreted; since PR 39 with the forward rule's two
+# ``name`` equations taken out, ``_named_residuals``, which is all that
+# PR added to such a call), at Ouro's call (2 x 2,048 tokens, 16 heads of
 # 128), JoyAI's (8,192 tokens, 32 heads of 192 / 128) and a ragged one:
 # the walk, the tiles, the index maps, the kernels' names and bodies. A
 # jax that prints jaxprs otherwise needs them taken again from that
@@ -304,6 +306,7 @@ def test_causal_with_equal_heads_is_the_program_it_was(case, monkeypatch):
     """Bit-equal by construction: not the outputs of two runs compared,
     the program itself."""
     monkeypatch.setattr(pa, "_interpret", lambda: False)
+    monkeypatch.setattr(pa, "_named_residuals", lambda o, lse, _: (o, lse))
     _, (b, l, h, d, dv), dtype = case
     q = jax.ShapeDtypeStruct((b, l, h, d), dtype)
     v = jax.ShapeDtypeStruct((b, l, h, dv), dtype)
@@ -311,3 +314,42 @@ def test_causal_with_equal_heads_is_the_program_it_was(case, monkeypatch):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == TODAYS[case], (
         f"the causal program changed (jax {jax.__version__}; pinned under "
         "0.9.0)")
+
+
+KEPT = (pa.FLASH_OUT, pa.FLASH_LSE)
+
+
+@pytest.mark.parametrize("checkpoint,kept,kernel_calls", [
+    (None, (), 3), (jax.checkpoint, (), 4),
+    (lambda f: jax.checkpoint(f, policy=jax.checkpoint_policies
+                              .save_only_these_names(*KEPT)), KEPT, 3)],
+    ids=["no-checkpoint", "plain-checkpoint", "the-two-names-kept"])
+def test_the_named_residuals_are_what_a_checkpoint_may_keep(
+        checkpoint, kept, kernel_calls, monkeypatch):
+    """The forward rule names the kernel's output and log-sum-exp. With
+    no policy a name is the identity: ``jax.grad`` through
+    ``flash_attention`` gives the values it gave without them and runs
+    the three kernels. Under a plain ``jax.checkpoint`` the backward
+    pass runs the forward kernel again; under one that keeps the two
+    names, and says so, it does not: what it keeps beside the arguments
+    is the output and a (BH, L) float32 row, and only there does the
+    pair pass a barrier. Bit for bit the same gradients in all three."""
+    from tests.test_looped_lm import computed_residuals
+
+    q, k, v = make(96, 4, 2, d=16, dv=8, dtype=jnp.bfloat16)
+    attend = lambda q, k, v: pa.flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=32, backward="pallas",
+        kept=kept)
+    wrapped = checkpoint(attend) if checkpoint else attend
+    got = gradients(wrapped, q, k, v)
+    traced = str(jax.make_jaxpr(lambda *a: gradients(wrapped, *a))(q, k, v))
+    assert traced.count("pallas_call[") == kernel_calls
+    if kept:
+        assert sorted(computed_residuals(wrapped, q, k, v)) == [
+            (2 * 4, 96), (2 * 4, 96, 8)]
+    else:
+        assert "optimization_barrier" not in str(jax.make_jaxpr(
+            lambda *a: gradients(attend, *a))(q, k, v))
+    monkeypatch.setattr(pa, "_named_residuals", lambda o, lse, _: (o, lse))
+    for g, want in zip(got, gradients(attend, q, k, v)):
+        np.testing.assert_array_equal(g, want)

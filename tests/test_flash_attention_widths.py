@@ -167,7 +167,9 @@ def test_full_attention_and_a_custom_scale_take_the_widths_too():
 # pass, file paths taken out) of a call with three equal widths, as the
 # kernel file of commit 0c2bf51 (before v had a width of its own) wrote
 # it under jax 0.9.0: (shape, dtype, causal) -> digest. The first is the
-# accepted cell's call (ouro-l8-train-b2x2048).
+# accepted cell's call (ouro-l8-train-b2x2048). Since PR 39 the forward
+# rule names its two residuals (``_named_residuals``); the digest is of
+# the program without those two equations, which is what it was.
 PROGRAM_AS_IT_WAS = {
     ((2, 2048, 16, 128), "bfloat16", True):
         "64b5078c4478b61dfe9d8b150cf0874c158789654568dc7436ef474982ca3041",
@@ -180,7 +182,7 @@ PROGRAM_AS_IT_WAS = {
 
 @pytest.mark.parametrize("call", sorted(PROGRAM_AS_IT_WAS, key=str),
                          ids=lambda c: "B{}L{}h{}d{}".format(*c[0]))
-def test_equal_widths_write_the_program_they_wrote(call):
+def test_equal_widths_write_the_program_they_wrote(call, monkeypatch):
     """Where the widths are equal the call is bit-equal to what it was,
     because it is the same program: kernel, tiles, grid, name, backward
     scan, operation for operation (the text is a golden of jax 0.9.0,
@@ -192,6 +194,7 @@ def test_equal_widths_write_the_program_they_wrote(call):
     if jax.__version__ != "0.9.0":
         pytest.skip("the program text is pinned under jax 0.9.0")
     shape, dtype, causal = call
+    monkeypatch.setattr(pa, "_named_residuals", lambda o, lse, _: (o, lse))
     q = jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
     text = str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
         lambda *a: pa.flash_attention(*a, causal=causal), q, k, v)[1](q))(

@@ -25,6 +25,7 @@ if ROOT not in sys.path:
 
 from chipbench import reference_moe_lm as ref  # noqa: E402
 from tpu_syncbn import parallel, runtime  # noqa: E402
+from tpu_syncbn.models import moe_lm  # noqa: E402
 from tpu_syncbn.models.moe_lm import LatentMoEDecoderLM  # noqa: E402
 from tpu_syncbn.parallel import expert  # noqa: E402
 
@@ -329,3 +330,65 @@ def test_the_check_counts_a_pair_the_walk_does_not_reach(monkeypatch):
     short, missed = expert.held_expert_moe(*args, first_expert=4, chunk=50)
     assert float(none_missed) == 0.0 and float(missed) == 120 - 2 * 50
     assert rel(short, whole) > 1e-2
+
+
+# -- what the layer's checkpoint keeps ------------------------------------------
+
+
+def flash_model(**over):
+    return LatentMoEDecoderLM(**{**SIZES, "attn_impl": "flash", **over},
+                              rngs=nnx.Rngs(7))
+
+
+def loss_and_grads(model, batch):
+    graphdef, params, rest = nnx.split(model, nnx.Param, ...)
+    return jax.value_and_grad(lambda p: nnx.merge(
+        graphdef, p, rest, copy=True).loss(*batch)[0])(params)
+
+
+def keeps_the_kernels_output_and_one_row(batch, monkeypatch):
+    """A layer application of each kind under the model's own
+    checkpoint: beside its arguments the backward pass keeps exactly
+    the kernel's output (BH, L, v width) and its log-sum-exp as a
+    (BH, L) float32 row: no (BH, L, 1) column, nothing as wide as an
+    expert, the router or the vocabulary. With nothing saved the step
+    runs the forward kernel once more a layer application."""
+    from tests.test_looped_lm import computed_residuals
+
+    model = flash_model()
+    x = model.embed_tokens(batch[0])
+    b, s = batch[0].shape
+    heads, width = SIZES["num_heads"], SIZES["v_dim"]
+    for block in (model.dense, model.sparse):
+        p = jax.tree_util.tree_map(lambda a: a[0], block.stacked())
+        bias = block.bias[...][0] if block.moe else None
+        layer = moe_lm.checkpointed(model._layer, moe_lm._saved())
+        kept = computed_residuals(layer, x, p, bias, *model._angles(s))
+        assert sorted(kept) == [(b * heads, s), (b * heads, s, width)]
+    calls = lambda: str(jax.make_jaxpr(lambda m: loss_and_grads(m, batch))(
+        flash_model())).count("name=flash_fwd")
+    ours = calls()  # each of the three scans' bodies holds its call once
+    monkeypatch.setattr(moe_lm, "_saved", lambda: ())
+    assert calls() == 2 * ours == 6
+
+
+def equals_the_plain_checkpoint_bit_for_bit(batch, monkeypatch):
+    """In bfloat16, the type the chip runs: the saved output and
+    log-sum-exp are the values the recomputation would give, so loss and
+    every gradient are those of a plain ``jax.checkpoint(fn)`` around the
+    same layer."""
+    model = lambda: flash_model(dtype=jnp.bfloat16)
+    loss, grads = loss_and_grads(model(), batch)
+    monkeypatch.setattr(moe_lm, "checkpointed",
+                        lambda fn, saved=None: jax.checkpoint(fn))
+    want_loss, want = loss_and_grads(model(), batch)
+    assert float(loss) == float(want_loss)
+    jax.tree_util.tree_map(np.testing.assert_array_equal, grads, want)
+
+
+@pytest.mark.parametrize("check", [keeps_the_kernels_output_and_one_row,
+                                   equals_the_plain_checkpoint_bit_for_bit],
+                         ids=lambda c: c.__name__)
+def test_the_layers_checkpoint_keeps_the_attention_kernels_results(
+        check, batch, monkeypatch):
+    check(batch, monkeypatch)

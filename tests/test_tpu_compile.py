@@ -15,7 +15,9 @@ All of it stays in this one process: libtpu takes a lock file, and a
 second process describing a topology at the same time aborts.
 """
 
+import collections
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
@@ -66,6 +68,17 @@ def _compile_fwd_and_grad(loss, *args):
         jax.value_and_grad(loss, argnums=(0, 1, 2))
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _flash_calls(compiled) -> collections.Counter:
+    """The compiled program's ``tpu_custom_call`` instructions of the
+    attention kernels, by kernel: an instruction carries its kernel's
+    ``name`` and a number (``flash_fwd_q512_k512.47``). A loop's body is
+    counted once."""
+    return collections.Counter(re.findall(
+        r'^\s*(?:ROOT )?%?(flash_[\w-]+?)(?:\.\d+)? = [^\n]*custom-call\('
+        r'[^\n]*custom_call_target="tpu_custom_call"', compiled.as_text(),
+        re.M))
 
 
 def _bn_loss(x, w, b):
@@ -161,12 +174,17 @@ def test_flash_attention_chosen_blocks_compile_for_v5e(v5e, mosaic, call,
     _compile_fwd_and_grad(loss, q, q, q)
 
 
-def test_looped_decoder_compiles_for_v5e(v5e, mosaic):
+def test_looped_decoder_compiles_for_v5e(v5e, mosaic, monkeypatch):
     """The looped decoder at Ouro-2.6B's published widths (hidden 2048,
     16 heads of 128, SwiGLU 5632), 2 sequences of 2,048 tokens, through
     the flash kernel with per-layer recomputation: loss and gradients in
     one program. Cut where the compile time is: 2 layers, 2 passes and an
-    eighth of the vocabulary; the benchmark's cell runs 8, 4 and all."""
+    eighth of the vocabulary; the benchmark's cell runs 8, 4 and all.
+
+    Its checkpoint keeps none of the kernel's residuals, so the names
+    the kernel's forward rule gives them lower to nothing: the compiled
+    step has the temporaries and the bytes accessed it has without them,
+    to the byte."""
     from flax import nnx
 
     from tpu_syncbn.models.looped_lm import LoopedDecoderLM
@@ -184,14 +202,22 @@ def test_looped_decoder_compiles_for_v5e(v5e, mosaic):
     def loss(p, tokens, targets):
         return nnx.merge(graphdef, p).loss(tokens, targets)[0]
 
-    compiled = jax.jit(jax.value_and_grad(loss)).lower(
-        on_chip(params), tokens, tokens).compile()
+    def compiled():
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            on_chip(params), tokens, tokens).compile()
+
     # the forward kernel and its recomputation, and the two backward
     # kernels at the tiles the cell's 2,048 tokens choose
-    text = compiled.as_text()
-    assert text.count("flash_fwd_q512_k512") >= 2
-    assert "flash_bwd_dkv_q512_k512" in text
-    assert "flash_bwd_dq_q512_k512" in text
+    ours = compiled()
+    assert _flash_calls(ours) == {
+        "flash_fwd_q512_k512": 2, "flash_bwd_dkv_q512_k512": 1,
+        "flash_bwd_dq_q512_k512": 1}
+    monkeypatch.setattr(pa, "_named_residuals", lambda o, lse, _: (o, lse))
+    unnamed = compiled()
+    assert (ours.memory_analysis().temp_size_in_bytes
+            == unnamed.memory_analysis().temp_size_in_bytes)
+    assert (ours.cost_analysis()["bytes accessed"]
+            == unnamed.cost_analysis()["bytes accessed"])
 
 
 @pytest.mark.parametrize("backward", ["xla", "pallas"])
@@ -241,7 +267,7 @@ def test_backward_kernels_chosen_blocks_compile_for_v5e(v5e, mosaic, call,
         assert f"flash_bwd_{kernel}_q{bq}_k{bk}" in compiled.as_text()
 
 
-def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
+def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic, monkeypatch):
     """The latent-attention mixture-of-experts decoder at
     JoyAI-LLM-Flash's published widths (hidden 2048, 32 heads of 192 /
     128, ranks 1536 and 512, experts 768 wide, a 256-way router with 16
@@ -250,12 +276,19 @@ def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
     loss and gradients in one program. Cut where the compile time is:
     one dense and one expert layer, the prediction module, a sixteenth of
     the slice of the vocabulary; the benchmark's cell runs 1 + 4, 8,192
-    tokens and 16,160 ids."""
+    tokens and 16,160 ids.
+
+    The layer's checkpoint keeps the kernel's output and log-sum-exp, so
+    the program holds the forward kernel once a layer application, not
+    twice, and what it holds for that is three outputs of 33.5 MB and
+    three (BH, L) rows of 0.5 MB: were the log-sum-exp kept as the
+    kernel writes it, (BH, T, 1) padded to 128 lanes, it would be 67 MB
+    a call and the step 316 MB larger and not 118."""
     from flax import nnx
 
-    from tpu_syncbn.models.moe_lm import LatentMoEDecoderLM
+    from tpu_syncbn.models import moe_lm
 
-    abstract = nnx.eval_shape(lambda: LatentMoEDecoderLM(
+    abstract = nnx.eval_shape(lambda: moe_lm.LatentMoEDecoderLM(
         vocab_size=1024, hidden_size=2048, num_heads=32, q_lora_rank=1536,
         kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_dim=128,
         dense_layers=1, dense_intermediate=7168, moe_layers=1, n_experts=256,
@@ -273,16 +306,25 @@ def test_latent_moe_decoder_compiles_for_v5e(v5e, mosaic):
         return nnx.merge(graphdef, p, r, copy=True).loss(
             tokens, targets, targets2)[0]
 
-    text = jax.jit(jax.value_and_grad(loss)).lower(
-        on_chip(params), on_chip(rest), tokens, tokens, tokens
-    ).compile().as_text()
-    # the attention kernel of three layer applications, forward and
-    # recomputed, and their backward kernels; the grouped products are
-    # the compiler's own kernels
-    assert text.count("flash_fwd_q512_k512") >= 6
-    assert text.count("flash_bwd_dkv_q512_k512") >= 3
-    assert text.count("flash_bwd_dq_q512_k512") >= 3
-    assert "ragged-dot" in text
+    def compiled():
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            on_chip(params), on_chip(rest), tokens, tokens, tokens).compile()
+
+    # the attention kernel of three layer applications and their backward
+    # kernels; the grouped products are the compiler's own kernels
+    ours = compiled()
+    assert _flash_calls(ours) == {
+        "flash_fwd_q512_k512": 3, "flash_bwd_dkv_q512_k512": 3,
+        "flash_bwd_dq_q512_k512": 3}
+    assert "ragged-dot" in ours.as_text()
+    # against the program with nothing named and nothing kept
+    monkeypatch.setattr(moe_lm, "_saved", lambda: ())
+    monkeypatch.setattr(pa, "_named_residuals", lambda o, lse, _: (o, lse))
+    plain = compiled()
+    assert _flash_calls(plain)["flash_fwd_q512_k512"] == 6
+    grown = (ours.memory_analysis().temp_size_in_bytes
+             - plain.memory_analysis().temp_size_in_bytes)
+    assert 90e6 < grown < 130e6, grown
 
 
 @pytest.mark.parametrize("call", [(1, 8192, 32, 4, 128, (4096, 4)),
@@ -345,15 +387,16 @@ def test_block_diffusion_decoder_compiles_for_v5e(v5e, mosaic):
         # copy=True: the loads are counted on variables of this trace
         return nnx.merge(graphdef, p, r, copy=True).loss(x0, xt, w)[0]
 
-    text = jax.jit(jax.value_and_grad(loss)).lower(
-        on_chip(params), on_chip(rest), tokens, tokens, weights
-    ).compile().as_text()
-    # a layer's forward kernel call and its recomputation, its two
-    # backward kernels; the grouped products are the compiler's own
-    assert text.count("flash_fwd_q512_k512") >= 2
-    assert "flash_bwd_dkv_q512_k512" in text
-    assert "flash_bwd_dq_q512_k512" in text
-    assert "ragged-dot" in text
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(
+        on_chip(params), on_chip(rest), tokens, tokens, weights).compile()
+    # a layer's forward kernel call (no second one: the layer's
+    # checkpoint keeps its output and log-sum-exp) and its two backward
+    # kernels, once in each loop's body; the grouped products are the
+    # compiler's own
+    assert _flash_calls(compiled) == {
+        "flash_fwd_q512_k512": 1, "flash_bwd_dkv_q512_k512": 1,
+        "flash_bwd_dq_q512_k512": 1}
+    assert "ragged-dot" in compiled.as_text()
 
 
 @pytest.mark.parametrize("n, strips", [(2, 4), (8, 1)])

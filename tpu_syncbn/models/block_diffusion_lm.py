@@ -52,7 +52,58 @@ router, softmax, SiLU, logits and losses in float32:
 
 The layers are stacked on a leading axis and applied by ``lax.scan``;
 with ``remat`` a layer application and the head are
-``jax.checkpoint``ed.
+``jax.checkpoint``ed. Of a layer application the backward pass keeps
+its input and what ``_saved()`` names (``save_only_these_names``): the
+attention kernel's output and log-sum-exp
+(``ops.pallas_attention.FLASH_OUT``, ``FLASH_LSE``), as in ``moe_lm``
+and for the same reason: without them it ran the forward kernel a
+second time, 3.46 ms a layer at 8,192 positions; and the output
+projection's result ``attn_proj``, which with the kernel's output kept
+is one product a layer that need not be made again. Under
+``attn_impl="xla"`` the kernel's names never appear.
+
+What the layer's checkpoint keeps, measured on a TPU v5e at the
+benchmark's cell (``sdar-l6-train-b1x4096``: six layers in one scan,
+4,096 tokens and their noisy copy, 646M parameters under AdamW; one
+traced run each on one seed, PERF.md section 6, PR 39; ms a step,
+``mixture`` the time under ``moe``, bytes at the peak of 16.91e9):
+
+=======================================  ======  =========  =======  ========
+kept beside the layer's input            step    recompute  mixture  bytes
+=======================================  ======  =========  =======  ========
+nothing (until PR 39)                    276.64  46.96      45.93    14.213e9
+the kernel's two                         254.82  25.57      38.45    14.199e9
++ ``attn_proj``: ``_saved()``            251.43  21.08      41.25    14.282e9
+the kernel's two + ``q``                 265.79  17.46      49.06    14.600e9
+the kernel's two + ``q``, ``k``, ``v``   266.44  16.69      46.42    14.701e9
+q, k behind a barrier: ``_saved()``      253.38  21.72      41.17    14.282e9
+the same + ``q``, ``k``                  246.07  12.83      40.19    14.734e9
+=======================================  ======  =========  =======  ========
+
+The mixture's time is the step's routing (it differs between two
+programs on one seed once their roundings differ; one program on one
+seed repeats to 0.005 ms), so read the step less the mixture: 230.71,
+216.37, 210.18, 216.73, 220.02, 212.21, 205.88. The kernel's two names
+take 14.3 ms off the step: the recomputed kernel calls' 20.77 less 2.1
+for the copy that makes the dQ kernel's column from the kept row and
+some 3 ms of copies of the saved stacks through the scan; the three
+kernels' 18 calls that
+are left take what they took (20.75 + 29.67 + 24.87). ``attn_proj``
+(six stacks of 33.5 MB, 83 MB at the peak) takes the output
+projection's recomputed product off, 4.9 ms of ``gqa``'s 20.1
+recomputed, 6.2 ms of the step. ``q`` beside the two takes 8.1 ms off
+the recomputation and costs 12.2 in the forward pass (no barrier stands
+between the rotation and its readers here, so a saved q is computed
+once more: what ``looped_lm`` found before it had its barrier) and
+400 MB: not kept; ``k`` and ``v`` beside it, 4 heads each, make it
+worse. The last two rows have q and k pass one
+``lax.optimization_barrier`` before they are named, as ``looped_lm``
+has it (less the mixture 212.21 and 205.88): the barrier alone costs
+2.0 ms, and with it ``q`` and ``k`` saved take 4.3 ms off the step that
+``_saved()`` gives for 452 MB. That pays by the rule and is left for
+the next change to this list: it was read after this list's last runs
+on the chip. In ``moe_lm``'s cell ``attn_proj`` does not pay (its
+table).
 
 Scopes (``jax.named_scope``, docs/OBSERVABILITY.md): ``gqa`` around the
 attention block with ``attention`` around its core inside it; ``moe``
@@ -76,12 +127,20 @@ from tpu_syncbn.models.moe_lm import (
     RECENT_STEPS, ExpertLoad, MoEDecoderBase, held_chunk)
 from tpu_syncbn.parallel import expert
 
-# The outputs of a layer application that its ``jax.checkpoint`` keeps
-# for the backward pass, of those the layer names (``q``, ``k``, ``v``,
-# ``attn_proj``, ``ffn_out``): none, as in ``moe_lm``.
-_SAVED = ()
 _MATRICES = ("wq", "wk", "wv", "wo", "router", "eg", "eu", "ed")
 _NORMS = ("norm1", "norm2", "q_norm", "k_norm")
+
+
+def _saved() -> tuple[str, ...]:
+    """What a layer application's ``jax.checkpoint`` keeps for the
+    backward pass beside its input: the attention kernel's output and
+    log-sum-exp under the kernel file's names, as in ``moe_lm``, and the
+    output projection's result ``attn_proj``. Of the other names the
+    layer gives itself (``q``, ``k``, ``v``, ``ffn_out``) none: the
+    module docstring has the table."""
+    from tpu_syncbn.ops.pallas_attention import FLASH_LSE, FLASH_OUT
+
+    return (FLASH_OUT, FLASH_LSE, "attn_proj")
 
 
 class _Layers(nnx.Module):
@@ -181,7 +240,8 @@ class BlockDiffusionMoELM(MoEDecoderBase):
     def _attend(self, q, k, v):
         with jax.named_scope("attention"):
             return block_diffusion_attention(
-                q, k, v, q.shape[1] // 2, self.block_length, self.attn_impl)
+                q, k, v, q.shape[1] // 2, self.block_length, self.attn_impl,
+                _saved())
 
     def _attention_block(self, x, p, cos, sin, parts: dict | None = None):
         """``a = x + Attn(N1(x))``; q, k, v and the core's output join
@@ -244,7 +304,7 @@ class BlockDiffusionMoELM(MoEDecoderBase):
         probabilities (n, E), pairs not computed (n,))."""
         h = self.embed_tokens(jnp.concatenate([x0, xt], axis=1))
         cos, sin = self._angles(h.shape[1])
-        layer = (checkpointed(self._layer, _SAVED) if self.remat
+        layer = (checkpointed(self._layer, _saved()) if self.remat
                  else self._layer)
         return lax.scan(lambda x, p: layer(x, p, cos, sin),
                         h, self.layers.stacked())

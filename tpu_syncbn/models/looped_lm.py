@@ -152,16 +152,19 @@ def apply_rotary(x, cos, sin):
     return (x32 * cos[:, None, :] + rotated * sin[:, None, :]).astype(x.dtype)
 
 
-def causal_attention(q, k, v, impl: str):
+def causal_attention(q, k, v, impl: str, kept: tuple[str, ...] = ()):
     """``softmax(q k^T / sqrt(d) + causal mask) v`` for (B, S, heads, d)
     arrays. ``"xla"`` materialises the float32 scores; ``"flash"`` runs
     ``ops.pallas_attention.flash_attention``, forward and backward in
     the kernel file's own kernels (the backward's dK/dV and dQ; each
-    takes its tiles from the call's shape)."""
+    takes its tiles from the call's shape). ``kept``: what the caller's
+    checkpoint keeps, for the kernel to know which of its residuals are
+    among it."""
     if impl == "flash":
         from tpu_syncbn.ops.pallas_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=True, backward="pallas")
+        return flash_attention(q, k, v, causal=True, backward="pallas",
+                               kept=kept)
     s = q.shape[1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
@@ -173,7 +176,8 @@ def causal_attention(q, k, v, impl: str):
                       preferred_element_type=jnp.float32).astype(v.dtype)
 
 
-def block_diffusion_attention(q, k, v, clean_len: int, block: int, impl: str):
+def block_diffusion_attention(q, k, v, clean_len: int, block: int, impl: str,
+                              kept: tuple[str, ...] = ()):
     """``softmax(q k^T / sqrt(d) + M) v`` over ``2 * clean_len``
     positions laid out ``[clean ; noisy]``, M the block-diffusion mask
     (``ops.pallas_attention.Visibility``), for q (B, S, heads, d) and k,
@@ -181,13 +185,14 @@ def block_diffusion_attention(q, k, v, clean_len: int, block: int, impl: str):
     head ``h // (heads / kv_heads)``. The sibling of ``causal_attention``
     under the same switch: ``"xla"`` materialises the masked float32
     scores, a group's q heads against their one k/v head; ``"flash"``
-    runs the kernels, forward and backward, k and v at their own heads."""
+    runs the kernels, forward and backward, k and v at their own heads;
+    ``kept`` as in ``causal_attention``."""
     from tpu_syncbn.ops import pallas_attention
 
     if impl == "flash":
         return pallas_attention.flash_attention(
             q, k, v, block_diffusion_mask=(clean_len, block),
-            backward="pallas")
+            backward="pallas", kept=kept)
     b, s, heads, d = q.shape
     grouped = q.reshape(b, s, k.shape[2], -1, d)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k,

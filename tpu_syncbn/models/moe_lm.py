@@ -53,8 +53,54 @@ Each kind of layer is stacked on a leading axis and applied by
 ``lax.scan`` (a stack of one is a scan of one: every layer then runs
 under the same checkpoint, whose ``prevent_cse=False`` needs the scan).
 With ``remat`` a layer application and each head read are
-``jax.checkpoint``ed: the backward pass keeps a layer's input and the
-outputs named in ``_SAVED`` and never a (B, S, vocabulary) map.
+``jax.checkpoint``ed: the backward pass keeps a layer's input and what
+``_saved()`` names (``save_only_these_names``), and never a (B, S,
+vocabulary) map. What it names are the attention kernel's two
+residuals, its output and its log-sum-exp
+(``ops.pallas_attention.FLASH_OUT``, ``FLASH_LSE``: the kernel's forward
+rule names them): q, k and v the backward pass makes again from the
+projections anyway, but to get these two back it ran the whole forward
+kernel a second time, 6.06 ms a layer application at 8,192 tokens.
+Under ``attn_impl="xla"`` the names never appear and nothing is kept.
+
+What the layer's checkpoint keeps, measured on a TPU v5e at the
+benchmark's cell (``joyai-l5-train-b1x8192``: 1 + 4 layers and the
+prediction module's, six layer applications through three scans, one
+sequence of 8,192 tokens, 680M parameters under AdamW; one traced run
+each on one seed, PERF.md section 6, PR 39; ms a step, ``mixture``
+the time under ``moe``, bytes at the peak of 16.91e9):
+
+=======================================  ======  =========  =======  ========
+kept beside the layer's input            step    recompute  mixture  bytes
+=======================================  ======  =========  =======  ========
+nothing (until PR 39)                    445.27  85.30      48.15    14.966e9
+the kernel's two: ``_saved()``           414.83  42.89      61.64    14.889e9
++ ``attn_proj``                          409.37  39.05      55.57    15.023e9
++ ``q``                                  402.95  31.64      50.36    15.406e9
++ ``q``, ``k``, ``v``                    does not fit: 2.13 GB over the two
+=======================================  ======  =========  =======  ========
+
+The mixture's time is the step's routing (it differs between two runs
+of one seed once their roundings differ), so read the step less the
+mixture: 397.12, 353.19, 353.80, 352.59. The two names take 43.9 ms off the
+step: the recomputed kernel calls' 36.95 and the layout changes around
+them; the forward kernel's six calls that are left take 37.25 (36.35
+before), the two backward kernels 118.3 (117.1), and the copy that
+makes the dQ kernel's column from the kept row 1.4. At the step's
+peak they cost no memory (the peak is not where the saved stacks
+live); the loss-and-gradient program alone grows by 544 MB (compiled
+for a described v5e; the closed form is 409). ``attn_proj`` beside
+them takes 3.7 ms off the recomputation and other fusions of the
+backward pass take 6.1 more: nothing for 134 MB, not kept
+(``block_diffusion_lm``, where it pays, keeps it). ``q`` beside them (six
+stacks of 101 MB) takes 11.2 ms off the recomputation and gives 5.6
+back in the forward pass and the rest in the backward pass: 0.6 ms of
+the step for 517 MB, so it is not kept; q, k and v together are 2.13 GB
+more than the two by the compiler's count, about 17.0e9 of the chip's
+16.91e9. With q and k behind one barrier before they are named, as
+``looped_lm`` has it, the two names give the same step to 0.001 ms and
+``q`` beside them 403.70 (352.59 + 0.7 less the mixture): nothing
+either.
 
 Scopes (``jax.named_scope``, docs/OBSERVABILITY.md): ``mla`` around the
 attention block with ``attention`` around its core inside it; ``mlp``
@@ -79,16 +125,22 @@ from tpu_syncbn.mesh_axes import DATA_AXIS
 from tpu_syncbn.nn.normalization import _axis_in_scope
 from tpu_syncbn.parallel import collectives, expert
 
-# The outputs of a layer application that its ``jax.checkpoint`` keeps
-# for the backward pass, of those the layer names (``q``, ``k``, ``v``,
-# ``attn_proj``, ``ffn_out``): none. At the cell's 8,192 tokens the step
-# holds 15.3 of the chip's 16.9 GB; q, k and v of six layer applications
-# are 1.6 GB for the 27.7 ms of a 785 ms step that recomputing them
-# costs (PERF.md section 6, PR 34: a reckoning from one traced run, no
-# sweep as in ``looped_lm``).
-_SAVED = ()
 _ROW_TILE = 512  # rows a tile of the grouped product on the TPU
 RECENT_STEPS = 16  # single steps' loads an expert layer keeps
+
+
+def _saved() -> tuple[str, ...]:
+    """What a layer application's ``jax.checkpoint`` keeps for the
+    backward pass beside its input: the attention kernel's output and
+    log-sum-exp, under the names the kernel file gives them in its
+    forward rule (imported here, as the kernel is, so that importing the
+    model does not import Pallas), so that the backward pass does not
+    run the forward kernel again to get them back. Of the names the
+    layer gives itself (``q``, ``k``, ``v``, ``attn_proj``,
+    ``ffn_out``) none: the module docstring has the table."""
+    from tpu_syncbn.ops.pallas_attention import FLASH_LSE, FLASH_OUT
+
+    return (FLASH_OUT, FLASH_LSE)
 
 
 class SelectionBias(nnx.Variable):
@@ -313,7 +365,7 @@ class LatentMoEDecoderLM(MoEDecoderBase):
 
     def _attend(self, q, k, v):
         with jax.named_scope("attention"):
-            return causal_attention(q, k, v, self.attn_impl)
+            return causal_attention(q, k, v, self.attn_impl, _saved())
 
     def _attention_block(self, x, p, cos, sin):
         """``a = x + MLA(N1(x))``."""
@@ -371,7 +423,7 @@ class LatentMoEDecoderLM(MoEDecoderBase):
         training path keeps none) each layer's input (n, B, S, H) joins
         them."""
         cos, sin = self._angles(h.shape[1])
-        layer = (checkpointed(self._layer, _SAVED) if self.remat
+        layer = (checkpointed(self._layer, _saved()) if self.remat
                  else self._layer)
         bias = block.bias[...] if block.moe else None
 

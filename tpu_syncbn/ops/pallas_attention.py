@@ -72,6 +72,35 @@ attention, ``models.transformer``), which no benchmark cell runs; it
 takes equal heads under no mask or the causal one, and refuses grouped
 heads and the block-diffusion mask by name.
 
+**What a caller's checkpoint may keep.** The VJP's residuals are q, k,
+v, the output ``o`` and the log-sum-exp ``lse``. A caller's
+``jax.checkpoint`` recomputes q, k and v anyway (they are its own
+projections); to get ``o`` and ``lse`` back it has to run the whole
+forward kernel again, unless it keeps them. The forward rule names the
+two (:data:`FLASH_OUT`, :data:`FLASH_LSE`, with
+``jax.ad_checkpoint.checkpoint_name``), and a caller whose policy is
+``save_only_these_names`` lists them (``models.moe_lm``,
+``models.block_diffusion_lm``); to any other caller a name is the
+identity. What each weighs a call: ``o`` is (B x H, L, Dv)
+in the inputs' type, 67 MB at 32 heads of 128 and 8,192 positions in
+bf16; ``lse`` is the (B x H, L) float32 row, 1 MB there. Such a caller
+says so (``kept``), and the pair then passes one
+``lax.optimization_barrier`` before it is named, so that the row exists
+as such: the kernel writes the log-sum-exp as (B x H, T, 1), which the
+TPU's layout pads to 128 lanes (134 MB a call there), the dQ kernel
+reads that column as it is, and left alone the compiler keeps the
+column for it. Compiled for a described v5e (PR 39;
+``tests/test_tpu_compile.py`` keeps the guard): the latent-attention
+decoder cut to three layer applications at 4,096 tokens holds 118 MB
+more with the two kept behind the barrier (the closed form is 102) and
+316 MB more without it; a barrier around the log-sum-exp alone is no
+help (316). The barrier is asked for and not everyone's because it
+costs a caller that keeps neither: the dQ kernel's column is then made
+from the row by a copy where there was a move of the padded column,
+1.62 ms of the looped decoder's 568.77 ms step on the chip (32 calls;
+PERF.md section 6, PR 39), and a name alone lowers to nothing, so that
+decoder's compiled step is what it was.
+
 Like the BN kernels, everything runs under ``interpret=True`` off-TPU
 (the CPU suite exercises the real kernel code path), and the kernel is
 an *opt-in* backend (``attn_impl="flash"`` of ``models.transformer``,
@@ -108,6 +137,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -116,6 +146,12 @@ from tpu_syncbn.ops._pallas_common import interpret as _interpret
 from tpu_syncbn.ops._pallas_common import sds as _sds
 
 _LANES = 128
+# The names of the two residuals of a call that a caller's
+# ``jax.checkpoint`` may keep (``save_only_these_names``): the forward
+# kernel's output (B x H, L, Dv) in the inputs' type and its log-sum-exp
+# as a lane-dense (B x H, L) float32 row (module docstring).
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
 # the forward's tiles: the widest the sweep on the chip found worth
 # having (benchmarks/flash_tile_sweep.py, PERF.md section 6, PR 31: at
 # 2,048 tokens of 128-wide heads 512 x 512 takes 0.35 ms a call, 512 x
@@ -1097,20 +1133,35 @@ def _flash_bwd_2d_pallas(res, do, *, rule, scale, block_q, block_k):
 # -- public API -----------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_2d(q, k, v, rule, scale, block_q, block_k, backward):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_2d(q, k, v, rule, scale, block_q, block_k, backward, dense_lse):
     o, _ = _flash_fwd_2d(q, k, v, rule=rule, scale=scale,
                          block_q=block_q, block_k=block_k)
     return o
 
 
-def _flash_2d_fwd(q, k, v, rule, scale, block_q, block_k, backward):
-    o, lse = _flash_fwd_2d(q, k, v, rule=rule, scale=scale,
-                           block_q=block_q, block_k=block_k)
+def _named_residuals(o, lse, dense_lse: bool):
+    """The two residuals a caller's checkpoint may keep, under their
+    names. Where the caller keeps the log-sum-exp (``dense_lse``) the
+    pair passes one barrier first, so that it exists as the (BH, L) row:
+    left alone, the compiler keeps the kernel's (BH, T, 1) output, which
+    the TPU's layout pads to 128 lanes, for the dQ kernel that reads a
+    column (module docstring)."""
+    if dense_lse:
+        o, lse = lax.optimization_barrier((o, lse))
+    return checkpoint_name(o, FLASH_OUT), checkpoint_name(lse, FLASH_LSE)
+
+
+def _flash_2d_fwd(q, k, v, rule, scale, block_q, block_k, backward,
+                  dense_lse):
+    o, lse = _named_residuals(*_flash_fwd_2d(
+        q, k, v, rule=rule, scale=scale, block_q=block_q, block_k=block_k),
+        dense_lse)
     return o, (q, k, v, o, lse)
 
 
-def _flash_2d_bwd(rule, scale, block_q, block_k, backward, res, do):
+def _flash_2d_bwd(rule, scale, block_q, block_k, backward, dense_lse, res,
+                  do):
     # a block the caller did not name comes from the shape: each
     # backward kernel's own tiles, or the scan's key block
     if backward == "pallas":
@@ -1136,6 +1187,7 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     backward: str = "xla",
+    kept: tuple[str, ...] = (),
 ) -> jax.Array:
     """Exact fused softmax attention: q ``(B, L, H, D)``, k ``(B, L,
     H_kv, D)``, v ``(B, L, H_kv, Dv)`` → ``(B, L, H, Dv)``.
@@ -1175,6 +1227,12 @@ def flash_attention(
     dQ: what ``models.looped_lm.causal_attention`` names, 2.1 to 3.7
     times as fast as the scan at the benchmark cells' shapes; the
     module's docstring has the numbers).
+    ``kept``: what the caller's ``jax.checkpoint`` keeps for the
+    backward pass (its ``save_only_these_names`` list; names that are
+    not this kernel's count for nothing): where :data:`FLASH_LSE` is
+    among them the log-sum-exp is made the lane-dense row before it is
+    named (the module's docstring has why, and what it costs a caller
+    that keeps neither, which is why it is asked for).
     """
     if q.ndim != 4:
         raise ValueError(f"expected (B, L, H, D), got {q.shape}")
@@ -1206,5 +1264,5 @@ def flash_attention(
     s = float(scale) if scale is not None else d ** -0.5
     to2d = lambda x: x.transpose(0, 2, 1, 3).reshape(-1, l, x.shape[-1])
     o = _flash_2d(to2d(q), to2d(k), to2d(v), rule, s, block_q, block_k,
-                  backward)
+                  backward, FLASH_LSE in kept)
     return o.reshape(b, h, l, v.shape[-1]).transpose(0, 2, 1, 3)
