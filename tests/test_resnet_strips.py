@@ -18,41 +18,107 @@ from tpu_syncbn.models import resnet
 from tpu_syncbn.models.resnet import BasicBlock, Bottleneck, ResNet, strip_count
 
 
-@pytest.mark.parametrize("n, h, strided, want", [
+@pytest.mark.parametrize("n, h, multiple, want", [
     # retinanet-train-b2's four stages: 2 images of 800x1344
-    (2, 200, False, 4), (2, 200, True, 4), (2, 100, True, 5), (2, 50, True, 5),
-    (1, 200, False, 8), (4, 200, True, 2), (3, 9, False, 3), (7, 10, False, 2),
+    (2, 200, 1, 4), (2, 200, 2, 4), (2, 100, 2, 5), (2, 50, 2, 5),
+    (1, 200, 1, 8), (4, 200, 2, 2), (3, 9, 1, 3), (7, 10, 1, 2),
     # N * G >= 8 needs a divisor of H that the stride leaves even strips
-    (2, 25, False, 5), (2, 25, True, 1), (1, 7, False, 1), (4, 7, False, 7),
-    (4, 7, True, 1), (1, 8, False, 8), (1, 8, True, 1), (2, 6, True, 1),
+    (2, 25, 1, 5), (2, 25, 2, 1), (1, 7, 1, 1), (4, 7, 1, 7),
+    (4, 7, 2, 1), (1, 8, 1, 8), (1, 8, 2, 1), (2, 6, 2, 1),
     # from 8 images up: whole images, whatever the height
-    (8, 200, False, 1), (8, 200, True, 1), (128, 56, False, 1),
+    (8, 200, 1, 1), (8, 200, 2, 1), (128, 56, 1, 1),
+    # the ImageNet stem, 7x7/2 then 3x3/2: strips of a multiple of 4
+    # rows; retinanet-train-b2's 800 rows, a height whose quarters are
+    # not (50), one with no such G, and 8 images or more
+    (2, 800, 4, 4), (2, 200, 4, 5), (4, 800, 4, 2), (1, 224, 4, 8),
+    (1, 12, 4, 1), (8, 800, 4, 1), (128, 224, 4, 1),
 ])
-def test_strip_count_is_the_least_that_reaches_eight(n, h, strided, want):
-    got = strip_count(n, h, strided)
+def test_strip_count_is_the_least_that_reaches_eight(n, h, multiple, want):
+    got = strip_count(n, h, multiple)
     assert got == want
     if got > 1:
-        assert n * got >= 8 and h % got == 0
-        assert not (strided and (h // got) % 2)
+        assert n * got >= 8 and h % got == 0 and (h // got) % multiple == 0
         assert not any(
-            n * g >= 8 and h % g == 0 and not (strided and (h // g) % 2)
+            n * g >= 8 and h % g == 0 and (h // g) % multiple == 0
             for g in range(1, got))
+
+
+def _check_conv_on_strips(kernel, stride, strips, rows, width):
+    """The convolution alone on strips of ``rows`` rows: equal to
+    ``conv(x)`` of the unfolded images, the rows at a strip's edge
+    included."""
+    conv = resnet._conv(6, 10, kernel, stride, nnx.Rngs(0))
+    x = jax.random.normal(jax.random.key(1), (3, rows * strips, width, 6))
+    want = conv(x)
+    got = resnet._conv_on_strips(
+        conv, x.reshape(3 * strips, rows, width, 6), strips)
+    assert got.shape == (3 * strips, rows // stride, *want.shape[2:])
+    np.testing.assert_allclose(
+        got.reshape(want.shape), want, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("width", [12, 11], ids=["w-even", "w-odd"])
 @pytest.mark.parametrize("stride", [1, 2])
 @pytest.mark.parametrize("strips", [2, 4, 5])
 def test_conv_on_strips_is_the_conv_of_the_image(strips, stride, width):
-    """The 3x3 alone, on strips of 4 rows: equal to ``conv(x)`` of the
-    unfolded images, the rows at a strip's edge included."""
-    conv = resnet._conv(6, 10, 3, stride, nnx.Rngs(0))
-    x = jax.random.normal(jax.random.key(1), (3, 4 * strips, width, 6))
-    want = conv(x)
-    got = resnet._conv_on_strips(
-        conv, x.reshape(3 * strips, 4, width, 6), strips)
-    assert got.shape == (3 * strips, 4 // stride, *want.shape[2:])
-    np.testing.assert_allclose(
-        got.reshape(want.shape), want, rtol=1e-5, atol=1e-5)
+    """A 3x3: the row above and the row below at stride 1, the row below
+    at stride 2."""
+    _check_conv_on_strips(3, stride, strips, 4, width)
+
+
+@pytest.mark.parametrize("width", [12, 11], ids=["w-even", "w-odd"])
+@pytest.mark.parametrize("rows", [4, 6])
+@pytest.mark.parametrize("strips", [2, 4, 5])
+def test_stem_conv_on_strips_is_the_conv_of_the_image(strips, rows, width):
+    """The stem's 7x7/2: two rows from the strip above, three from the
+    strip below, at even strip heights down to 4."""
+    _check_conv_on_strips(7, 2, strips, rows, width)
+
+
+def _pool_input(kind, shape):
+    """Integer values, so that every sum of the backward is exact:
+    ``relu``: the stem's, runs of zeros and of equal values across the
+    strips' edges; ``negative``: -3 to -1, which a pad of 0 would beat;
+    ``flat``: zeros everywhere, every window a tie."""
+    rng = np.random.RandomState(7)
+    x = rng.randint(-2, 3, size=shape).astype(np.float32)
+    if kind == "flat":
+        return jnp.zeros(shape)
+    if kind == "negative":
+        return jnp.asarray(np.minimum(x, 0) - 1)
+    x = jnp.asarray(np.maximum(x, 0))
+    return x.astype(jnp.bfloat16) if kind == "relu-bf16" else x
+
+
+@pytest.mark.parametrize("kind", ["relu", "negative", "flat", "relu-bf16"])
+@pytest.mark.parametrize("width", [12, 11], ids=["w-even", "w-odd"])
+@pytest.mark.parametrize("strips", [2, 4])
+def test_max_pool_on_strips_is_the_pool_of_the_image(strips, width, kind):
+    """The stem's 3x3/2 max-pool on strips of 2 rows (the least the rule
+    leaves it) against ``nnx.max_pool`` of the whole images: the forward
+    and the input's gradient equal to the bit, so the halo's fill at an
+    image's last strip is -inf and the backward picks, among equal
+    values, the element the whole image's pool picks."""
+    x = _pool_input(kind, (3, 2 * strips, width, 5))
+
+    def whole(x):
+        return nnx.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+
+    def folded(x):
+        y = resnet._max_pool_on_strips(
+            x.reshape(3 * strips, 2, width, 5), strips)
+        return y.reshape(3, strips, *y.shape[2:])
+
+    want, want_vjp = jax.vjp(whole, x)
+    got, got_vjp = jax.vjp(folded, x)
+    cot = jnp.asarray(np.random.RandomState(8).randint(
+        -3, 4, size=want.shape), x.dtype)
+    np.testing.assert_array_equal(
+        np.asarray(got.reshape(want.shape), np.float32),
+        np.asarray(want, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(got_vjp(cot.reshape(got.shape))[0], np.float32),
+        np.asarray(want_vjp(cot)[0], np.float32))
 
 
 def _plain_features(model, x):
@@ -120,13 +186,20 @@ def test_features_on_strips_equal_whole_images(block, layers, n, h, w, strips):
     x = jax.random.normal(jax.random.key(n * h), (n, h, w, 3))
     # the rule picked what the case is here for
     heights = [h, h, h // 2, h // 4]
-    assert tuple(strip_count(n, hh, i > 0)
+    assert tuple(strip_count(n, hh, 2 if i else 1)
                  for i, hh in enumerate(heights)) == strips
     text = _scopes(model, x)
     for i, g in enumerate(strips):
         assert (f"layer{i + 1}/strips" in text) == (g > 1)
+    # the CIFAR stem, 3x3/1, is a stage's case: a row from either side
+    assert ("stem/strips" in text) == (strip_count(n, h) > 1)
     assert "halo" in text
+    _assert_features_equal(model, x)
 
+
+def _assert_features_equal(model, x):
+    """``features`` against the plain composition: maps, gradients and
+    BN running statistics, to float32 rounding."""
     feats, grads, stats = _value_grads_stats(
         lambda m, x: m.features(x), model, x)
     want_feats, want_grads, want_stats = _value_grads_stats(
@@ -145,14 +218,34 @@ def test_features_on_strips_equal_whole_images(block, layers, n, h, w, strips):
         stats, want_stats)
 
 
+@pytest.mark.parametrize("n, h, w, stem_strips", [
+    (2, 64, 20, 4), (2, 16, 23, 4), (4, 40, 12, 2), (2, 24, 12, 6)],
+    ids=["n2-h64", "n2-h16-w23", "n4-h40", "n2-h24"])
+def test_imagenet_stem_on_strips_equals_whole_images(n, h, w, stem_strips):
+    """The 7x7/2 convolution, its BN and ReLU and the 3x3/2 max-pool on
+    strips of a multiple of 4 rows (4 the least), the stages after them
+    by their own rule."""
+    model = ResNet(Bottleneck, (1, 1, 1, 1), num_classes=3, width=8,
+                   rngs=nnx.Rngs(0))
+    x = jax.random.normal(jax.random.key(n * h), (n, h, w, 3))
+    assert strip_count(n, h, 4) == stem_strips
+    text = _scopes(model, x)
+    assert "stem/strips" in text and "stem/halo" in text
+    _assert_features_equal(model, x)
+
+
 def test_imagenet_stem_and_bf16_compute_on_strips():
-    """The 7x7 stem and the max-pool stay on whole images, and the halo'd
-    call promotes to the module's compute dtype as ``nnx.Conv`` does."""
+    """retinanet-train-b2's case at a toy size: 2 images, the 7x7/2 stem
+    and the max-pool on 4 strips an image as ``layer1`` is, in bf16; the
+    halo'd calls promote to the module's compute dtype as ``nnx.Conv``
+    does."""
     model = ResNet(Bottleneck, (1, 1, 1, 1), num_classes=3, width=8,
                    dtype=jnp.bfloat16, rngs=nnx.Rngs(0))
     x = jax.random.normal(jax.random.key(0), (2, 64, 48, 3))
+    assert strip_count(2, 64, 4) == strip_count(2, 16) == 4
     text = _scopes(model, x)
-    assert "layer1/strips" in text and "stem/strips" not in text
+    assert "stem/strips" in text and "stem/halo" in text
+    assert "layer1/strips" in text
     feats = model.features(x)
     want = _plain_features(nnx.clone(model), x)
     for got, w in zip(feats, want, strict=True):
@@ -162,14 +255,19 @@ def test_imagenet_stem_and_bf16_compute_on_strips():
             rtol=5e-2, atol=5e-2)
 
 
-@pytest.mark.parametrize("n, h", [(8, 32), (16, 16), (1, 7), (2, 3)],
-                         ids=["n8", "n16", "n1-h7", "n2-h3"])
-def test_whole_images_where_the_rule_refuses(n, h):
+@pytest.mark.parametrize("n, h, small_input", [
+    (8, 32, True), (16, 16, True), (1, 7, True), (2, 3, True),
+    (8, 64, False), (16, 32, False), (1, 12, False), (2, 6, False)],
+    ids=["n8", "n16", "n1-h7", "n2-h3", "imagenet-n8", "imagenet-n16",
+         "imagenet-n1-h12", "imagenet-n2-h6"])
+def test_whole_images_where_the_rule_refuses(n, h, small_input):
     """From 8 images up, and where no strip count fits (N * G >= 8 with
-    G dividing an odd or a small height): no ``strips``, no ``halo``,
-    and the program ``features`` lowers to is the plain composition's."""
+    G dividing an odd or a small height, or, the ImageNet stem, leaving
+    no multiple of 4): no ``strips``, no ``halo``, and the program
+    ``features`` lowers to is the plain composition's, so the 128-image
+    cells' step is what it was before the fold."""
     model = ResNet(Bottleneck, (1, 1, 1, 1), num_classes=3, width=8,
-                   small_input=True, rngs=nnx.Rngs(0))
+                   small_input=small_input, rngs=nnx.Rngs(0))
     x = jnp.zeros((n, h, 8, 3))
     text = _scopes(model, x)
     assert "strips" not in text and "halo" not in text

@@ -399,6 +399,13 @@ def test_block_diffusion_decoder_compiles_for_v5e(v5e, mosaic):
     assert "ragged-dot" in compiled.as_text()
 
 
+def _copies_and_pads(text: str) -> list[tuple[str, str]]:
+    """(dimensions, operation) of the compiled program's entry
+    computation's ``copy`` and ``pad`` instructions."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    return re.findall(r"= \w+\[([\d,]*)\]\S* (copy|pad)\(", entry)
+
+
 @pytest.mark.parametrize("n, strips", [(2, 4), (8, 1)])
 def test_bottlenecks_on_strips_keep_space_to_batch_away(v5e, n, strips):
     """Two bottleneck blocks of RetinaNet's ``layer1`` at 800x1344
@@ -412,15 +419,13 @@ def test_bottlenecks_on_strips_keep_space_to_batch_away(v5e, n, strips):
     away, without a chip. At 8 images the rule leaves the stage alone
     (there the plain program is the 14-fusion one, 23.1 KB a position,
     and every folded variant read worse)."""
-    import re
-
     from flax import nnx
 
     from tpu_syncbn.models import resnet
     from tpu_syncbn.nn import BatchNorm2d
 
     shape = (n, 200, 336, 256)
-    assert resnet.strip_count(n, shape[1], False) == strips
+    assert resnet.strip_count(n, shape[1]) == strips
     abstract = nnx.eval_shape(lambda: nnx.List([
         resnet.Bottleneck(256, 64, 1, BatchNorm2d, nnx.Rngs(0),
                           dtype=jnp.bfloat16) for _ in range(2)]))
@@ -437,11 +442,58 @@ def test_bottlenecks_on_strips_keep_space_to_batch_away(v5e, n, strips):
         jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)).compile()
     text = compiled.as_text()
     assert ("halo" in text) == (strips > 1)
-    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
-    # (dimensions, operation) of the entry's copies and pads
-    ops = re.findall(r"= \w+\[([\d,]*)\]\S* (copy|pad)\(", entry)
+    ops = _copies_and_pads(text)
     assert sum(op == "copy" for _, op in ops) <= 2, ops
     assert not [d for d, op in ops if op == "pad" and d.count(",") >= 3], ops
     kb_a_position = (compiled.cost_analysis()["bytes accessed"]
                      / (n * 200 * 336) / 1e3)
     assert kb_a_position < 45, kb_a_position
+
+
+def test_stem_on_strips_keeps_space_to_batch_away(v5e):
+    """RetinaNet's stem at 2 images of 800x1344 (``retinanet-train-b2``):
+    the 7x7/2 convolution, BN, ReLU and the 3x3/2 max-pool in bf16, loss
+    and the parameters' gradients in one program, on whole images and
+    on the 4 row strips an image ``ResNet.features`` folds it onto.
+    Whole images: 11 ``copy`` and 1 ``pad`` in the entry computation, 10
+    of them of the 64-channel maps or of the input laid rows-first
+    (space-to-batch), 6.06 GB accessed. On strips: 9 ``copy``, the
+    input's layout, the halo's rows of 3 channels and the kernel, none
+    of them space-to-batch, no ``pad``, 2.72 GB."""
+    from flax import nnx
+
+    from tpu_syncbn.models import resnet
+
+    abstract = nnx.eval_shape(lambda: resnet.ResNet(
+        resnet.Bottleneck, (1, 1, 1, 1), dtype=jnp.bfloat16,
+        rngs=nnx.Rngs(0)))
+    graphdef, params, rest = nnx.split(abstract, nnx.Param, ...)
+    on_chip = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e), t)
+    x = jax.ShapeDtypeStruct((2, 800, 1344, 3), jnp.bfloat16, sharding=v5e)
+    assert resnet.strip_count(2, 800, 4) == 4
+
+    def compiled(stem):
+        def loss(p, r, x):
+            return stem(nnx.merge(graphdef, p, r, copy=True), x).astype(
+                jnp.float32).mean()
+
+        return jax.jit(jax.value_and_grad(loss)).lower(
+            on_chip(params), on_chip(rest), x).compile()
+
+    whole = compiled(lambda m, x: m._stem(x, 1))
+    strips = compiled(lambda m, x: resnet._on_strips(m._stem, x, 4))
+    assert "halo" in strips.as_text()
+    # a copy or pad of a 64-channel map (not the 7x7 kernel), or of the
+    # input laid rows-first, as the space-to-batch conversion lays it
+    converted = lambda ops: [
+        d for d, _ in ops
+        if (d.endswith(",64") and not d.startswith("7,7,"))
+        or d.startswith("800,")]
+    whole_ops = _copies_and_pads(whole.as_text())
+    ops = _copies_and_pads(strips.as_text())
+    assert len(converted(whole_ops)) >= 8, whole_ops
+    assert not converted(ops), ops
+    assert not [op for _, op in ops if op == "pad"], ops
+    gb = [c.cost_analysis()["bytes accessed"] / 1e9 for c in (whole, strips)]
+    assert gb[0] > 5.5 and gb[1] < 3.0, gb
