@@ -2,12 +2,16 @@
 (the reference's C13, [torch] utils/data/distributed.py), loader ordering and
 worker determinism, device prefetch."""
 
+import collections
+
 import numpy as np
 import pytest
 import torch
 from torch.utils.data import DistributedSampler as TorchDistributedSampler
 
 from tpu_syncbn import data as tdata
+from tpu_syncbn.data.loader import _build_batch
+from tpu_syncbn.obs import tracing
 
 
 class _TorchSized(torch.utils.data.Dataset):
@@ -673,3 +677,193 @@ def test_loader_stress_no_deadlock():
     assert not t.is_alive(), "loader stress run deadlocked (watchdog fired)"
     if errors:
         raise errors[0]
+
+
+# -- every sample written into its slot of the batch as it is made ----------
+
+_Pt = collections.namedtuple("_Pt", "x y")
+
+
+class _Listed(tdata.Dataset):
+    """``samples[i]``, made afresh on every read: what a dataset whose
+    transforms run at ``__getitem__`` hands the loader."""
+
+    def __init__(self, make, n=6):
+        self.make, self.n = make, n
+
+    def __len__(self):
+        return self.n
+
+    def __getitem__(self, i):
+        return self.make(i)
+
+
+def _assert_same_batch(got, want):
+    """Equal to the bit, container for container and leaf for leaf."""
+    assert type(got) is type(want)
+    if isinstance(want, (tuple, list)):
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            _assert_same_batch(a, b)
+    elif isinstance(want, dict):
+        assert list(got) == list(want)
+        for k in want:
+            _assert_same_batch(got[k], want[k])
+    else:
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert got.tobytes() == want.tobytes()
+
+
+def _built(ds, idxs, collate_fn=tdata.default_collate):
+    """The batch ``_build_batch`` makes, and the ``slotted`` arg of its
+    ``loader.build`` span."""
+    tracer = tracing.install(tracing.Tracer())
+    try:
+        batch = _build_batch(ds, collate_fn, idxs, seq=0, worker=0)
+    finally:
+        tracing.uninstall()
+    (build,) = [e for e in tracer.events if e["name"] == "loader.build"]
+    collates = [e for e in tracer.events if e["name"] == "loader.collate"]
+    assert [c["args"]["parent_id"] for c in collates] == [
+        build["args"]["span_id"]]  # what is left to assemble, inside
+    return batch, build["args"]["slotted"]
+
+
+def _family_sample(workload):
+    """A maker of the benchmark cell's samples at its rehearsal size,
+    from its own pool and transform, as ``chipbench/inputs/loader.py``
+    makes them."""
+    import importlib
+
+    from chipbench import run
+
+    wl = run.rehearsal(run.load_json("workloads", workload + ".json"))
+    cfg = run.rehearsal(run.load_json("configs", wl["config"] + ".json"))
+    family = importlib.import_module("chipbench.families." + cfg["family"])
+    n = wl["input"]["pool_images"]
+    pool = family.make_pool(cfg, n, np.random.default_rng(2147483659))
+    transform = family.transform(cfg)
+    return lambda i: transform(tuple(a[i % n] for a in pool))
+
+
+SLOTTED = {
+    "array": lambda i: np.full((2, 3), i, np.float32),
+    "tuple": lambda i: (np.full(3, i, np.int16), np.ones((2, 2)) * i),
+    "list": lambda i: [np.arange(4) + i, np.float32(i)],
+    "namedtuple": lambda i: _Pt(np.full(2, i, np.uint8), i),
+    "dict": lambda i: {"b": np.full(2, i), "a": (i, np.zeros(3, bool))},
+    "numpy_scalars": lambda i: (np.float16(i / 3), np.int8(i), np.bool_(i)),
+    "zero_d_arrays": lambda i: np.array(i / 7, np.float32),
+    "python_scalars": lambda i: (i, i / 3, bool(i % 2), i * 1j),
+    "strings": lambda i: ("ab"[i % 2] * 3, b"xyz"),
+    "empty": lambda i: (np.zeros((0, 4), np.float32), ()),
+}
+CELLS = ["r50-train-b128", "retinanet-train-b2", "ouro-l8-train-b2x2048",
+         "joyai-l5-train-b1x8192", "sdar-l6-train-b1x4096"]
+
+
+@pytest.mark.parametrize("case", list(SLOTTED) + CELLS)
+def test_slot_by_slot_build_is_default_collate_of_the_samples(case):
+    make = SLOTTED[case] if case in SLOTTED else _family_sample(case)
+    ds = _Listed(make)
+    idxs = [4, 0, 5, 2]
+    batch, slotted = _built(ds, idxs)
+    assert slotted == 1
+    _assert_same_batch(batch, tdata.default_collate([ds[i] for i in idxs]))
+
+
+FALLBACKS = {
+    "ragged": (lambda i: np.zeros(2 + (i == 3), np.float32), ValueError),
+    "dtype_changes": (lambda i: np.full(2, i, np.int32 if i < 3 else np.int64),
+                      None),
+    "python_int_then_float": (lambda i: i if i < 3 else float(i), None),
+    "container_changes": (lambda i: (i, i) if i < 3 else [i, i], None),
+    "string_length_changes": (lambda i: "a" * (1 + i // 3), None),
+    "object_leaf": (lambda i: (None, i), None),
+    "missing_key": (lambda i: {"a": i} if i < 3 else {"b": i}, KeyError),
+}
+
+
+@pytest.mark.parametrize("case", list(FALLBACKS))
+def test_a_batch_that_cannot_be_slotted_is_collated_whole(case):
+    """What a slot cannot hold as ``default_collate`` would build it (a
+    shape, dtype or container that changes within the batch, a leaf
+    that is not array-like) is collated whole: the same batch, or the
+    same error."""
+    make, error = FALLBACKS[case]
+    ds = _Listed(make)
+    idxs = [1, 2, 3, 4]
+    if error is not None:
+        with pytest.raises(error):
+            tdata.default_collate([ds[i] for i in idxs])
+        tracer = tracing.install(tracing.Tracer())
+        try:
+            with pytest.raises(error):
+                _build_batch(ds, tdata.default_collate, idxs, seq=0, worker=0)
+        finally:
+            tracing.uninstall()
+        (build,) = [e for e in tracer.events if e["name"] == "loader.build"]
+        assert build["args"]["slotted"] == 0
+        return
+    batch, slotted = _built(ds, idxs)
+    assert slotted == 0
+    _assert_same_batch(batch, tdata.default_collate([ds[i] for i in idxs]))
+
+
+def test_a_user_collate_fn_gets_the_list_of_samples():
+    ds = _Listed(lambda i: np.full(2, i, np.float32))
+    seen = []
+
+    def collate(samples):
+        seen.append([s.copy() for s in samples])
+        return np.concatenate(samples)
+
+    batch, slotted = _built(ds, [3, 1], collate)
+    assert slotted == 0
+    np.testing.assert_array_equal(batch, [3, 3, 1, 1])
+    assert [s[0] for s in seen[0]] == [3, 1]
+
+
+def test_untraced_build_is_the_traced_one():
+    ds = _Listed(SLOTTED["dict"])
+    traced, _ = _built(ds, [0, 1, 2])
+    _assert_same_batch(
+        _build_batch(ds, tdata.default_collate, [0, 1, 2], seq=0, worker=0),
+        traced)
+
+
+class _Images(tdata.Dataset):
+    """uint8 images through the benchmark's image transform; module level,
+    so process workers can unpickle it."""
+
+    def __init__(self):
+        T = tdata.transforms
+        self.norm = T.Compose([T.ToFloat(), T.Normalize(
+            (0.485, 0.456, 0.406), (0.229, 0.224, 0.225))])
+        self.pool = np.random.default_rng(7).integers(
+            0, 256, (5, 12, 10, 3), dtype=np.uint8)
+
+    def __len__(self):
+        return 20
+
+    def __getitem__(self, i):
+        return self.norm(self.pool[i % 5]), np.int32(i)
+
+
+@pytest.mark.parametrize("workers,worker_type", [
+    (3, "thread"), (8, "thread"), (2, "process")])
+def test_loaders_yield_the_sequential_batches_with_the_image_transform(
+        workers, worker_type):
+    ds = _Images()
+    ref = list(tdata.DataLoader(ds, batch_size=4))
+    loader = tdata.DataLoader(ds, batch_size=4, num_workers=workers,
+                              worker_type=worker_type)
+    try:
+        got = list(loader)
+    finally:
+        loader.close()
+    assert len(got) == len(ref) == 5
+    for a, b in zip(got, ref):
+        _assert_same_batch(a, b)
+    images = np.stack([ds[i][0] for i in range(4)])
+    _assert_same_batch(ref[0][0], images)

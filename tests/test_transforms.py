@@ -1,6 +1,7 @@
 """Host-side transform tests."""
 
 import numpy as np
+import pytest
 
 from tpu_syncbn.data import transforms as T
 
@@ -105,3 +106,116 @@ def test_threaded_loader_with_random_transforms_no_crash():
     batches = list(dl)
     assert len(batches) == 8
     assert all(b[0].shape == (8, 32, 32, 3) for b in batches)
+
+
+# -- ToFloat and Normalize: made in place, today's bits -----------------------
+
+MEAN, STD = (0.485, 0.456, 0.406), (0.229, 0.224, 0.225)
+
+
+def to_float_formula(x):
+    """``ToFloat`` as a formula: a float32 copy of uint8 over 255."""
+    if x.dtype == np.uint8:
+        return x.astype(np.float32) / 255.0
+    return np.asarray(x, np.float32)
+
+
+def normalize_formula(x, mean, std):
+    """``Normalize`` as a formula, with fresh arrays at every step."""
+    return ((np.asarray(x, np.float32) - np.asarray(mean, np.float32))
+            / np.asarray(std, np.float32))
+
+
+def every_value(dtype, shape):
+    """Every uint8 value over ``shape``, in ``dtype``; the floats scaled
+    off the integers and signed, so the inputs are not all whole."""
+    x = (np.arange(int(np.prod(shape))) % 256).reshape(shape)
+    if dtype == np.uint8:
+        return x.astype(np.uint8)
+    return (x * 1.37 - 100.0).astype(dtype)
+
+
+def assert_same_bits(got, want):
+    assert got.dtype == want.dtype == np.float32
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32, np.float64])
+@pytest.mark.parametrize("shape", [(16, 16, 3), (4, 8, 8, 3), (256, 3)],
+                         ids=["image", "batch", "rows"])
+@pytest.mark.parametrize("stats", [(MEAN, STD), (0.5, 0.25)],
+                         ids=["per_channel", "scalar"])
+@pytest.mark.parametrize("pipeline", ["fused", "to_float", "normalize"])
+def test_to_float_and_normalize_give_the_formulas_bits(
+        dtype, shape, stats, pipeline):
+    """The pair in a ``Compose`` runs as one step, each alone as itself;
+    all three give the formulas' bits, and the caller's array is left as
+    it was. The batch shape is the resident cells' call."""
+    mean, std = stats
+    x = every_value(dtype, shape)
+    before = x.copy()
+    if pipeline == "fused":
+        got = T.Compose([T.ToFloat(), T.Normalize(mean, std)])(x)
+        want = normalize_formula(to_float_formula(x), mean, std)
+    elif pipeline == "to_float":
+        got, want = T.ToFloat()(x), to_float_formula(x)
+    else:
+        got, want = T.Normalize(mean, std)(x), normalize_formula(x, mean, std)
+    assert_same_bits(got, want)
+    np.testing.assert_array_equal(x, before)
+    assert x.dtype == before.dtype
+
+
+def test_every_uint8_value_gives_the_formulas_bits():
+    x = np.arange(256, dtype=np.uint8).repeat(3).reshape(1, 256, 3)
+    got = T.Compose([T.ToFloat(), T.Normalize(MEAN, STD)])(x)
+    assert_same_bits(got, normalize_formula(to_float_formula(x), MEAN, STD))
+    assert len(np.unique(got[..., 0])) == 256
+
+
+@pytest.mark.parametrize("between", [
+    T.CenterCrop(8), T.RandomHorizontalFlip(p=1.0)], ids=["crop", "flip"])
+def test_a_transform_between_the_two_keeps_them_apart(between):
+    x = every_value(np.uint8, (12, 12, 3))
+    before = x.copy()
+    got = T.Compose([T.ToFloat(), between, T.Normalize(MEAN, STD)])(x)
+    want = normalize_formula(between(to_float_formula(x)), MEAN, STD)
+    assert_same_bits(got, want)
+    np.testing.assert_array_equal(x, before)
+
+
+@pytest.mark.parametrize("x", [
+    np.ones((8, 3), np.float32)[:, ::-1],            # not contiguous
+    np.asfortranarray(every_value(np.float32, (6, 5, 3))),
+    every_value(np.float32, (3,)),                  # one pixel
+], ids=["strided", "fortran", "pixel"])
+def test_normalize_in_place_on_any_layout(x):
+    before = x.copy()
+    got = T.Normalize(MEAN, STD)(x)
+    assert_same_bits(np.ascontiguousarray(got),
+                     np.ascontiguousarray(normalize_formula(x, MEAN, STD)))
+    np.testing.assert_array_equal(x, before)
+
+
+def test_normalize_whose_statistics_widen_the_input():
+    """A mean that broadcasts the input to a larger shape cannot be
+    written over it: a new array of the broadcast shape, as before."""
+    x = every_value(np.float32, (5, 3))
+    mean = np.full((2, 1, 3), 0.5, np.float32)
+    got = T.Normalize(mean, STD)(x)
+    assert got.shape == (2, 5, 3)
+    assert_same_bits(got, normalize_formula(x, mean, STD))
+
+
+def test_normalize_follows_the_row_shape_and_the_statistics_it_is_given():
+    """The statistics tiled along a row are kept for the last row shape:
+    images of another width, or a mean set anew, are normalized by what
+    they are given."""
+    norm = T.Normalize(MEAN, STD)
+    for shape in [(4, 6, 3), (4, 7, 3), (2, 4, 6, 3), (5, 6, 3)]:
+        x = every_value(np.float32, shape)
+        assert_same_bits(norm(x), normalize_formula(x, MEAN, STD))
+    norm.mean = np.asarray((0.1, 0.2, 0.3), np.float32)
+    x = every_value(np.float32, (4, 6, 3))
+    assert_same_bits(norm(x), normalize_formula(x, norm.mean, STD))

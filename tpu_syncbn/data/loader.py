@@ -72,15 +72,100 @@ def get_worker_info() -> WorkerInfo | None:
 
 def _build_batch(dataset, collate_fn, idxs, *, seq: int, worker: int):
     """One batch, where every worker model builds it: the samples
-    (dataset + transforms), then the collation. Under tracing
-    (``obs.tracing``) a ``loader.build`` span with ``loader.collate``
-    as its child, on the building thread (what of the build is not
-    collation is the samples); a process worker records in its own
-    process."""
-    with tracing.span("loader.build", seq=seq, worker=worker):
+    (dataset + transforms), then the collation. Under the default
+    collation each sample is written into its slot of the batch as soon
+    as it is made, while its bytes are still in the cache, and let go
+    (:func:`_slot_by_slot`); a user ``collate_fn`` gets the list of
+    samples. Under tracing (``obs.tracing``) a ``loader.build`` span
+    whose ``slotted`` is 1 where the batch was built slot by slot and 0
+    where it was collated whole, with ``loader.collate`` as its child
+    around what assembly is left after the last sample, on the building
+    thread; a process worker records in its own process."""
+    tracer = tracing.get()
+    token = None if tracer is None else tracer.begin(
+        "loader.build", seq=seq, worker=worker)
+    slotted = False
+    try:
+        if collate_fn is default_collate:
+            batch, slotted = _slot_by_slot(dataset, idxs)
+            return batch
         samples = [dataset[i] for i in idxs]
         with tracing.span("loader.collate"):
             return collate_fn(samples)
+    finally:
+        if token is not None:
+            tracer.end(token, slotted=int(slotted))
+
+
+def _slot_by_slot(dataset, idxs) -> tuple[Any, bool]:
+    """``default_collate([dataset[i] for i in idxs])``, and whether it was
+    built slot by slot. The first sample gives the batch's containers
+    and, through ``np.asarray``, its leaves' shapes and dtypes; each leaf
+    of the batch is allocated once and every sample is copied into its
+    slot as soon as it is made. A later sample whose containers, leaf
+    shapes or leaf dtypes differ from the first's, and a first sample
+    with a leaf that is not array-like (an object array), fall back to
+    ``default_collate`` of all the samples, those already slotted read
+    back from their slots: the same batch, or the same error, as
+    collating the samples whole."""
+    first = dataset[idxs[0]]
+    leaves = _leaves(first, first)
+    if leaves is None or any(a.dtype.hasobject for a in leaves):
+        samples = [first] + [dataset[i] for i in idxs[1:]]
+        with tracing.span("loader.collate"):
+            return default_collate(samples), False
+    kinds = [(a.shape, a.dtype) for a in leaves]
+    slots = [np.empty((len(idxs),) + a.shape, a.dtype) for a in leaves]
+    for j, i in enumerate(idxs):
+        if j:
+            sample = dataset[i]
+            leaves = _leaves(sample, first)
+            if leaves is None or any(
+                    a.shape != shape or a.dtype != dtype
+                    for a, (shape, dtype) in zip(leaves, kinds)):
+                made = [_assemble(first, (s[k] for s in slots))
+                        for k in range(j)]
+                samples = made + [sample] + [dataset[i] for i in idxs[j + 1:]]
+                with tracing.span("loader.collate"):
+                    return default_collate(samples), False
+        for s, a in zip(slots, leaves):
+            s[j] = a
+        sample = leaves = None  # let the sample go before the next is made
+    with tracing.span("loader.collate"):
+        return _assemble(first, iter(slots)), True
+
+
+def _leaves(x, like) -> list | None:
+    """The leaves of the sample ``x`` as ``np.asarray`` makes them, in the
+    order :func:`default_collate` visits them in ``like``; None where
+    ``x``'s containers are not ``like``'s (type, length, keys)."""
+    out: list = []
+    return out if _visit(x, like, out) else None
+
+
+def _visit(x, like, out: list) -> bool:
+    if isinstance(like, (tuple, list)):
+        return (type(x) is type(like) and len(x) == len(like)
+                and all(_visit(a, b, out) for a, b in zip(x, like)))
+    if isinstance(like, dict):
+        return (isinstance(x, dict) and all(k in x for k in like)
+                and all(_visit(x[k], v, out) for k, v in like.items()))
+    if isinstance(x, (tuple, list, dict)):
+        return False
+    out.append(np.asarray(x))
+    return True
+
+
+def _assemble(like, leaves: Iterator):
+    """``like``'s containers, as :func:`default_collate` builds them,
+    around the next of ``leaves`` in the order :func:`_leaves` gives."""
+    if isinstance(like, tuple) and hasattr(like, "_fields"):  # namedtuple
+        return type(like)(*(_assemble(v, leaves) for v in like))
+    if isinstance(like, (tuple, list)):
+        return type(like)(_assemble(v, leaves) for v in like)
+    if isinstance(like, dict):
+        return {k: _assemble(v, leaves) for k, v in like.items()}
+    return next(leaves)
 
 
 def _persistent_process_worker(
@@ -254,7 +339,12 @@ def _close_pool(pool) -> None:
 
 def default_collate(samples: Sequence[Any]):
     """Stack a list of samples into batched numpy arrays (mirrors torch's
-    default_collate for array/tuple/dict/scalar structures)."""
+    default_collate for array/tuple/dict/scalar structures). The
+    :class:`DataLoader` given this function builds the same batch slot by
+    slot, each sample written into its slot as it is made, and calls it
+    on the whole list only where a later sample's containers, leaf
+    shapes or leaf dtypes differ from the first's, or a leaf is not
+    array-like."""
     first = samples[0]
     if isinstance(first, tuple) and hasattr(first, "_fields"):  # namedtuple
         return type(first)(*(default_collate(list(s)) for s in zip(*samples)))
@@ -272,6 +362,12 @@ class DataLoader:
     is deterministic — identical to the single-threaded order — because
     workers fill a slot-addressed reorder window, not a free-for-all
     queue.
+
+    Under the default ``collate_fn`` a worker writes each sample into its
+    slot of the batch as soon as the sample is made, and falls back to
+    collating the whole list where a later sample's containers, leaf
+    shapes or leaf dtypes differ from the first's, or a leaf is not
+    array-like (:func:`default_collate`).
 
     ``worker_type`` selects the concurrency model. ``"thread"`` (default)
     matches TPU-host reality: PIL's JPEG decode and numpy's transforms
